@@ -86,12 +86,14 @@ def build_train_step(bs: int = 32, seq: int = 128, cfg_kw=None,
 
 
 def main():
+    from paddle_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import jax
-    on_tpu = jax.default_backend() not in ("cpu",)
+    on_tpu = jax.default_backend() == "tpu"
     if on_tpu:
         # single real chip: absolute number, bert-base. AMP-O2 bf16 at
         # bs 128 is the round-5 recipe (+32% over the r4 f32/bs32
-        # number — benchmarks/RESULTS.md BERT probe)
+        # number — the rounds-1-5 notes (git history before PR 23) BERT probe)
         import numpy as np_
         bs, seq, steps = 128, 128, 10
         step = build_train_step(bs, seq, amp=True)
@@ -126,8 +128,4 @@ def main():
 
 
 if __name__ == "__main__":
-    import os
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
-        import jax
-        jax.config.update("jax_platforms", "cpu")
     main()

@@ -19,6 +19,8 @@ import numpy as np
 def main():
     import jax
     jax.config.update("jax_platforms", "cpu")  # serving-host benchmark
+    from paddle_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import paddle_tpu as paddle
     from paddle_tpu import nn
     from paddle_tpu.jit.static_function import InputSpec

@@ -11,12 +11,14 @@ import numpy as np
 
 
 def main():
+    from paddle_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import jax
     import paddle_tpu as paddle
     import paddle_tpu.distributed as dist
     from paddle_tpu.models.bert import ErnieForSequenceClassification
 
-    on_tpu = jax.default_backend() not in ("cpu",)
+    on_tpu = jax.default_backend() == "tpu"
     n_dev = 1 if on_tpu else 4
     if on_tpu:
         kw = dict(vocab_size=18000, hidden_size=768, num_hidden_layers=12,
@@ -86,8 +88,4 @@ def main():
 
 
 if __name__ == "__main__":
-    import os
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
-        import jax
-        jax.config.update("jax_platforms", "cpu")
     main()

@@ -14,11 +14,13 @@ import numpy as np
 
 
 def main():
+    from paddle_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import jax
     import jax.numpy as jnp
     from paddle_tpu.models.gpt import GPTConfig, GPTSpmdTrainer, build_mesh
 
-    on_tpu = jax.default_backend() not in ("cpu",)
+    on_tpu = jax.default_backend() == "tpu"
     if on_tpu:
         cfg = GPTConfig(vocab_size=50304, hidden_size=2048, num_layers=24,
                         num_heads=16, max_seq_len=1024, dtype=jnp.bfloat16)
@@ -62,8 +64,4 @@ def main():
 
 
 if __name__ == "__main__":
-    import os
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
-        import jax
-        jax.config.update("jax_platforms", "cpu")
     main()

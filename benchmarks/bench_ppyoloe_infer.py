@@ -38,13 +38,15 @@ def _bench_one(path, x, steps, precision=None):
 
 
 def main():
+    from paddle_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import jax
     import paddle_tpu as paddle
     import paddle_tpu.jit as jit
     from paddle_tpu import inference, nn
     from paddle_tpu.vision.models import ppyoloe_s
 
-    on_tpu = jax.default_backend() not in ("cpu",)
+    on_tpu = jax.default_backend() == "tpu"
     size, bs, steps = ((640, 8, 10) if on_tpu else (64, 1, 2))
 
     model = ppyoloe_s()
@@ -84,7 +86,4 @@ def main():
 
 
 if __name__ == "__main__":
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
-        import jax
-        jax.config.update("jax_platforms", "cpu")
     main()

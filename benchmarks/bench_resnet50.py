@@ -38,12 +38,14 @@ def build_train_step(bs: int, img_hw: int = 224):
 
 
 def main():
+    from paddle_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import jax
     import paddle_tpu as paddle
     from paddle_tpu.vision.models import resnet50
     import paddle_tpu.jit as jit
 
-    on_tpu = jax.default_backend() not in ("cpu",)
+    on_tpu = jax.default_backend() == "tpu"
     bs, steps = (256, 10) if on_tpu else (4, 2)
     img = (bs, 3, 224, 224) if on_tpu else (bs, 3, 32, 32)
 
@@ -86,8 +88,4 @@ def main():
 
 
 if __name__ == "__main__":
-    import os
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
-        import jax
-        jax.config.update("jax_platforms", "cpu")
     main()

@@ -1903,11 +1903,13 @@ def run_multihost_fabric(cfg_kwargs, *, slots, max_len, min_bucket,
 
 
 def main():
+    from paddle_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import jax
     import paddle_tpu as paddle
     from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 
-    on_tpu = jax.default_backend() not in ("cpu",)
+    on_tpu = jax.default_backend() == "tpu"
     if on_tpu:
         cfg = LlamaConfig(vocab_size=32000, hidden_size=2048,
                           num_hidden_layers=16, num_attention_heads=16,
@@ -2138,7 +2140,4 @@ if __name__ == "__main__":
         if "host_platform_device_count" not in flags:
             os.environ["XLA_FLAGS"] = \
                 flags + " --xla_force_host_platform_device_count=8"
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
-        import jax
-        jax.config.update("jax_platforms", "cpu")
     main()

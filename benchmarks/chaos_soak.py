@@ -36,6 +36,8 @@ import time
 
 
 def main():
+    from paddle_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--episodes", type=int,
                     default=int(os.environ.get("PTPU_CHAOS_EPISODES",
@@ -131,7 +133,4 @@ def main():
 
 
 if __name__ == "__main__":
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
-        import jax
-        jax.config.update("jax_platforms", "cpu")
     main()

@@ -15,6 +15,8 @@ import _path  # noqa: F401  (repo-root import shim)
 
 
 def main():
+    from paddle_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--remat", default="full")
     ap.add_argument("--bs", type=int, default=6)

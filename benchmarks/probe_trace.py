@@ -2,7 +2,8 @@
 
 Runs N steps of the flagship GPT trainer (or ResNet-50 with --model
 resnet) under jax.profiler, then prints the per-op device-time ledger
-via the self-contained xplane parser — the tool behind RESULTS.md's
+via the self-contained xplane parser — the tool behind the rounds-1-5 notes
+(git history before PR 23)
 step waterfalls.
 
   python benchmarks/probe_trace.py --steps 3 [--top 25]
@@ -18,6 +19,8 @@ import xplane
 
 
 def main():
+    from paddle_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--model", default="gpt",
                     choices=["gpt", "resnet", "bert"])

@@ -22,7 +22,8 @@ Cost model (assumptions stated, all overridable):
 - ring costs per chip: all-reduce 2(g-1)/g * B; all-gather and
   reduce-scatter (g-1)/g * B (B = full payload bytes); all-to-all
   (g-1)/g^2 * B; collective-permute B.
-- compute time from the measured single-chip step (RESULTS.md), held
+- compute time from the measured single-chip step (the rounds-1-5 notes (git
+history before PR 23)), held
   constant per chip (weak scaling: per-chip batch fixed).
 - two efficiency curves: exposed (zero overlap, worst case) and
   overlapped (collectives hide under compute up to 100%, cost =
@@ -231,6 +232,8 @@ def main():
                     default=[8, 16, 32])
     args = ap.parse_args()
     _force_cpu(max(args.devices))
+    from paddle_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     lines = []
     results = {}
@@ -362,7 +365,8 @@ def _render(r) -> str:
             "chip per mesh axis (v5e 2D torus, 45 GB/s/link/direction);",
             "one v5e pod = 256 chips so no DCN hop appears in 8->256;",
             "per-chip batch fixed (weak scaling); compute times are the",
-            "MEASURED single-chip steps from benchmarks/RESULTS.md.",
+            "MEASURED single-chip steps from the rounds-1-5 notes (git",
+            "history before PR 23).",
             "Exposed = zero overlap (worst case); overlapped = perfect",
             "compute/comm overlap (max(comp, comm)). The reference's",
             "bucketed EagerReducer and our jit schedules land between",

@@ -1,4 +1,5 @@
-"""Step-budget decomposition: the machine-checked form of the RESULTS.md
+"""Step-budget decomposition: the machine-checked form of the rounds-1-5 notes
+(git history before PR 23)
 step waterfalls.
 
 Buckets one profiled training step (xplane self-times on the device ops
@@ -341,7 +342,7 @@ def mesh_collectives_smoke(steps: int = 3) -> Optional[dict]:
     decompose with the v2 ``collectives`` record. This exercises the
     exposed-vs-overlapped split against an ACTUAL multi-device
     execution's all-reduce/all-gather intervals instead of the
-    synthetic fixture: the flow the on-chip BENCH_r06 run will reuse.
+    synthetic fixture: the flow an on-chip bench run reuses.
 
     The step is Megatron-shaped in miniature: activations data-
     parallel over `fsdp`, both weights output/contraction-sharded over
@@ -378,10 +379,12 @@ def mesh_collectives_smoke(steps: int = 3) -> Optional[dict]:
         return jnp.sum((y - x) ** 2)     # loss crosses `fsdp` too
 
     step(x, w1, w2).block_until_ready()  # compile outside the trace
-    on_tpu = jax.default_backend() not in ("cpu",)
+    on_tpu = jax.default_backend() == "tpu"
     return capture(lambda: step(x, w1, w2), steps=steps,
                    plane_filter="TPU" if on_tpu else "CPU",
-                   line_filter=None if on_tpu else "XLATfrtCpuClient")
+                   # the CPU client's per-device executor threads
+                   # ("tf_XLAPjRtCpuClient/<id>" in jax 0.9 traces)
+                   line_filter=None if on_tpu else "CpuClient")
 
 
 def _run_gpt_step():
@@ -412,6 +415,9 @@ def main():
     ap.add_argument("--write-fixture", action="store_true")
     ap.add_argument("--out", help="also write the JSON record here")
     args = ap.parse_args()
+    if args.run:  # the only modes that compile
+        from paddle_tpu.utils.compile_cache import enable_compile_cache
+        enable_compile_cache()
 
     if args.write_fixture:
         print(write_fixture())

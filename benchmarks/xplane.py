@@ -3,7 +3,8 @@
 jax.profiler.start_trace writes ``plugins/profile/<ts>/*.xplane.pb``
 (tensorflow XSpace proto). This decodes just enough of the schema —
 planes → lines → events with per-plane event-metadata tables — to
-produce the step-decomposition ledgers in RESULTS.md without any
+produce the step-decomposition ledgers in the rounds-1-5 notes (git history
+before PR 23) without any
 tensorflow/tensorboard dependency. Wire format details follow
 tsl/profiler/protobuf/xplane.proto; decoding is the same
 varint/length-delimited walk as paddle_tpu/onnx/proto.py:read_fields.

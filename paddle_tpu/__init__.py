@@ -6,78 +6,6 @@ Public surface mirrors ``import paddle`` (reference:
 nn.Layer modules, optimizers, AMP, DataLoader, distributed parallelism, jit
 capture — re-architected TPU-first (see SURVEY.md §7).
 """
-import jax as _jax
-
-# -- jax version shims: the codebase targets the current jax surface;
-# alias the few renamed/moved APIs so older lines (e.g. 0.4.x in this
-# image) serve the same programs. --------------------------------------
-# True when running on a pre-jax.shard_map jax: the experimental
-# shard_map backing the alias below cannot lower axis_index/ppermute
-# inside PARTIAL-AUTO regions (pipe-parallel paths); tests gate on it.
-_jax_compat_old_shard_map = not hasattr(_jax, "shard_map")
-
-if _jax_compat_old_shard_map:
-    # jax < 0.5 ships shard_map under experimental only, with the old
-    # kwarg surface (check_rep/auto instead of check_vma/axis_names)
-    # and a REQUIRED mesh; adapt it so the `jax.shard_map(...)` call
-    # sites (and `from jax import shard_map` imports below) work on
-    # both lines. Call sites that omit mesh= rely on the jax.set_mesh
-    # context — the set_mesh shim below records it here.
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    _compat_mesh = [None]
-
-    def _shard_map(f, *, mesh=None, in_specs, out_specs,
-                   check_vma=None, check_rep=None, axis_names=None,
-                   auto=None):
-        if mesh is None:
-            mesh = _compat_mesh[0]
-        if mesh is None:
-            raise RuntimeError(
-                "jax.shard_map without mesh= needs an enclosing "
-                "jax.set_mesh(...) on this pre-0.5 jax")
-        if auto is None and axis_names is not None:
-            auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-        kw = {}
-        if auto:
-            kw["auto"] = auto
-        rep = check_rep if check_rep is not None else check_vma
-        if rep is not None:
-            kw["check_rep"] = rep
-        return _shard_map_old(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, **kw)
-
-    _jax.shard_map = _shard_map
-import jax.export  # noqa: F401  (0.4.x: not loaded by `import jax`)
-if not hasattr(_jax, "set_mesh"):
-    # pre-set_mesh jax: sharding is carried entirely by the
-    # NamedShardings already attached to every jitted step, so the
-    # context degrades to recording the mesh for the shard_map shim
-    # and otherwise doing nothing. (Entering the legacy `with mesh:`
-    # resource env instead would flip pjit into the xmap-era axis-env
-    # lowering, which emits PartitionId ops XLA's SPMD partitioner
-    # rejects.)
-    import contextlib as _contextlib
-
-    @_contextlib.contextmanager
-    def _set_mesh(mesh):
-        if not _jax_compat_old_shard_map:
-            yield mesh
-            return
-        prev, _compat_mesh[0] = _compat_mesh[0], mesh
-        try:
-            yield mesh
-        finally:
-            _compat_mesh[0] = prev
-
-    _jax.set_mesh = _set_mesh
-try:
-    import jax.experimental.pallas.tpu as _pltpu
-    if not hasattr(_pltpu, "CompilerParams"):
-        _pltpu.CompilerParams = _pltpu.TPUCompilerParams
-except ImportError:  # pallas absent: kernels gate on backend anyway
-    pass
-
 from .framework.dtype import (  # noqa: F401
     bool_ as bool, uint8, int8, int16, int32, int64, float16, bfloat16,
     float32, float64, complex64, complex128, float8_e4m3fn, float8_e5m2,

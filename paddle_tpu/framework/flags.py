@@ -83,7 +83,8 @@ define_flag("FLAGS_bn_pallas", False,
             "kernels (ops/bn_pallas.py). Default OFF: measured SLOWER "
             "than XLA's BN fusions on v5e NCHW shapes (165-220 vs "
             "263-395 GB/s - the unaligned spatial lane dim defeats "
-            "Pallas block DMA; benchmarks/RESULTS.md round-5)")
+            "Pallas block DMA; the rounds-1-5 notes (git history "
+            "before PR 23) round-5)")
 define_flag("FLAGS_use_stride_kernel", True, "views share storage (no-op on XLA)")
 define_flag("FLAGS_eager_delete_tensor_gb", 0.0, "gc threshold (XLA-managed)")
 define_flag("FLAGS_low_precision_op_list", 0, "record AMP op dtype decisions")

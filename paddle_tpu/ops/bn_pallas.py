@@ -2,7 +2,8 @@
 
 Why: on v5e, XLA's BN reduce/apply fusions sustain only ~150-250 GB/s
 against the ~660 GB/s the in-house Pallas kernels reach (measured:
-benchmarks/RESULTS.md round-5 ResNet ledger; the 98.8 ms ResNet-50 step
+the rounds-1-5 notes (git history before PR 23) round-5 ResNet ledger; the 98.8
+ms ResNet-50 step
 carries ~93 ms of such fusions). BatchNorm is pure streaming work, so
 the fix is the same one fused_adamw applied to the optimizer: hand
 Pallas the whole pass. Four kernels, each one read (+ at most one

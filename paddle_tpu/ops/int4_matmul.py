@@ -2,7 +2,8 @@
 
 The XLA lowering of unpack->dequant->matmul materializes the bf16
 weight copy in HBM every call, which DESTROYS the bandwidth win decode
-exists for (measured 62 tok/s bs1 vs 329 bf16 — benchmarks/RESULTS.md
+exists for (measured 62 tok/s bs1 vs 329 bf16 — the rounds-1-5 notes (git
+history before PR 23)
 round-5 int4 ledger). This kernel reads the PACKED uint8 nibbles
 [K/2, N] straight from HBM, unpacks and scales in VMEM registers, and
 feeds the MXU — HBM cost stays 0.5 B/weight.

@@ -1,6 +1,6 @@
 """Real-int8 deployment layers: PTQ-calibrated Linear/Conv2D that
 execute on the int8 MXU (294.8 TOPS measured vs 147 bf16 on v5e —
-benchmarks/RESULTS.md), not fake-quant simulation.
+the rounds-1-5 notes (git history before PR 23)), not fake-quant simulation.
 
 Reference behavior: the reference's int8 story terminates in a deployed
 engine (analysis_predictor + TRT int8 /

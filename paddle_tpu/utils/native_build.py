@@ -9,6 +9,7 @@ translation unit compiled on first import.
 """
 from __future__ import annotations
 
+import hashlib
 import os
 import subprocess
 from typing import Optional
@@ -20,17 +21,22 @@ OUT_DIR = os.path.join(REPO_ROOT, "build")
 
 def build_native_so(src_name: str, so_name: str,
                     opt: str = "-O3") -> Optional[str]:
-    """Compile csrc/<src_name> to build/<so_name> if stale; returns the
-    .so path or None on failure (callers degrade to pure-python paths)."""
+    """Compile csrc/<src_name> to build/<stem>.<hash>.so, where the hash
+    is of the source text and the flags: a library is reused only when
+    it was built from exactly this source (mtimes say nothing after a
+    copy or a checkout). Returns the .so path or None on failure
+    (callers degrade to pure-python paths)."""
     src = os.path.join(REPO_ROOT, "csrc", src_name)
-    so = os.path.join(OUT_DIR, so_name)
     try:
+        with open(src, "rb") as f:
+            digest = hashlib.sha256(opt.encode() + f.read()).hexdigest()
         os.makedirs(OUT_DIR, exist_ok=True)
-        if os.path.exists(so) and \
-                os.path.getmtime(so) >= os.path.getmtime(src):
-            return so
     except OSError:  # missing csrc tree etc: degrade, don't raise
-        return so if os.path.exists(so) else None
+        return None
+    stem, ext = os.path.splitext(so_name)
+    so = os.path.join(OUT_DIR, f"{stem}.{digest[:16]}{ext}")
+    if os.path.exists(so):
+        return so
     tmp = f"{so}.{os.getpid()}.tmp"
     cmd = ["g++", opt, "-shared", "-fPIC", "-pthread", "-std=c++17",
            src, "-o", tmp]

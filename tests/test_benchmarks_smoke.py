@@ -206,6 +206,9 @@ def test_benchmark_script_smoke(script, tmp_path):
             pytest.skip("native TCPStore extension unavailable")
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=8",
+               # the scripts turn the compile cache on: keep it (and
+               # their cluster workers') out of the checkout
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jax_cache"),
                PYTHONPATH=os.pathsep.join(
                    [HERE] + os.environ.get("PYTHONPATH", "")
                    .split(os.pathsep)))

@@ -16,7 +16,8 @@ from paddle_tpu.resilience import chaos, faults, invariants
 from paddle_tpu.resilience.invariants import (ConservationLedger,
                                               InvariantViolation)
 
-pytestmark = pytest.mark.chaos
+pytestmark = [pytest.mark.chaos,
+              pytest.mark.usefixtures("worker_compile_cache")]
 
 
 @pytest.fixture(autouse=True)

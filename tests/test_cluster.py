@@ -27,9 +27,10 @@ from paddle_tpu.resilience import faults
 from paddle_tpu.resilience.train_loop import RestartLimitExceeded
 from paddle_tpu.serving import ClusterSupervisor, ServingEngine
 
-pytestmark = pytest.mark.skipif(
-    get_lib() is None,
-    reason="native TCPStore extension unavailable")
+pytestmark = [
+    pytest.mark.skipif(get_lib() is None,
+                       reason="native TCPStore extension unavailable"),
+    pytest.mark.usefixtures("worker_compile_cache")]
 
 MODEL_KW = dict(num_hidden_layers=1, hidden_size=32,
                 intermediate_size=64, num_attention_heads=2,
@@ -356,8 +357,10 @@ def test_handshake_rejects_unauthenticated_and_wrong_secret_peers():
             fr.server_handshake(b, b"right-secret")
     finally:
         b.close()
-        a.close()
+        # join BEFORE closing the dialer's own socket: closing it under
+        # a blocked recv raises EBADF there instead of the typed error
         t.join(timeout=10)
+        a.close()
     assert client_err                        # the dialer got a typed
     assert fr.auth_failures() >= before + 2  # refusal too, all counted
 

@@ -106,7 +106,8 @@ def test_stage_overlap_arithmetic(tmp_path, monkeypatch):
     — sleeps overlap even on a 1-core host, where CPU-bound compute
     cannot), M micro-batches through S stages must take ~(M + S - 1) x D,
     not the serial M x S x D. This pins the favorable regime the +63%
-    1-core serving tax (benchmarks/RESULTS.md) cannot show."""
+    1-core serving tax (the rounds-1-5 notes (git history before PR 23)) cannot
+    show."""
     from paddle_tpu.inference.dist_model_mp import (DistModelMP,
                                                     DistModelConfig)
     _, (p1, p2) = _export_stages(tmp_path)

@@ -2,7 +2,7 @@
 per-site against the shipping default, plus the bad-value guard.
 (On CPU every mode runs the shared XLA fallback quantizers, so the
 losses must agree to float tolerance — the TPU perf A/B lives in
-benchmarks/RESULTS.md.)"""
+the rounds-1-5 notes (git history before PR 23).)"""
 import numpy as np
 import jax.numpy as jnp
 import pytest

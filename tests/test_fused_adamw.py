@@ -201,7 +201,8 @@ def test_moment8_state_checkpoint_roundtrip(tmp_path):
     """(q, scale) tuple leaves must survive paddle.save/load with their
     TUPLE-ness intact — _adamw dispatches on isinstance(leaf, tuple),
     so a serializer that returns lists would silently break resume.
-    (Full TPU resume verified live on-chip; RESULTS.md round-5.)"""
+    (Full TPU resume verified live on-chip; the rounds-1-5 notes (git history
+    before PR 23) round-5.)"""
     import paddle_tpu as paddle
     from paddle_tpu.ops.fused_adamw import moment8_init
     mq, msc, vq, vsc = moment8_init(jnp.zeros((64, 256)))

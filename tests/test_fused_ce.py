@@ -122,7 +122,8 @@ def _dense_forward_of_trainer(tr, ids, labels):
 
 def test_ce_int8_mechanism_close_but_not_default():
     # ce_int8 exists as an OPTION (rejected as a training default:
-    # 300-step parity diverges — benchmarks/RESULTS.md round 4). The
+    # 300-step parity diverges — the rounds-1-5 notes (git history before PR
+    # 23) round 4). The
     # mechanism itself must stay numerically sane at one-shot scale.
     import numpy as np
     from paddle_tpu.ops.fused_ce import fused_softmax_cross_entropy

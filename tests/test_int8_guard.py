@@ -1,4 +1,5 @@
-"""int8 drift guard + dynamic lr schedule (round 4; RESULTS.md wqkv
+"""int8 drift guard + dynamic lr schedule (round 4; the rounds-1-5 notes (git
+history before PR 23) wqkv
 SNR ~1 finding is why the default is watched, not assumed)."""
 import jax
 import jax.numpy as jnp

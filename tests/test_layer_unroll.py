@@ -37,10 +37,15 @@ def _losses(tr, steps, ids, labels):
 
 def test_unrolled_loss_bit_identical_to_rolled_scan():
     """The headline parity: same init (identical RNG draws), same data
-    -> bit-identical loss trajectory. Params stay allclose but not
-    bitwise: the grad-clip global norm sums per-leaf partials in leaf
-    order, which differs between the stacked and per-layer layouts by
-    f32 reassociation (~1 ulp/step). Doubles as the trace-count
+    -> the same loss trajectory to the last ulp. Step 1 (a pure forward
+    on identical params) is bit-identical. From step 2 on the losses
+    may differ in the last f32 ulp: on the installed XLA (jax 0.9.0)
+    the scan body and the unrolled layers fuse their backward
+    differently, so grads — and with them every param, embeddings
+    included, clipping or not — differ by f32 reassociation
+    (~1 ulp/step), and bit identity of the later losses cannot be
+    restored from the model's side (PR 23 measured 1 ulp at step 2,
+    0 at step 3). Doubles as the trace-count
     assertion: the unrolled step fn must compile no more than the
     rolled one (ONE executable + the shared donated-output-sharding
     retrace on step 2), and stay flat after."""
@@ -49,7 +54,9 @@ def test_unrolled_loss_bit_identical_to_rolled_scan():
     tr_u = _trainer("full")
     lr = _losses(tr_r, 3, ids, labels)
     lu = _losses(tr_u, 3, ids, labels)
-    assert lr == lu, (lr, lu)
+    assert lr[0] == lu[0], (lr, lu)
+    np.testing.assert_array_max_ulp(np.float32(lr), np.float32(lu),
+                                    maxulp=2)
     pr = np.asarray(jax.device_get(tr_r.params["blocks"]["wqkv"]))[0]
     pu = np.stack([np.asarray(jax.device_get(
         tr_u.params["blocks"][k]["wqkv"]))
@@ -111,12 +118,24 @@ def test_unrolled_state_checkpoints_and_resumes(tmp_path):
 def test_unrolled_matches_rolled_under_wgrad_sr():
     """quant8='wgrad': the unrolled per-layer seeds must reproduce the
     scan's _layer_seeds derivation exactly, or SR streams (and losses)
-    diverge."""
+    diverge. The derivation is compared exactly; the losses are
+    bit-identical at step 1 and within 3e-6 relative at step 2. Bit
+    identity there is gone on the installed XLA (jax 0.9.0): the
+    last-ulp grad differences of the test above flip a few stochastic
+    roundings, which PR 23 measured as 1.4e-6 relative, while a wrong
+    seed stream measured 6.0e-6."""
     ids, labels = _data()
-    lr = _losses(_trainer(1, layers=2, quant8="wgrad"), 2, ids, labels)
-    lu = _losses(_trainer("full", layers=2, quant8="wgrad"), 2,
-                 ids, labels)
-    assert lr == lu, (lr, lu)
+    tr_r = _trainer(1, layers=2, quant8="wgrad")
+    tr_u = _trainer("full", layers=2, quant8="wgrad")
+    seed = jnp.int32(7)
+    np.testing.assert_array_equal(
+        np.asarray(tr_r._layer_seeds(seed)),
+        np.asarray([tr_u._layer_seed(seed, li)
+                    for li in range(tr_u.Lps)]))
+    lr = _losses(tr_r, 2, ids, labels)
+    lu = _losses(tr_u, 2, ids, labels)
+    assert lr[0] == lu[0], (lr, lu)
+    np.testing.assert_allclose(lr, lu, rtol=3e-6, atol=0)
 
 
 @pytest.mark.full

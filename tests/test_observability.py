@@ -745,6 +745,7 @@ def test_engine_broken_after_donating_step_failure(tmp_path):
     assert req.finished and len(req.output_ids) == 4
 
 
+@pytest.mark.usefixtures("worker_compile_cache")
 def test_cluster_metric_families_and_death_dump(tmp_path):
     """ISSUE-11 observability satellite: a cluster run leaves — in ONE
     registry — per-worker liveness/respawn gauges, respawn and kill

@@ -1,7 +1,8 @@
 """Step-budget tool (benchmarks/step_budget.py): the selftest fixture
 parses with stable bucket keys on CPU-only CI, the xplane writer
 round-trips through the parser, and the classifier buckets the op
-families the RESULTS.md ledgers talk about (tier-1 by design — the tool
+families the rounds-1-5 notes (git history before PR 23) ledgers talk about
+(tier-1 by design — the tool
 must not silently rot between TPU rounds)."""
 import json
 import os
