@@ -1,0 +1,6 @@
+"""A scalar the driver took on the host's clock, times ``scale``."""
+
+
+def read(args: dict, obs: dict):
+    v = obs["host"].get(args["key"])
+    return None if v is None else v * args.get("scale", 1.0)

@@ -1,0 +1,8 @@
+"""Device busy time in the traced window over the steps in it, ms."""
+
+
+def read(args: dict, obs: dict):
+    tr = obs.get("trace")
+    if not tr or not tr["steps"]:
+        return None
+    return tr["busy_s"] * 1e3 / tr["steps"]
