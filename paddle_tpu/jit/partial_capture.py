@@ -318,7 +318,9 @@ class SegmentedFunction:
         f = eager_ex.make_frame(dict(ba.arguments))
         # mutable containers the CALLER can still see (argument-
         # reachable): crossing a jit boundary must never clone them
-        arg_mut_ids = frozenset(_mutable_ids(list(ba.arguments.values())))
+        # (walked as a TUPLE: a temporary list would put its own id in
+        # the set, and the next list born at that address would match)
+        arg_mut_ids = frozenset(_mutable_ids(tuple(ba.arguments.values())))
         segments_run = 0
         while True:
             segments_run += 1

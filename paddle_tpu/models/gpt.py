@@ -41,6 +41,8 @@ from ..nn.layer.common import Dropout, Embedding, Linear
 from ..nn.layer.norm import LayerNorm
 from ..nn.layer.container import LayerList
 from ..framework.tensor import Tensor, apply_op
+from ..observability import span
+from ..utils.compile_cache import Watched
 from ._decode_cache import (cache_attend, check_cache_pos,
                             paged_cache_attend)
 
@@ -405,238 +407,249 @@ class GPTSpmdTrainer:
                  lr_schedule=None,
                  int8_guard_period: int = 0,
                  int8_guard_threshold: float = 0.10):
-        self.cfg = cfg
-        self.mesh = mesh
-        self.remat = remat  # per-block activation checkpointing
-        # AMP-O2 contract (reference python/paddle/amp/auto_cast.py O2
-        # `decorate`): compute/grads in cfg.dtype, fp32 master params in
-        # the optimizer. Grads materialize at cfg.dtype (half the HBM of
-        # fp32 grads), masters+update stay fp32.
-        self.mixed_precision = mixed_precision
-        # AdamW moment storage dtype; bf16 moments let ~1.3B params fit
-        # a single 16G chip (update math still fp32)
-        self.moment_dtype = moment_dtype
-        # Master-weight storage dtype. fp32 = classic AMP-O2 masters.
-        # bf16 = store masters AT compute precision and apply the AdamW
-        # update with stochastic rounding (update math in fp32, the
-        # rounding noise is unbiased so tiny updates accumulate in
-        # expectation — the bf16+SR training recipe). Halves master HBM
-        # and removes the per-step master->compute cast entirely, which
-        # is what frees enough HBM for save_dots remat at 1.3B/16G.
-        self.master_dtype = master_dtype
-        self._stoch_round = (jnp.dtype(master_dtype) == jnp.bfloat16)
-        # int8 MXU forward for the wide block matmuls (qkv/ffn), exact
-        # bf16 backward — ~2x MXU rate on v5e (ops/quant_matmul.py).
-        # quant8="dgrad" additionally runs the activation gradient on
-        # the int8 MXU (wgrad stays exact bf16). quant8="wgrad" runs
-        # ALL THREE matmuls int8 — the weight gradient quantizes with
-        # stochastic rounding along the token axis, which keeps it
-        # unbiased so Adam's moments integrate the noise to zero
-        # (ops/quant_matmul.int8_linear_all8); SR streams are seeded
-        # per (step, layer, site) from the optimizer step counter.
-        self.quant8 = quant8
-        # lr_schedule: traced fn step_f32 -> multiplier on the base lr
-        # (cosine decay etc.); costs nothing — the multiplier rides the
-        # fused kernel's scalar vector.
-        self.lr_schedule = lr_schedule
-        # int8 drift guard: every `period` steps measure the relative
-        # dgrad error of the int8 path on ONE layer-0 matmul (~1% of a
-        # step); if it exceeds the threshold, fall back one quant tier
-        # (wgrad -> dgrad -> exact) and recompile the step. Exists
-        # because the 500-step parity runs end with wqkv SNR ~1 — the
-        # default is earned, but nothing should drift unwatched.
-        self.int8_guard_period = int(int8_guard_period)
-        self.int8_guard_threshold = float(int8_guard_threshold)
-        if self.int8_guard_period and mesh.shape.get("pipe", 1) > 1:
-            # the probe indexes blocks leaves as [S, L, ...][0, 0];
-            # pipelined/VPP layouts need their own probe — refuse
-            # loudly rather than crash inside the jitted probe
-            raise ValueError(
-                "int8_guard_period requires a single-stage mesh "
-                "(pipe=1)")
-        self._guard_fn = None
-        self._guard_events = []
-        self._host_step = 0
-        if quant8 == "wgrad" and mesh.shape.get("pipe", 1) > 1:
-            # the pipeline paths do not thread the per-step SR seed;
-            # running them would silently reuse one stream every step —
-            # exactly the data-correlated bias SR exists to remove
-            raise ValueError(
-                "quant8='wgrad' supports single-stage meshes (pipe=1); "
-                "pipeline schedules keep wgrad exact (use 'dgrad')")
-        # pp schedule: "gpipe" = autodiff'd scan+ppermute forward
-        # (F-then-B); "1f1b" = explicit on-device 1F1B train schedule
-        # (distributed/pipeline.pipeline_train_1f1b) with O(S) instead
-        # of O(M) in-flight activations per stage; "vpp" = interleaved
-        # virtual-pipeline (each rank holds vpp_chunks model chunks —
-        # fill bubble shrinks by 1/V) and "zb" = ZeroBubble ZB-H1
-        # (backward split into input-grad and weight-grad jobs, W fills
-        # the cooldown bubble) — both execute their job tables on
-        # device via distributed/pipeline_scheduled.py
-        aliases = {"fthenb": "gpipe", "zero_bubble": "zb",
-                   "interleaved": "vpp"}
-        pipeline_schedule = aliases.get(pipeline_schedule,
-                                        pipeline_schedule)
-        if pipeline_schedule not in ("gpipe", "1f1b", "vpp", "zb"):
-            raise ValueError(f"unknown pipeline_schedule "
-                             f"{pipeline_schedule!r}")
-        self.pipeline_schedule = pipeline_schedule
-        # chunked params only make sense with a pipe axis: with pipe=1
-        # every schedule degenerates to the plain forward, which
-        # consumes unchunked [S=1, L, ...] stage params
-        self.V = int(vpp_chunks) if (pipeline_schedule == "vpp"
-                                     and mesh.shape["pipe"] > 1) else 1
-        # MoE-FFN variant: E experts per block, GShard top-2 dispatch,
-        # experts sharded over the 'data' mesh axis (expert parallelism
-        # — the dispatch/combine einsums lower to the all-to-all pair
-        # the reference's global_scatter/global_gather implement by
-        # hand, moe_layer.py:263); the load-balance aux loss is
-        # accumulated through the layer scan and added to the CE loss.
-        self.moe_experts = int(moe_experts)
-        self.moe_capacity_factor = moe_capacity_factor
-        self.moe_aux_weight = moe_aux_weight
-        # single-pass Pallas AdamW (ops/fused_adamw.py): one kernel per
-        # leaf reads p/g/m/v and writes p/m/v with in-kernel SR random
-        # bits — 14 bytes/param of HBM traffic vs ~26 for the XLA
-        # multi-pass schedule. Only meaningful on a real TPU; the
-        # unsharded leaves the kernel needs exist when no mesh axis
-        # shards params in ways the 2-D collapse can't see, so gate to
-        # single-device meshes (GSPMD partitions pallas_call manually
-        # sharded kernels poorly).
-        if fused_optimizer is None:
-            fused_optimizer = (jax.default_backend() == "tpu"
-                               and mesh.size == 1)
-        self.fused_optimizer = fused_optimizer
-        # int8 moment storage for fused-eligible leaves (round-5 lever
-        # b): m int8-SR, v as sqrt(v) int8-SR, per-row f32
-        # scales — 14 -> ~10 B/param of optimizer HBM traffic
-        # (ops/fused_adamw.fused_adamw_update8). Parity-gated like every
-        # quantization default: benchmarks/parity_int8.py --moment8.
-        self.moment8 = bool(moment8)
-        if self.moment8 and not (self.fused_optimizer
-                                 and mesh.size == 1):
-            # mesh.size must be checked here too: fused_optimizer=True
-            # passed explicitly on a multi-device mesh would otherwise
-            # let the opaque fused_adamw_update8 pallas_call reach the
-            # partitioner, which replicates custom calls (same gate as
-            # pallas_ops.single_device_tpu)
-            raise ValueError(
-                "moment8 rides the fused AdamW kernel, which requires "
-                "a SINGLE-device TPU mesh (got fused_optimizer="
-                f"{self.fused_optimizer}, mesh.size={mesh.size}); it "
-                "has no XLA fallback path")
-        # unroll policy for the per-stage layer loop. An int is the
-        # classic lax.scan body-unroll factor: the body is replicated
-        # but params/carries stay STACKED [L, ...], so every
-        # remat-saved residual still round-trips HBM through a
-        # dynamic-update-slice into the stacked buffer (plus a matching
-        # dynamic-slice in the backward) — measured ~49 ms of pure
-        # stacking traffic on the 1.3B step, and scan-unroll alone
-        # measured a LOSS (round 3/5). "full" is the structural fix
-        # (round 6): blocks params live as a PER-LAYER pytree (a dict
-        # of "layer_NNN" subtrees, no [L, ...] leading dim anywhere —
-        # dict-shaped so checkpointing flattens it like any state), the
-        # stage runs as a Python loop, and remat saves/gradients/
-        # optimizer state are per-layer leaves — XLA writes each
-        # layer's residuals and weight-grad dequants straight from the
-        # producing fusion instead of DUS-stacking them. Costs compile
-        # time roughly linearly in num_layers; requires pipe=1 (the
-        # pipeline shard_map consumes stacked stage params).
-        self._unroll_full = (layer_unroll == "full")
-        if self._unroll_full:
-            if mesh.shape["pipe"] > 1 or self.V > 1:
+        # set-up by phase: the configuration, then the state
+        with span("train.build"):
+            self.cfg = cfg
+            self.mesh = mesh
+            self.remat = remat  # per-block activation checkpointing
+            # AMP-O2 contract (reference python/paddle/amp/auto_cast.py O2
+            # `decorate`): compute/grads in cfg.dtype, fp32 master params in
+            # the optimizer. Grads materialize at cfg.dtype (half the HBM of
+            # fp32 grads), masters+update stay fp32.
+            self.mixed_precision = mixed_precision
+            # AdamW moment storage dtype; bf16 moments let ~1.3B params fit
+            # a single 16G chip (update math still fp32)
+            self.moment_dtype = moment_dtype
+            # Master-weight storage dtype. fp32 = classic AMP-O2 masters.
+            # bf16 = store masters AT compute precision and apply the AdamW
+            # update with stochastic rounding (update math in fp32, the
+            # rounding noise is unbiased so tiny updates accumulate in
+            # expectation — the bf16+SR training recipe). Halves master HBM
+            # and removes the per-step master->compute cast entirely, which
+            # is what frees enough HBM for save_dots remat at 1.3B/16G.
+            self.master_dtype = master_dtype
+            self._stoch_round = (jnp.dtype(master_dtype) == jnp.bfloat16)
+            # int8 MXU forward for the wide block matmuls (qkv/ffn), exact
+            # bf16 backward — ~2x MXU rate on v5e (ops/quant_matmul.py).
+            # quant8="dgrad" additionally runs the activation gradient on
+            # the int8 MXU (wgrad stays exact bf16). quant8="wgrad" runs
+            # ALL THREE matmuls int8 — the weight gradient quantizes with
+            # stochastic rounding along the token axis, which keeps it
+            # unbiased so Adam's moments integrate the noise to zero
+            # (ops/quant_matmul.int8_linear_all8); SR streams are seeded
+            # per (step, layer, site) from the optimizer step counter.
+            self.quant8 = quant8
+            # lr_schedule: traced fn step_f32 -> multiplier on the base lr
+            # (cosine decay etc.); costs nothing — the multiplier rides the
+            # fused kernel's scalar vector.
+            self.lr_schedule = lr_schedule
+            # int8 drift guard: every `period` steps measure the relative
+            # dgrad error of the int8 path on ONE layer-0 matmul (~1% of a
+            # step); if it exceeds the threshold, fall back one quant tier
+            # (wgrad -> dgrad -> exact) and recompile the step. Exists
+            # because the 500-step parity runs end with wqkv SNR ~1 — the
+            # default is earned, but nothing should drift unwatched.
+            self.int8_guard_period = int(int8_guard_period)
+            self.int8_guard_threshold = float(int8_guard_threshold)
+            if self.int8_guard_period and mesh.shape.get("pipe", 1) > 1:
+                # the probe indexes blocks leaves as [S, L, ...][0, 0];
+                # pipelined/VPP layouts need their own probe — refuse
+                # loudly rather than crash inside the jitted probe
                 raise ValueError(
-                    "layer_unroll='full' requires a single-stage mesh "
-                    "(pipe=1, vpp_chunks=1): pipeline schedules consume "
-                    "stacked [S, L, ...] stage params")
-            self.layer_unroll = cfg.num_layers
-        else:
-            self.layer_unroll = int(layer_unroll)
-        # vocab-chunk count for the fused CE: fewer chunks = bigger
-        # (faster) head matmuls but a larger live logits buffer
-        self.ce_chunks = int(ce_chunks)
-        # int8-MXU CE head matmuls (fwd + recompute + dx; dhead exact —
-        # it feeds the tied embedding's Adam state). ~31 ms of head
-        # matmuls at the flagship shape; earn/reject via parity_int8.
-        self.ce_int8 = bool(ce_int8)
-        # producer-fused gelu->quantize for the ffn2 site (round-5
-        # lever d); auto-on for the all-int8 recipe. Note: removes the
-        # standalone "ffn_act" residual, so policies that SAVE ffn_act
-        # (save_attn_ffn) force it off.
-        if fuse_gelu_quant and quant8 != "wgrad":
-            raise ValueError(
-                "fuse_gelu_quant rides the all-int8 recipe: it needs "
-                "quant8='wgrad' (the fused op quantizes both the fwd "
-                "row and the wgrad SR column streams)")
-        if fuse_gelu_quant is None:
-            fuse_gelu_quant = quant8 == "wgrad"
-        self.fuse_gelu_quant = bool(fuse_gelu_quant) and \
-            remat != "save_attn_ffn"
-        # producer-fused LayerNorm->quantize for the qkv/ffn1 sites
-        # (round-5 lever a): same mechanism as fuse_gelu_quant — the
-        # rowq kernel computes LN stats + normalize + quantize in one
-        # read of the pre-LN residual; the wgrad colq kernel reuses the
-        # emitted [M,1] stats. Default OFF: measured a structural LOSS
-        # on the flagship step (337.4 -> 344-356 ms across full/qkv/
-        # ffn1/fwd-only variants) — the custom-call boundary breaks
-        # XLA's residual-add/bias/save fusions around each site, which
-        # costs more than the saved LN-output round-trip (trace diff in
-        # the rounds-1-5 notes (git history before PR 23); contrast
-        # fuse_gelu_quant, whose site
-        # feeds another custom call, not an XLA fusion).
-        if fuse_ln_quant and quant8 != "wgrad":
-            raise ValueError(
-                "fuse_ln_quant rides the all-int8 recipe: it needs "
-                "quant8='wgrad' (the fused op quantizes both the fwd "
-                "row and the wgrad SR column streams)")
-        if fuse_ln_quant is None:
-            fuse_ln_quant = False
-        # True = both sites; "qkv"/"ffn1" = that site only (A/B probes)
-        if fuse_ln_quant not in (True, False, "qkv", "ffn1"):
-            raise ValueError(
-                f"fuse_ln_quant must be True/False/'qkv'/'ffn1', got "
-                f"{fuse_ln_quant!r}")
-        self.fuse_ln_quant = fuse_ln_quant
-        # fuse_ln_quant's wgrad sub-knob (ADVICE r5): True computes the
-        # LN inside the backward column-quantize path from the saved
-        # [M,1] stats (two reads of the pre-LN x, no h buffer); False
-        # re-materializes LN(x) once and runs the plain one-pass colq
-        # kernel. None defers to env PTPU_FUSE_BWD_COLQ (default off —
-        # the A/B that earned the default is in the rounds-1-5 notes (git
-        # history before PR 23)).
-        # The [M,1] mean/rstd residuals are only SAVED when the branch
-        # is on (ops/quant_matmul.int8_ln_linear_all8).
-        if fuse_bwd_colq is None:
-            from ..ops.quant_matmul import _env_fuse_bwd_colq
-            fuse_bwd_colq = _env_fuse_bwd_colq()
-        self.fuse_bwd_colq = bool(fuse_bwd_colq)
-        if self.moe_experts and mesh.shape["pipe"] > 1 \
-                and self.pipeline_schedule == "gpipe":
-            raise NotImplementedError(
-                "MoE + pipeline parallelism requires an explicit "
-                "schedule engine ('1f1b', 'vpp' or 'zb'): the "
-                "autodiff'd GPipe scan has no aux-loss side channel")
-        # Pallas flash attention on real TPU; XLA einsum attention
-        # elsewhere (interpret-mode pallas is orders slower on CPU, and
-        # the Mosaic kernel does not lower on GPU backends)
-        if use_flash is None:
-            use_flash = jax.default_backend() == "tpu"
-        self.use_flash = use_flash
-        self.S = mesh.shape["pipe"]
-        if cfg.num_layers % (self.S * self.V):
-            raise ValueError("num_layers must divide pp degree "
-                             "(x vpp_chunks for 'vpp')")
-        self.Lps = cfg.num_layers // (self.S * self.V)
-        self.M = microbatches or max(2 * self.S, 1)
-        if self.pipeline_schedule == "vpp" and self.S > 1 \
-                and self.M % self.S:
-            raise ValueError("interleaved schedule needs "
-                             "microbatches % pp degree == 0")
-        self._sched_cache = None
-        self.lr = learning_rate
-        self.wd = weight_decay
-        self.betas = (beta1, beta2)
-        self.grad_clip = grad_clip
+                    "int8_guard_period requires a single-stage mesh "
+                    "(pipe=1)")
+            self._guard_fn = None
+            self._guard_events = []
+            self._host_step = 0
+            if quant8 == "wgrad" and mesh.shape.get("pipe", 1) > 1:
+                # the pipeline paths do not thread the per-step SR seed;
+                # running them would silently reuse one stream every step —
+                # exactly the data-correlated bias SR exists to remove
+                raise ValueError(
+                    "quant8='wgrad' supports single-stage meshes (pipe=1); "
+                    "pipeline schedules keep wgrad exact (use 'dgrad')")
+            # pp schedule: "gpipe" = autodiff'd scan+ppermute forward
+            # (F-then-B); "1f1b" = explicit on-device 1F1B train schedule
+            # (distributed/pipeline.pipeline_train_1f1b) with O(S) instead
+            # of O(M) in-flight activations per stage; "vpp" = interleaved
+            # virtual-pipeline (each rank holds vpp_chunks model chunks —
+            # fill bubble shrinks by 1/V) and "zb" = ZeroBubble ZB-H1
+            # (backward split into input-grad and weight-grad jobs, W fills
+            # the cooldown bubble) — both execute their job tables on
+            # device via distributed/pipeline_scheduled.py
+            aliases = {"fthenb": "gpipe", "zero_bubble": "zb",
+                       "interleaved": "vpp"}
+            pipeline_schedule = aliases.get(pipeline_schedule,
+                                            pipeline_schedule)
+            if pipeline_schedule not in ("gpipe", "1f1b", "vpp", "zb"):
+                raise ValueError(f"unknown pipeline_schedule "
+                                 f"{pipeline_schedule!r}")
+            self.pipeline_schedule = pipeline_schedule
+            # chunked params only make sense with a pipe axis: with pipe=1
+            # every schedule degenerates to the plain forward, which
+            # consumes unchunked [S=1, L, ...] stage params
+            self.V = int(vpp_chunks) if (pipeline_schedule == "vpp"
+                                         and mesh.shape["pipe"] > 1) else 1
+            # MoE-FFN variant: E experts per block, GShard top-2 dispatch,
+            # experts sharded over the 'data' mesh axis (expert parallelism
+            # — the dispatch/combine einsums lower to the all-to-all pair
+            # the reference's global_scatter/global_gather implement by
+            # hand, moe_layer.py:263); the load-balance aux loss is
+            # accumulated through the layer scan and added to the CE loss.
+            self.moe_experts = int(moe_experts)
+            self.moe_capacity_factor = moe_capacity_factor
+            self.moe_aux_weight = moe_aux_weight
+            # single-pass Pallas AdamW (ops/fused_adamw.py): one kernel per
+            # leaf reads p/g/m/v and writes p/m/v with in-kernel SR random
+            # bits — 14 bytes/param of HBM traffic vs ~26 for the XLA
+            # multi-pass schedule. Only meaningful on a real TPU; the
+            # unsharded leaves the kernel needs exist when no mesh axis
+            # shards params in ways the 2-D collapse can't see, so gate to
+            # single-device meshes (GSPMD partitions pallas_call manually
+            # sharded kernels poorly).
+            if fused_optimizer is None:
+                fused_optimizer = (jax.default_backend() == "tpu"
+                                   and mesh.size == 1)
+            self.fused_optimizer = fused_optimizer
+            # int8 moment storage for fused-eligible leaves (round-5 lever
+            # b): m int8-SR, v as sqrt(v) int8-SR, per-row f32
+            # scales — 14 -> ~10 B/param of optimizer HBM traffic
+            # (ops/fused_adamw.fused_adamw_update8). Parity-gated like every
+            # quantization default: benchmarks/parity_int8.py --moment8.
+            self.moment8 = bool(moment8)
+            if self.moment8 and not (self.fused_optimizer
+                                     and mesh.size == 1):
+                # mesh.size must be checked here too: fused_optimizer=True
+                # passed explicitly on a multi-device mesh would otherwise
+                # let the opaque fused_adamw_update8 pallas_call reach the
+                # partitioner, which replicates custom calls (same gate as
+                # pallas_ops.single_device_tpu)
+                raise ValueError(
+                    "moment8 rides the fused AdamW kernel, which requires "
+                    "a SINGLE-device TPU mesh (got fused_optimizer="
+                    f"{self.fused_optimizer}, mesh.size={mesh.size}); it "
+                    "has no XLA fallback path")
+            # unroll policy for the per-stage layer loop. An int is the
+            # classic lax.scan body-unroll factor: the body is replicated
+            # but params/carries stay STACKED [L, ...], so every
+            # remat-saved residual still round-trips HBM through a
+            # dynamic-update-slice into the stacked buffer (plus a matching
+            # dynamic-slice in the backward) — measured ~49 ms of pure
+            # stacking traffic on the 1.3B step, and scan-unroll alone
+            # measured a LOSS (round 3/5). "full" is the structural fix
+            # (round 6): blocks params live as a PER-LAYER pytree (a dict
+            # of "layer_NNN" subtrees, no [L, ...] leading dim anywhere —
+            # dict-shaped so checkpointing flattens it like any state), the
+            # stage runs as a Python loop, and remat saves/gradients/
+            # optimizer state are per-layer leaves — XLA writes each
+            # layer's residuals and weight-grad dequants straight from the
+            # producing fusion instead of DUS-stacking them. Costs compile
+            # time roughly linearly in num_layers; requires pipe=1 (the
+            # pipeline shard_map consumes stacked stage params).
+            self._unroll_full = (layer_unroll == "full")
+            if self._unroll_full:
+                if mesh.shape["pipe"] > 1 or self.V > 1:
+                    raise ValueError(
+                        "layer_unroll='full' requires a single-stage mesh "
+                        "(pipe=1, vpp_chunks=1): pipeline schedules consume "
+                        "stacked [S, L, ...] stage params")
+                self.layer_unroll = cfg.num_layers
+            else:
+                self.layer_unroll = int(layer_unroll)
+            # vocab-chunk count for the fused CE: fewer chunks = bigger
+            # (faster) head matmuls but a larger live logits buffer
+            self.ce_chunks = int(ce_chunks)
+            # int8-MXU CE head matmuls (fwd + recompute + dx; dhead exact —
+            # it feeds the tied embedding's Adam state). ~31 ms of head
+            # matmuls at the flagship shape; earn/reject via parity_int8.
+            self.ce_int8 = bool(ce_int8)
+            # producer-fused gelu->quantize for the ffn2 site (round-5
+            # lever d); auto-on for the all-int8 recipe. Note: removes the
+            # standalone "ffn_act" residual, so policies that SAVE ffn_act
+            # (save_attn_ffn) force it off.
+            if fuse_gelu_quant and quant8 != "wgrad":
+                raise ValueError(
+                    "fuse_gelu_quant rides the all-int8 recipe: it needs "
+                    "quant8='wgrad' (the fused op quantizes both the fwd "
+                    "row and the wgrad SR column streams)")
+            if fuse_gelu_quant is None:
+                fuse_gelu_quant = quant8 == "wgrad"
+            self.fuse_gelu_quant = bool(fuse_gelu_quant) and \
+                remat != "save_attn_ffn"
+            # producer-fused LayerNorm->quantize for the qkv/ffn1 sites
+            # (round-5 lever a): same mechanism as fuse_gelu_quant — the
+            # rowq kernel computes LN stats + normalize + quantize in one
+            # read of the pre-LN residual; the wgrad colq kernel reuses the
+            # emitted [M,1] stats. Default OFF: measured a structural LOSS
+            # on the flagship step (337.4 -> 344-356 ms across full/qkv/
+            # ffn1/fwd-only variants) — the custom-call boundary breaks
+            # XLA's residual-add/bias/save fusions around each site, which
+            # costs more than the saved LN-output round-trip (trace diff in
+            # the rounds-1-5 notes (git history before PR 23); contrast
+            # fuse_gelu_quant, whose site
+            # feeds another custom call, not an XLA fusion).
+            if fuse_ln_quant and quant8 != "wgrad":
+                raise ValueError(
+                    "fuse_ln_quant rides the all-int8 recipe: it needs "
+                    "quant8='wgrad' (the fused op quantizes both the fwd "
+                    "row and the wgrad SR column streams)")
+            if fuse_ln_quant is None:
+                fuse_ln_quant = False
+            # True = both sites; "qkv"/"ffn1" = that site only (A/B probes)
+            if fuse_ln_quant not in (True, False, "qkv", "ffn1"):
+                raise ValueError(
+                    f"fuse_ln_quant must be True/False/'qkv'/'ffn1', got "
+                    f"{fuse_ln_quant!r}")
+            self.fuse_ln_quant = fuse_ln_quant
+            # fuse_ln_quant's wgrad sub-knob (ADVICE r5): True computes the
+            # LN inside the backward column-quantize path from the saved
+            # [M,1] stats (two reads of the pre-LN x, no h buffer); False
+            # re-materializes LN(x) once and runs the plain one-pass colq
+            # kernel. None defers to env PTPU_FUSE_BWD_COLQ (default off —
+            # the A/B that earned the default is in the rounds-1-5 notes (git
+            # history before PR 23)).
+            # The [M,1] mean/rstd residuals are only SAVED when the branch
+            # is on (ops/quant_matmul.int8_ln_linear_all8).
+            if fuse_bwd_colq is None:
+                from ..ops.quant_matmul import _env_fuse_bwd_colq
+                fuse_bwd_colq = _env_fuse_bwd_colq()
+            self.fuse_bwd_colq = bool(fuse_bwd_colq)
+            if self.moe_experts and mesh.shape["pipe"] > 1 \
+                    and self.pipeline_schedule == "gpipe":
+                raise NotImplementedError(
+                    "MoE + pipeline parallelism requires an explicit "
+                    "schedule engine ('1f1b', 'vpp' or 'zb'): the "
+                    "autodiff'd GPipe scan has no aux-loss side channel")
+            # Pallas flash attention on real TPU; XLA einsum attention
+            # elsewhere (interpret-mode pallas is orders slower on CPU, and
+            # the Mosaic kernel does not lower on GPU backends)
+            if use_flash is None:
+                use_flash = jax.default_backend() == "tpu"
+            self.use_flash = use_flash
+            self.S = mesh.shape["pipe"]
+            if cfg.num_layers % (self.S * self.V):
+                raise ValueError("num_layers must divide pp degree "
+                                 "(x vpp_chunks for 'vpp')")
+            self.Lps = cfg.num_layers // (self.S * self.V)
+            self.M = microbatches or max(2 * self.S, 1)
+            if self.pipeline_schedule == "vpp" and self.S > 1 \
+                    and self.M % self.S:
+                raise ValueError("interleaved schedule needs "
+                                 "microbatches % pp degree == 0")
+            self._sched_cache = None
+            self.lr = learning_rate
+            self.wd = weight_decay
+            self.betas = (beta1, beta2)
+            self.grad_clip = grad_clip
+        with span("train.init_state") as sp:
+            self._init_state(seed)
+            sp.set_attr("n_params", self.n_params())
+        self._step_fn = None
+
+    # -- init --------------------------------------------------------------
+    def _init_state(self, seed: int) -> None:
+        """Parameters and optimizer state, created on the mesh."""
+        mesh = self.mesh
         self.params = self._init_params(jax.random.key(seed))
         zeros_moment = lambda p: jnp.zeros(  # noqa: E731
             p.shape, self.moment_dtype, device=p.sharding)
@@ -674,9 +687,7 @@ class GPTSpmdTrainer:
                 "m": jax.tree.map(zeros_moment, self.params),
                 "v": jax.tree.map(zeros_moment, self.params),
             }
-        self._step_fn = None
 
-    # -- init --------------------------------------------------------------
     def _init_params(self, key) -> Dict[str, Any]:
         cfg = self.cfg
         D, V, T, Ff = (cfg.hidden_size, cfg.vocab_size, cfg.max_seq_len,
@@ -1413,9 +1424,12 @@ class GPTSpmdTrainer:
             return params, opt_state, loss
 
         data_spec = _spec(self.mesh, ("data",), None)
-        self._step_fn = jax.jit(
+        # the program and its cache key are jax.jit(step)'s own; the
+        # wrapper only reports the call that compiled (or loaded) it
+        self._step_fn = Watched(jax.jit(
             step, donate_argnums=(0, 1),
-            in_shardings=(None, None, data_spec, data_spec))
+            in_shardings=(None, None, data_spec, data_spec)),
+            kind="train_step")
         return self._step_fn
 
     def _build_guard(self):
@@ -1502,7 +1516,10 @@ class GPTSpmdTrainer:
             input_ids = input_ids._data
         if isinstance(labels, Tensor):
             labels = labels._data
-        with jax.set_mesh(self.mesh):
+        # the HOST's side of the step: placing the batch and enqueuing
+        # the program, which returns before the device has finished
+        with span("train.step", tokens=int(np.prod(labels.shape))), \
+                jax.set_mesh(self.mesh):
             if self.quant8 and self.int8_guard_period and \
                     self._host_step % self.int8_guard_period == 0:
                 self._run_guard(jnp.asarray(input_ids))

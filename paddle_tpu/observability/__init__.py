@@ -7,10 +7,12 @@ Three pieces (docs/OBSERVABILITY.md has the full guide):
   clock; Prometheus text exposition + JSON exporters. The process
   default (``default_registry()``) is what serving, jit, io, and
   distributed publish to.
-- **Spans** (``tracing.py``): host annotations that forward to
-  ``profiler.RecordEvent`` / ``jax.profiler.TraceAnnotation`` and carry
-  structured args — serving spans carry request ids, so one request is
-  traceable across engine iterations in the chrome trace.
+- **Spans** (``tracing.py``): host annotations with structured args
+  that reach any live JAX profiler session
+  (``jax.profiler.TraceAnnotation``), the chrome trace of
+  ``profiler.Profiler``, and an always-on process ring with parents
+  and self times (``tracing.query``) — serving spans carry request
+  ids, so one request is traceable across engine iterations.
 - **Flight recorder** (``flight_recorder.py``): bounded ring of the
   last N step records (latency, occupancy, queue depth, compile
   events) dumped to disk when a step raises, the watchdog flags a dead

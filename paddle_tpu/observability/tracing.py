@@ -1,14 +1,22 @@
 """Request-correlated spans + distributed trace propagation.
 
 A ``span`` is the host-side annotation every instrumented layer opens
-around its hot sections. It forwards to ``profiler.RecordEvent`` — so
-when a ``profiler.Profiler`` is recording, the span lands in BOTH the
-chrome-trace host timeline and (via RecordEvent's TraceAnnotation
-forwarding) the XPlane device trace — and it carries structured
-attributes (``request_id`` first among them) into the chrome event's
-``args``, which is what makes serving timelines correlatable: filter
-the trace by ``args.request_id`` and one request's prefill/decode
-steps line up across engine iterations.
+around its hot sections. One span goes three ways:
+
+- into the JAX profiler's trace, as a ``jax.profiler.TraceAnnotation``
+  carrying the span's scalar attributes, whenever ANY profiler session
+  is live (``jax.profiler.start_trace``, TensorBoard's capture,
+  ``profiler.Profiler``) — so the program's phases lie in the same
+  ``.xplane.pb``, on the same clock, as the device's operations;
+- into the chrome-trace host timeline while a ``profiler.Profiler`` is
+  recording, attributes in the event's ``args`` (filter by
+  ``args.request_id`` and one request's prefill/decode steps line up
+  across engine iterations);
+- into the process ring: a bounded :class:`TraceBuffer` of COMPLETED
+  spans, installed by default on ``time.perf_counter``. Every record
+  names its enclosing span (``parent``), so :func:`query` gives each
+  span's self time; that is what the benchmark's ``program_span``
+  reader and an operator in a debugger read.
 
 Since the serving path spans PROCESSES (frontdoor → router → RPC →
 worker engine), spans also participate in distributed tracing:
@@ -18,10 +26,9 @@ worker engine), spans also participate in distributed tracing:
   RPC frame (``serving/cluster.py`` puts the active context in each
   message, alongside the virtual clock), so worker-side engine spans
   parent correctly.
-- :class:`TraceBuffer` — a bounded per-process ring of COMPLETED
-  spans. When one is installed (``install_trace_buffer``), every
-  ``Span.__exit__`` records ``{name, t0, t1, pid, trace, attrs}``
-  into it on the buffer's clock (workers install theirs with the
+- :class:`TraceBuffer` — every ``Span.__exit__`` records ``{name, id,
+  parent, t0, t1, pid, trace, attrs}`` into the installed buffer on
+  the buffer's clock (cluster workers install their own with the
   engine's virtual-clock ``time_fn``). ``drain()`` hands the ring to
   the telemetry scrape; the cumulative ``drained_total`` /
   ``dropped_total`` counters let the merger detect a LOST scrape (or
@@ -31,13 +38,14 @@ worker engine), spans also participate in distributed tracing:
   only know a ``request_id`` resolve their trace without any engine
   code changes.
 
-Spans are cheap when nothing records: RecordEvent no-ops its event
-append unless the profiler state machine is in RECORD, and the trace
-buffer is only consulted when one is installed.
+Cost with nothing listening: two clock reads, one ``is_enabled`` probe
+of the profiler and one dict into the ring (PERF.md section 6 has the
+measured microseconds).
 """
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import os
 import threading
 import time
@@ -47,7 +55,12 @@ from typing import Any, Callable, Dict, List, Optional
 __all__ = ["Span", "span", "TraceContext", "TraceBuffer",
            "install_trace_buffer", "current_trace_buffer",
            "bind_request", "unbind_request", "clear_bindings",
-           "context_for", "active_context"]
+           "context_for", "active_context", "query"]
+
+# the default ring holds a 50 s window of a saturated serving cell
+# (~1,100 engine steps x ~13 spans, ~15,000) twice over, about 20 MB
+# when full; ``dropped_total`` says when it did not
+DEFAULT_CAPACITY = 32768
 
 
 @dataclasses.dataclass(frozen=True)
@@ -109,6 +122,11 @@ class TraceBuffer:
             self.drained_total += len(out)
             return out
 
+    def snapshot(self) -> List[dict]:
+        """The ring as it stands (oldest first), nothing taken."""
+        with self._lock:
+            return list(self._ring)
+
     def __len__(self) -> int:
         with self._lock:
             return len(self._ring)
@@ -116,10 +134,34 @@ class TraceBuffer:
 
 # -- process-global wiring (buffer + rid bindings + active stack) -----
 
-_buffer: Optional[TraceBuffer] = None
+_buffer: Optional[TraceBuffer] = TraceBuffer(DEFAULT_CAPACITY,
+                                             time.perf_counter)
 _bindings: Dict[int, TraceContext] = {}
 _bind_lock = threading.Lock()
 _tls = threading.local()
+_ids = itertools.count(1)
+_pid = os.getpid()
+_trace_me = None       # jax.profiler.TraceAnnotation, at first use
+_profiler = None       # paddle_tpu.profiler, at first use
+
+
+def _refresh_pid() -> None:
+    global _pid
+    _pid = os.getpid()
+
+
+os.register_at_fork(after_in_child=_refresh_pid)
+
+
+def _peers():
+    """``(TraceAnnotation, profiler)``: imported at the first span, not
+    with this module — profiler is a peer package and observability
+    stays importable on its own."""
+    global _trace_me, _profiler
+    from jax.profiler import TraceAnnotation
+    from .. import profiler
+    _trace_me, _profiler = TraceAnnotation, profiler
+    return _trace_me, _profiler
 
 
 def install_trace_buffer(
@@ -134,6 +176,12 @@ def install_trace_buffer(
 
 def current_trace_buffer() -> Optional[TraceBuffer]:
     return _buffer
+
+
+def _now() -> float:
+    """The installed ring's clock (``time.perf_counter`` by default)."""
+    buf = _buffer
+    return float(buf.now()) if buf is not None else time.perf_counter()
 
 
 def bind_request(rid: int, ctx: Optional[TraceContext]) -> None:
@@ -157,10 +205,18 @@ def clear_bindings() -> None:
 
 
 def context_for(rid) -> Optional[TraceContext]:
-    if rid is None:
+    # no binding anywhere (every process but a cluster worker): no lock
+    if rid is None or not _bindings:
         return None
     with _bind_lock:
         return _bindings.get(int(rid))
+
+
+def has_bindings() -> bool:
+    """True on a process whose requests carry trace contexts (a
+    cluster worker): its batch spans list their request ids for the
+    merged timeline's per-request lanes."""
+    return bool(_bindings)
 
 
 def _stack() -> list:
@@ -174,88 +230,174 @@ def active_context() -> Optional[TraceContext]:
     """The context of the innermost open span that has one — what
     the cluster RPC client stamps on every outgoing frame."""
     st = _stack()
-    return st[-1] if st else None
+    return st[-1]._eff if st else None
+
+
+_SCALARS = (int, float, str, bool)
 
 
 class Span:
-    """Context manager wrapping profiler.RecordEvent with attributes.
+    """One host span: context manager, attributes, parent.
 
     ``set_attr`` may be called inside the span (attributes are read at
-    exit, when the chrome event is emitted). ``ctx`` attaches an
-    explicit :class:`TraceContext`; without one, the request binding
-    for ``attrs['request_id']`` and then the enclosing span's context
-    are consulted. When a :class:`TraceBuffer` is installed the
-    completed span is recorded into it at exit (even when the body
-    raised — a failed stage is still part of the timeline).
+    exit, when the ring record and the chrome event are made; the
+    profiler annotation carries the scalar ones known at entry).
+    ``ctx`` attaches an explicit :class:`TraceContext`; without one,
+    the request binding for ``attrs['request_id']`` and then the
+    enclosing span's context are consulted. The completed span is
+    recorded into the installed :class:`TraceBuffer` at exit (even when
+    the body raised — a failed stage is still part of the timeline).
     """
+
+    __slots__ = ("name", "ctx", "attrs", "id", "_parent", "_ann",
+                 "_buf", "_t0", "_t0_ns", "_eff")
 
     def __init__(self, name: str, request_id: Optional[int] = None,
                  ctx: Optional[TraceContext] = None, **attrs: Any):
         self.name = name
         self.ctx = ctx
-        self.attrs: Dict[str, Any] = {}
         if request_id is not None:
-            self.attrs["request_id"] = request_id
-        self.attrs.update(attrs)
-        self._ev = None
+            attrs["request_id"] = request_id
+        self.attrs: Dict[str, Any] = attrs
+        self.id = 0
+        self._parent = 0
+        self._ann = None
         self._buf: Optional[TraceBuffer] = None
         self._t0 = 0.0
+        self._t0_ns: Optional[int] = None
         self._eff: Optional[TraceContext] = None
-        self._pushed = False
 
     def set_attr(self, key: str, value: Any) -> "Span":
         self.attrs[key] = value
         return self
 
     def __enter__(self) -> "Span":
-        # lazy import: profiler is a peer package and observability
-        # must stay importable on its own
-        from .. import profiler
-        self._ev = profiler.RecordEvent(self.name, args=self.attrs)
-        self._ev.begin()
+        trace_me, prof = (_trace_me, _profiler) if _profiler is not None \
+            else _peers()
+        st = _stack()
+        parent = st[-1] if st else None
+        self.id = next(_ids)
+        self._parent = parent.id if parent is not None else 0
         self._eff = (self.ctx
                      or context_for(self.attrs.get("request_id"))
-                     or active_context())
-        if self._eff is not None:
-            _stack().append(self._eff)
-            self._pushed = True
-        buf = _buffer
+                     or (parent._eff if parent is not None else None))
+        st.append(self)
+        if trace_me.is_enabled():
+            # live under ANY jax profiler session, not only ours
+            self._ann = trace_me(self.name, **{
+                k: v for k, v in self.attrs.items()
+                if isinstance(v, _SCALARS)})
+            self._ann.__enter__()
+        if prof._is_recording():
+            self._t0_ns = time.perf_counter_ns()
+        buf = self._buf = _buffer
         if buf is not None:
-            self._buf = buf
             self._t0 = float(buf.now())
         return self
 
     def __exit__(self, *exc):
-        if self._ev is not None:
-            self._ev.end()
-            self._ev = None
-        if self._pushed:
-            st = _stack()
-            if st:
-                st.pop()
-            self._pushed = False
         buf = self._buf
+        t1 = float(buf.now()) if buf is not None else 0.0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        if self._t0_ns is not None:
+            if _profiler._is_recording():
+                _profiler._host_event(self.name, self._t0_ns,
+                                      time.perf_counter_ns(), self.attrs)
+            self._t0_ns = None
+        st = _stack()
+        if st and st[-1] is self:
+            st.pop()
+        elif self in st:
+            st.remove(self)
         if buf is not None:
             self._buf = None
-            rec = {"name": self.name, "t0": self._t0,
-                   "t1": float(buf.now()), "pid": os.getpid()}
-            if self._eff is not None:
-                rec["trace"] = self._eff.trace_id
-                rec["parent"] = self._eff.parent_span_id
-            if exc and exc[0] is not None:
-                rec["error"] = getattr(exc[0], "__name__", str(exc[0]))
-            if self.attrs:
-                rec["attrs"] = dict(self.attrs)
-            buf.record(rec)
+            buf.record(_record(
+                self.name, self.id, self._parent, self._t0, t1,
+                self._eff, self.attrs,
+                exc[0] if exc and exc[0] is not None else None))
         return False
+
+
+def _record(name, sid, parent, t0, t1, eff, attrs, error=None) -> dict:
+    rec = {"name": name, "id": sid, "parent": parent, "t0": t0,
+           "t1": t1, "pid": _pid}
+    if eff is not None:
+        rec["trace"] = eff.trace_id
+    if error is not None:
+        rec["error"] = getattr(error, "__name__", str(error))
+    if attrs:
+        rec["attrs"] = dict(attrs)
+    return rec
 
 
 def span(name: str, request_id: Optional[int] = None,
          ctx: Optional[TraceContext] = None, **attrs: Any) -> Span:
-    """Open a host span; ``request_id``/attrs flow into the chrome
-    trace event's ``args``::
+    """Open a host span; ``request_id``/attrs flow into the ring, the
+    profiler annotation and the chrome trace event's ``args``::
 
         with span("serving.prefill", request_id=req.rid, bucket=32):
             ...
     """
     return Span(name, request_id=request_id, ctx=ctx, **attrs)
+
+
+def _record_span(name: str, t0: float, **attrs: Any) -> None:
+    """``utils/compile_cache.Watched``'s way in, and nobody else's: a
+    call is known to have compiled only once it is over, so its span,
+    begun at ``t0`` (a reading of :func:`_now`) and ending now, is
+    recorded after the fact as a child of the span that is open. It
+    reaches the ring, not the profiler's trace (XLA's own compile
+    events are there). Every phase known beforehand is a :class:`Span`.
+    """
+    buf = _buffer
+    if buf is None:
+        return
+    st = _stack()
+    parent = st[-1] if st else None
+    buf.record(_record(
+        name, next(_ids), parent.id if parent is not None else 0,
+        float(t0), float(buf.now()),
+        parent._eff if parent is not None else None, attrs))
+
+
+def query(name: Optional[str] = None, t0: float = float("-inf"),
+          t1: float = float("inf"),
+          buffer: Optional[TraceBuffer] = None) -> dict:
+    """Completed spans whose START lies in ``[t0, t1)`` on the ring's
+    clock, by exact ``name`` or, with a trailing ``*``, by prefix
+    (``"compile.*"``); ``None`` takes all. Each record is a copy with
+    ``dur`` and ``self`` added: self time is the duration minus what
+    the span's children cover. ``dropped_total`` above 0 says the ring
+    overflowed since it was installed: the oldest spans (children end
+    before their parents) are gone, and counts and self times from
+    before that point are too low or too high.
+
+    A read for a debugger, a notebook or the benchmark's reader —
+    nothing is drained and nothing exported."""
+    buf = buffer if buffer is not None else _buffer
+    if buf is None:
+        return {"spans": [], "dropped_total": 0, "recorded_total": 0}
+    recs = buf.snapshot()
+    covered: Dict[int, float] = {}
+    for r in recs:
+        p = r.get("parent")
+        if p:
+            covered[p] = covered.get(p, 0.0) + (r["t1"] - r["t0"])
+    prefix = name[:-1] if name is not None and name.endswith("*") \
+        else None
+    out = []
+    for r in recs:
+        if not t0 <= r["t0"] < t1:
+            continue
+        if prefix is not None:
+            if not r["name"].startswith(prefix):
+                continue
+        elif name is not None and r["name"] != name:
+            continue
+        dur = r["t1"] - r["t0"]
+        out.append(dict(r, dur=dur, self=max(
+            0.0, dur - covered.get(r.get("id"), 0.0))))
+    return {"spans": out, "dropped_total": buf.dropped_total,
+            "recorded_total": buf.recorded_total}

@@ -59,6 +59,25 @@ def _is_recording() -> bool:
     return _recording[0]
 
 
+def _host_event(name: str, t0_ns: int, t1_ns: int, args=None) -> None:
+    """Append one completed host event to the chrome-trace list (the
+    caller has checked ``_is_recording()``). ``RecordEvent`` and
+    ``observability.Span`` both end here."""
+    ev = {
+        "name": name, "ph": "X", "pid": os.getpid(),
+        "tid": threading.get_ident(),
+        "ts": t0_ns / 1000.0,
+        "dur": (t1_ns - t0_ns) / 1000.0,
+        "cat": "host",
+    }
+    if args:
+        ev["args"] = {k: (v if isinstance(
+            v, (int, float, str, bool, type(None))) else repr(v))
+            for k, v in args.items()}
+    with _events_lock:
+        _events.append(ev)
+
+
 class RecordEvent:
     """Host-side annotation (reference: platform/profiler/event_tracing.h:43
     RecordEvent — emitted inside every generated ad_func). Also forwards to
@@ -92,19 +111,7 @@ class RecordEvent:
             self._jax_ann.__exit__(None, None, None)
             self._jax_ann = None
         if _is_recording():
-            ev = {
-                "name": self.name, "ph": "X", "pid": os.getpid(),
-                "tid": threading.get_ident(),
-                "ts": self._t0 / 1000.0,
-                "dur": (t1 - self._t0) / 1000.0,
-                "cat": "host",
-            }
-            if self.args:
-                ev["args"] = {k: (v if isinstance(
-                    v, (int, float, str, bool, type(None))) else repr(v))
-                    for k, v in self.args.items()}
-            with _events_lock:
-                _events.append(ev)
+            _host_event(self.name, self._t0, t1, self.args)
         self._t0 = None
 
     def __enter__(self):

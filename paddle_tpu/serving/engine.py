@@ -62,7 +62,9 @@ import numpy as np
 
 from ..framework.tensor import Tensor
 from ..observability import default_recorder, default_registry, span
+from ..observability.tracing import has_bindings
 from ..resilience.faults import InjectedFault, maybe_fail
+from ..utils.compile_cache import Watched, note_trace
 from .errors import (DeadlineExceeded, EngineBroken, EngineClosed,
                      EngineIdle, QueueFull, RequestCancelled)
 from .kv_tier import HostPageTier, PersistentPrefixStore
@@ -451,6 +453,11 @@ class ServingEngine:
             # engine's own trace-count ledger, so the compile contract
             # (1 decode + 1 verify + 1 draft) is asserted in one place
             self._proposers["draft"].trace_counts = self.trace_counts
+            self._proposers["draft"].registry = self.registry
+        # compile events of the running step ({kind, key, seconds,
+        # cache}; utils/compile_cache.Watched fills it), taken into the
+        # step's flight-recorder record
+        self._compiles: List[dict] = []
         reg = self.registry
         self._m_queue_depth = reg.gauge(
             "ptpu_serving_queue_depth", "requests waiting for a slot")
@@ -468,15 +475,6 @@ class ServingEngine:
         self._m_reject = reg.counter(
             "ptpu_serving_rejected_total",
             "submissions refused at admission", labels=("reason",))
-        self._m_deadline = reg.counter(
-            "ptpu_serving_deadline_cancellations_total",
-            "requests cancelled at their deadline (queued + in-flight)")
-        self._m_disconnect = reg.counter(
-            "ptpu_serving_disconnects_total",
-            "requests cancelled because their client went away")
-        self._m_recover = reg.counter(
-            "ptpu_serving_recoveries_total",
-            "successful recover() calls after a broken step")
         self._m_replay_mismatch = reg.counter(
             "ptpu_serving_recover_replay_mismatch_total",
             "recovery re-prefills whose greedy replay token diverged "
@@ -485,9 +483,6 @@ class ServingEngine:
             self._m_chunk_steps = reg.counter(
                 "ptpu_serving_chunk_steps_total",
                 "chunked-prefill chunk program runs")
-            self._m_chunk_depth = reg.gauge(
-                "ptpu_serving_chunk_queue_depth",
-                "PREFILLING requests mid-chunked-prefill")
         if self.paged:
             self._m_pages_free = reg.gauge(
                 "ptpu_serving_pages_free", "KV pages on the free list")
@@ -514,23 +509,6 @@ class ServingEngine:
                                      "prefix_lookup_tokens": 0,
                                      "cow_copies": 0}
             self.peak_active_slots = 0
-        if self._kv_tier is not None:
-            self._m_host_pages = reg.gauge(
-                "ptpu_kv_host_pages",
-                "KV pages resident in the host RAM tier")
-            self._m_demotions = reg.counter(
-                "ptpu_kv_demotions_total",
-                "cold KV pages demoted device -> host tier")
-            self._m_promotions = reg.counter(
-                "ptpu_kv_promotions_total",
-                "tiered KV pages promoted back onto device pages")
-            self._m_tier_hit = reg.counter(
-                "ptpu_kv_tier_prefix_hit_tokens_total",
-                "prompt tokens served from demoted prefix pages, by "
-                "the tier that held them", labels=("tier",))
-            self._last_page_stats.update(
-                demotions=0, promotions=0,
-                prefix_hit_tokens_host=0, prefix_hit_tokens_disk=0)
         if self.speculative:
             self._m_spec_acc = reg.histogram(
                 "ptpu_serving_spec_accepted_length",
@@ -540,15 +518,6 @@ class ServingEngine:
                 buckets=tuple(float(i) for i in
                               range(1, self.spec_k + 1)),
                 labels=("proposer",))
-            self._m_spec_draft = reg.counter(
-                "ptpu_serving_spec_draft_tokens_total",
-                "draft tokens proposed to the verify program")
-            self._m_spec_accepted = reg.counter(
-                "ptpu_serving_spec_accepted_draft_tokens_total",
-                "draft tokens confirmed by the verify program")
-            self._m_spec_hit = reg.gauge(
-                "ptpu_serving_spec_draft_hit_rate",
-                "cumulative accepted/proposed draft-token ratio")
             self._m_spec_proposer = reg.counter(
                 "ptpu_spec_proposer_total",
                 "rows drafted per verify step, by proposer kind",
@@ -652,14 +621,25 @@ class ServingEngine:
         cache.update(fresh)
         return p, b
 
-    def _publish_page_stats(self) -> None:
+    def _publish_page_stats(self, sp=None) -> None:
+        """Pool and prefix counters into the registry and, as this
+        step's counts, onto the ``serving.step`` span ``sp``."""
         if not self.paged:
             return
         c = self.cache
+        in_use = c.active_page_count()
         self._m_pages_free.set(c.free_page_count())
-        self._m_pages_active.set(c.active_page_count())
+        self._m_pages_active.set(in_use)
         self._m_pages_cached.set(c.cached_page_count())
         last = self._last_page_stats
+        if sp is not None:
+            sp.set_attr("pages_in_use", in_use)
+            sp.set_attr("pages_reserved", c.committed_pages)
+            sp.set_attr("pages_total", c.num_pages - 1)
+            sp.set_attr("prefix_hit_tokens", c.prefix_hit_tokens
+                        - last["prefix_hit_tokens"])
+            sp.set_attr("prefix_lookup_tokens", c.prefix_lookup_tokens
+                        - last["prefix_lookup_tokens"])
         for counter, key in ((self._m_prefix_hit, "prefix_hit_tokens"),
                              (self._m_prefix_lookup,
                               "prefix_lookup_tokens"),
@@ -668,19 +648,6 @@ class ServingEngine:
             if cur > last[key]:
                 counter.inc(cur - last[key])
             last[key] = cur
-        if self._kv_tier is not None:
-            self._m_host_pages.set(self._kv_tier.host_page_count())
-            for counter, key in (
-                    (self._m_demotions, "demotions"),
-                    (self._m_promotions, "promotions"),
-                    (self._m_tier_hit.labels(tier="host"),
-                     "prefix_hit_tokens_host"),
-                    (self._m_tier_hit.labels(tier="disk"),
-                     "prefix_hit_tokens_disk")):
-                cur = getattr(c, key)
-                if cur > last[key]:
-                    counter.inc(cur - last[key])
-                last[key] = cur
 
     def spec_stats(self) -> dict:
         """Speculative-decoding snapshot (raises on a non-speculative
@@ -888,47 +855,60 @@ class ServingEngine:
         t0 = self.metrics.now()
         step_idx = self._step_idx
         self._step_idx += 1
-        tc0 = (self.trace_counts["decode"],
-               sum(self.trace_counts["prefill"].values()))
+        del self._compiles[:]
         # the finished list is allocated HERE, outside the try: a
         # request that reaches a terminal state early in the step
         # (deadline sweep, decode finisher) is already evicted from its
         # slot/queue, so if the step then faults it exists nowhere else
         # — it must survive the raise or it is lost forever
         finished: List[Request] = []
-        try:
-            with span("serving.step", step=step_idx) as sp:
-                admitted, n_active = self._step_inner(finished)
-                sp.set_attr("active_slots", n_active)
-        except Exception as e:
-            if finished:
-                self._undelivered.extend(finished)
-            if self._donate():
-                # the jit call may have CONSUMED the donated pools
-                # before failing: ks/vs can reference deleted device
-                # buffers, and any later step would die confusingly —
-                # refuse further use until recover() rebuilds them
-                self._broken = f"step #{step_idx}: " \
-                               f"{type(e).__name__}: {e}"
+        with span("serving.step", step=step_idx) as sp:
             try:
-                self.recorder.record(
-                    "serving.step_error", step=step_idx,
-                    error=f"{type(e).__name__}: {e}")
-                path = self.recorder.dump(
-                    reason=f"ServingEngine.step #{step_idx} raised "
-                           f"{type(e).__name__}: {e}",
-                    registry=self.registry)
-                import sys
-                print(f"[serving] flight recorder dumped to {path}",
-                      file=sys.stderr)
-            except Exception:
-                pass               # never mask the original failure
-            raise
+                admitted, n_active = self._step_inner(finished, sp)
+            except Exception as e:
+                self._on_step_error(step_idx, e, finished)
+                raise
+            with span("serving.publish"):
+                return self._publish_step(sp, step_idx, t0, admitted,
+                                          n_active, finished)
+
+    def _on_step_error(self, step_idx: int, e: Exception,
+                       finished: List[Request]) -> None:
+        if finished:
+            self._undelivered.extend(finished)
+        if self._donate():
+            # the jit call may have CONSUMED the donated pools
+            # before failing: ks/vs can reference deleted device
+            # buffers, and any later step would die confusingly —
+            # refuse further use until recover() rebuilds them
+            self._broken = f"step #{step_idx}: " \
+                           f"{type(e).__name__}: {e}"
+        try:
+            self.recorder.record(
+                "serving.step_error", step=step_idx,
+                error=f"{type(e).__name__}: {e}")
+            path = self.recorder.dump(
+                reason=f"ServingEngine.step #{step_idx} raised "
+                       f"{type(e).__name__}: {e}",
+                registry=self.registry)
+            import sys
+            print(f"[serving] flight recorder dumped to {path}",
+                  file=sys.stderr)
+        except Exception:
+            pass               # never mask the original failure
+
+    def _publish_step(self, sp, step_idx: int, t0: float, admitted,
+                      n_active: int,
+                      finished: List[Request]) -> List[Request]:
+        """What a step tells the outside once its work is done: the
+        step span's counts, registry, flight recorder, auditor."""
         dt = self.metrics.now() - t0
         depth = self.scheduler.depth
         self._m_step.observe(dt)
         self._m_queue_depth.set(depth)
         self._m_active.set(n_active)
+        sp.set_attr("active_slots", n_active)
+        sp.set_attr("queue_depth", depth)
         wt = self._watchtower
         if wt is not None:
             wt.observe_step()
@@ -946,9 +926,7 @@ class ServingEngine:
             active_slots=n_active, queue_depth=depth,
             admitted=admitted,
             evicted=[(r.rid, r.finish_reason) for r in finished],
-            compiles_decode=self.trace_counts["decode"] - tc0[0],
-            compiles_prefill=(
-                sum(self.trace_counts["prefill"].values()) - tc0[1]))
+            compiles=list(self._compiles))
         if self.auditor is not None and not self._in_drain:
             # drain() audits its aggregate return instead, so each
             # request is audited at exactly ONE external boundary
@@ -957,9 +935,9 @@ class ServingEngine:
         self._undelivered = []
         return finished
 
-    def _step_inner(self, finished: List[Request]):
-        admitted: List[int] = []
-
+    def _admit(self, finished: List[Request]):
+        """The step's sweeps and admission (span ``serving.admit``);
+        returns the (slot, request) pairs to prefill."""
         # 0) deadline + disconnect sweeps — cancel expired requests and
         # requests whose client went away BEFORE spending a prefill or
         # decode slot-step on them
@@ -975,15 +953,25 @@ class ServingEngine:
         # claim reserves the request's worst-case page span so decode
         # can never run out of pages mid-flight
         claim = None
+        refused: List[int] = []      # rids whose page claim failed
         if self.paged:
             claim = lambda req: self.cache.try_reserve(
                 req, req.prompt,
-                req.prompt_len + req.max_new_tokens)
+                req.prompt_len + req.max_new_tokens) \
+                or refused.append(req.rid)
         pairs = self.scheduler.admissions(
             self.cache.free_slots(), claim=claim,
             lookahead=self.admission_lookahead,
             unclaim=self.cache.cancel_reservation if self.paged
             else None)
+        return pairs, len(refused)
+
+    def _step_inner(self, finished: List[Request], sp=None):
+        admitted: List[int] = []
+        with span("serving.admit") as asp:
+            pairs, refused = self._admit(finished)
+            asp.set_attr("admitted", len(pairs))
+            asp.set_attr("refused_for_pages", refused)
         # per-step prefill token budget (chunked engines): one chunk's
         # worth. Prompts that fit run the MONOLITHIC prefill program
         # inside the budget (the degenerate case IS the unchunked
@@ -1055,8 +1043,6 @@ class ServingEngine:
             ran += 1
             if self.chunk_control is None and ran >= 1:
                 break
-        if chunk is not None:
-            self._m_chunk_depth.set(len(self._chunk_fifo))
         # 2) one decode step over all occupied slots — the speculative
         # engine runs its widened k-token VERIFY program instead (same
         # contract: ONE compiled program for any request mix).
@@ -1069,67 +1055,84 @@ class ServingEngine:
                 self._decode_verify(active, finished)
             else:
                 self._decode_plain(active, finished)
-        self.metrics.on_step(len(active))
-        if self.paged:
-            self.peak_active_slots = max(self.peak_active_slots,
-                                         len(active))
-            self._publish_page_stats()
+        with span("serving.publish"):
+            self.metrics.on_step(len(active))
+            if self.paged:
+                self.peak_active_slots = max(self.peak_active_slots,
+                                             len(active))
+                self._publish_page_stats(sp)
         return admitted, len(active)
 
     def _decode_plain(self, active, finished: List[Request]) -> None:
         """The k=1 decode step (non-speculative engines)."""
-        toks = np.zeros((self.max_slots, 1), np.int64)
-        pos = np.zeros((self.max_slots,), np.int32)
-        mask = np.zeros((self.max_slots,), bool)
-        copies = []
-        for s in active:
-            req = self.cache.slots[s]
-            toks[s, 0] = req.out_tokens[-1]
-            pos[s] = req.next_pos
-            mask[s] = True
-            if self.paged:
-                # the write may cross into a new page (allocate)
-                # or a shared one (COW) — resolve BEFORE the step
-                c = self.cache.ensure_decode_page(s, req.next_pos)
-                if c is not None:
-                    copies.append(c)
-        # COW copies run BEFORE the fault point: ensure_decode_page
-        # already flipped the table rows, and a retried (non-broken)
-        # step would not re-issue a lost copy — device state must be
-        # consistent with the table when the fault can fire
-        if self.paged:
-            self._run_copies(copies)
-        maybe_fail("serving.step.decode", step=self._step_idx - 1)
-        if self.meshctx is not None:
-            # mesh engines: the SHARDED decode program is about to run
-            # (chaos kill point for the tensor-parallel flavor)
-            maybe_fail("serving.decode.sharded",
-                       step=self._step_idx - 1, tp=self.meshctx.tp)
-        with span("serving.decode", batch=len(active),
-                  request_ids=[self.cache.slots[s].rid
-                               for s in active]):
-            if self.paged:
-                logits, ks, vs, kss, vss = self._decode_fn()(
-                    self._params, self._buffers, toks, pos, mask,
-                    self.cache.page_table.copy(),
-                    self.cache.ks, self.cache.vs,
-                    self.cache.kss, self.cache.vss)
-                self.cache.ks, self.cache.vs = list(ks), list(vs)
-                self.cache.kss, self.cache.vss = \
-                    list(kss), list(vss)
-            else:
-                logits, ks, vs = self._decode_fn()(
-                    self._params, self._buffers, toks, pos, mask,
-                    self.cache.ks, self.cache.vs)
-                self.cache.ks, self.cache.vs = list(ks), list(vs)
-            logits = np.asarray(jax.device_get(logits))
-        for s in active:
-            req = self.cache.slots[s]
-            tok = sample_token(logits[s], req.sampling, req._rng)
-            req.out_tokens.append(tok)
-            self.metrics.on_token(req.rid)
-            if self._is_finished(req, tok):
-                self._evict(s, req, finished)
+        with self._decode_span("serving.decode", active):
+            with span("serving.decode.build") as sp:
+                toks = np.zeros((self.max_slots, 1), np.int64)
+                pos = np.zeros((self.max_slots,), np.int32)
+                mask = np.zeros((self.max_slots,), bool)
+                copies = []
+                for s in active:
+                    req = self.cache.slots[s]
+                    toks[s, 0] = req.out_tokens[-1]
+                    pos[s] = req.next_pos
+                    mask[s] = True
+                    if self.paged:
+                        # the write may cross into a new page
+                        # (allocate) or a shared one (COW) — resolve
+                        # BEFORE the step
+                        c = self.cache.ensure_decode_page(s, req.next_pos)
+                        if c is not None:
+                            copies.append(c)
+                # COW copies run BEFORE the fault point:
+                # ensure_decode_page already flipped the table rows,
+                # and a retried (non-broken) step would not re-issue a
+                # lost copy — device state must be consistent with the
+                # table when the fault can fire
+                if self.paged:
+                    self._run_copies(copies)
+                sp.set_attr("cow_copies", len(copies))
+            maybe_fail("serving.step.decode", step=self._step_idx - 1)
+            if self.meshctx is not None:
+                # mesh engines: the SHARDED decode program is about to
+                # run (chaos kill point for the tensor-parallel flavor)
+                maybe_fail("serving.decode.sharded",
+                           step=self._step_idx - 1, tp=self.meshctx.tp)
+            with span("serving.decode.enqueue", batch=len(active)):
+                if self.paged:
+                    logits, ks, vs, kss, vss = self._decode_fn()(
+                        self._params, self._buffers, toks, pos, mask,
+                        self.cache.page_table.copy(),
+                        self.cache.ks, self.cache.vs,
+                        self.cache.kss, self.cache.vss)
+                    self.cache.ks, self.cache.vs = list(ks), list(vs)
+                    self.cache.kss, self.cache.vss = \
+                        list(kss), list(vss)
+                else:
+                    logits, ks, vs = self._decode_fn()(
+                        self._params, self._buffers, toks, pos, mask,
+                        self.cache.ks, self.cache.vs)
+                    self.cache.ks, self.cache.vs = list(ks), list(vs)
+            logits = self._fetch("serving.decode.fetch", logits)
+        with span("serving.sample", rows=len(active)) as sp:
+            n0 = len(finished)
+            for s in active:
+                req = self.cache.slots[s]
+                tok = sample_token(logits[s], req.sampling, req._rng)
+                req.out_tokens.append(tok)
+                self.metrics.on_token(req.rid)
+                if self._is_finished(req, tok):
+                    self._evict(s, req, finished)
+            sp.set_attr("finished", len(finished) - n0)
+
+    def _decode_span(self, name: str, active, **attrs):
+        """The batch span of one decode or verify step. It carries the
+        batch size; the request ids ride along only on a process whose
+        requests have trace contexts (a cluster worker: the merged
+        timeline fans batch spans out to per-request lanes)."""
+        if has_bindings():
+            attrs["request_ids"] = [self.cache.slots[s].rid
+                                    for s in active]
+        return span(name, batch=len(active), **attrs)
 
     def _decode_verify(self, active, finished: List[Request]) -> None:
         """One speculative verify step: draft up to k-1 tokens per
@@ -1220,7 +1223,6 @@ class ServingEngine:
                     if sampled:
                         row_draft[s], row_qs[s] = draft, qs
                     self._spec["draft_tokens"] += len(draft)
-                    self._m_spec_draft.inc(len(draft))
                     self._m_spec_proposer.labels(kind=kind).inc()
             wlen[s] = n
         if self.spec_gate and all(int(wlen[s]) == 1 for s in active):
@@ -1255,13 +1257,16 @@ class ServingEngine:
         copies = []
         try:
             if self.paged:
-                for s in active:
-                    copies += self.cache.ensure_decode_range(
-                        s, self.cache.slots[s].next_pos, int(wlen[s]))
-                # COW copies BEFORE the kill point (same reason as the
-                # plain decode: flipped table rows must never outrun
-                # their copies)
-                self._run_copies(copies)
+                with span("serving.decode.build") as sp:
+                    for s in active:
+                        copies += self.cache.ensure_decode_range(
+                            s, self.cache.slots[s].next_pos,
+                            int(wlen[s]))
+                    # COW copies BEFORE the kill point (same reason as
+                    # the plain decode: flipped table rows must never
+                    # outrun their copies)
+                    self._run_copies(copies)
+                    sp.set_attr("cow_copies", len(copies))
             # mid-verify-step kill point: drafts built, pages
             # claimed/COW'd, nothing emitted yet — recovery must
             # replay token-identically and leak no pages
@@ -1272,27 +1277,31 @@ class ServingEngine:
                 maybe_fail("serving.decode.sharded",
                            step=self._step_idx - 1,
                            tp=self.meshctx.tp)
-            with span("serving.verify", batch=len(active), k=K,
-                      request_ids=[self.cache.slots[s].rid
-                                   for s in active]):
-                if self.paged:
-                    logits, greedy, acc, ks, vs, kss, vss = \
-                        self._verify_fn()(
-                            self._params, self._buffers, toks, pos,
-                            mask, wlen, self.cache.page_table.copy(),
-                            self.cache.ks, self.cache.vs,
-                            self.cache.kss, self.cache.vss)
-                    self.cache.ks, self.cache.vs = list(ks), list(vs)
-                    self.cache.kss, self.cache.vss = \
-                        list(kss), list(vss)
-                else:
-                    logits, greedy, acc, ks, vs = self._verify_fn()(
-                        self._params, self._buffers, toks, pos, mask,
-                        wlen, self.cache.ks, self.cache.vs)
-                    self.cache.ks, self.cache.vs = list(ks), list(vs)
-                logits = np.asarray(jax.device_get(logits))
-                greedy = np.asarray(jax.device_get(greedy))
-                acc = np.asarray(jax.device_get(acc))
+            with self._decode_span("serving.verify", active, k=K):
+                with span("serving.decode.enqueue", batch=len(active)):
+                    if self.paged:
+                        logits, greedy, acc, ks, vs, kss, vss = \
+                            self._verify_fn()(
+                                self._params, self._buffers, toks,
+                                pos, mask, wlen,
+                                self.cache.page_table.copy(),
+                                self.cache.ks, self.cache.vs,
+                                self.cache.kss, self.cache.vss)
+                        self.cache.ks, self.cache.vs = \
+                            list(ks), list(vs)
+                        self.cache.kss, self.cache.vss = \
+                            list(kss), list(vss)
+                    else:
+                        logits, greedy, acc, ks, vs = \
+                            self._verify_fn()(
+                                self._params, self._buffers, toks,
+                                pos, mask, wlen,
+                                self.cache.ks, self.cache.vs)
+                        self.cache.ks, self.cache.vs = \
+                            list(ks), list(vs)
+                logits, greedy, acc = (
+                    self._fetch("serving.decode.fetch", x)
+                    for x in (logits, greedy, acc))
         except Exception:
             # a verify step that dies here (fault point, program
             # failure) never emitted a token, but ensure_decode_range
@@ -1312,28 +1321,27 @@ class ServingEngine:
             raise
         emitted_by_slot = {}
         try:
-            for s in active:
-                req = self.cache.slots[s]
-                emitted = self._emit_verified(
-                    s, req, greedy[s], int(acc[s]), logits[s],
-                    draft=row_draft.get(s), qs=row_qs.get(s))
-                emitted_by_slot[s] = emitted
-                self._spec["rows"] += 1
-                self._spec["emitted"] += emitted
-                self._spec["accepted_draft_tokens"] += emitted - 1
-                self._spec["acc_len_hist"][min(emitted, K)] += 1
-                self._m_spec_acc.labels(
-                    proposer=row_kind.get(s, "none")).observe(
-                        float(emitted))
-                if emitted > 1:
-                    self._m_spec_accepted.inc(emitted - 1)
-                if self.paged and not req.finished:
-                    # return pages past the next write position that
-                    # only rejected draft tokens touched (finished
-                    # rows release everything below)
-                    self.cache.rollback_speculation(s, req.next_pos)
-                if req.finished:
-                    self._evict(s, req, finished)
+            with span("serving.sample", rows=len(active)):
+                for s in active:
+                    req = self.cache.slots[s]
+                    emitted = self._emit_verified(
+                        s, req, greedy[s], int(acc[s]), logits[s],
+                        draft=row_draft.get(s), qs=row_qs.get(s))
+                    emitted_by_slot[s] = emitted
+                    self._spec["rows"] += 1
+                    self._spec["emitted"] += emitted
+                    self._spec["accepted_draft_tokens"] += emitted - 1
+                    self._spec["acc_len_hist"][min(emitted, K)] += 1
+                    self._m_spec_acc.labels(
+                        proposer=row_kind.get(s, "none")).observe(
+                            float(emitted))
+                    if self.paged and not req.finished:
+                        # return pages past the next write position that
+                        # only rejected draft tokens touched (finished
+                        # rows release everything below)
+                        self.cache.rollback_speculation(s, req.next_pos)
+                    if req.finished:
+                        self._evict(s, req, finished)
         except Exception:
             # a fault mid-emission (serving.spec.resample) leaves rows
             # not yet emitted this pass with over-claimed pages — the
@@ -1348,9 +1356,6 @@ class ServingEngine:
                             s, req.next_pos)
             raise
         self._spec["steps"] += 1
-        if self._spec["draft_tokens"]:
-            self._m_spec_hit.set(self._spec["accepted_draft_tokens"]
-                                 / self._spec["draft_tokens"])
         # feed the tuner every ATTEMPTED row's accepted length (an
         # empty draft reads as 1: speculation didn't pay on that row)
         self._tuner_step(attempted,
@@ -1500,7 +1505,6 @@ class ServingEngine:
             req.finished, req.finish_reason = True, "deadline"
             req.error = DeadlineExceeded(
                 req.rid, "expired while queued")
-            self._m_deadline.inc()
             self.metrics.on_finished(req.rid)
             finished.append(req)
         for s in self.cache.active_slots():
@@ -1510,7 +1514,6 @@ class ServingEngine:
                 req.error = DeadlineExceeded(
                     req.rid, f"expired in slot {s} after "
                              f"{len(req.out_tokens)} token(s)")
-                self._m_deadline.inc()
                 self._evict(s, req, finished)
 
     def _cancel_requested(self, req: Request) -> bool:
@@ -1545,7 +1548,6 @@ class ServingEngine:
         req.finished, req.finish_reason = True, "disconnect"
         req.error = exc if exc is not None \
             else RequestCancelled(req.rid, detail or "disconnect")
-        self._m_disconnect.inc()
         if finished is not None:
             self.metrics.on_finished(req.rid)
             finished.append(req)
@@ -1705,7 +1707,6 @@ class ServingEngine:
             self._proposer_retain(
                 r.rid for r in self.cache.slots if r is not None)
         self._broken = None
-        self._m_recover.inc()
         dt = self.metrics.now() - t0
         report = {"reason": reason,
                   "recovered_slots": len(todo),
@@ -1866,10 +1867,11 @@ class ServingEngine:
                                    cancel_check=True)
         self.cache.assign(slot, req)
         req.slot = slot
-        tok = sample_token(logits, req.sampling, req._rng)
-        req.out_tokens.append(tok)
-        self.metrics.on_token(req.rid)
-        self._is_finished(req, tok)
+        with span("serving.sample", rows=1):
+            tok = sample_token(logits, req.sampling, req._rng)
+            req.out_tokens.append(tok)
+            self.metrics.on_token(req.rid)
+            self._is_finished(req, tok)
 
     def _prefill_raw(self, slot: int, ids: np.ndarray,
                      request_id=None, req=None,
@@ -1901,7 +1903,8 @@ class ServingEngine:
             bucket = bucket_for(n, self.min_bucket, self.max_len)
             self._m_prefill.labels(bucket=bucket).inc()
             with span("serving.prefill", request_id=request_id,
-                      slot=slot, bucket=bucket, prompt_len=n,
+                      bucket=bucket, prompt_tokens=n,
+                      program="prefill",
                       replay=bool(req is not None and req.out_tokens)):
                 padded = np.zeros((1, bucket), np.int64)
                 padded[0, :n] = ids
@@ -1927,7 +1930,7 @@ class ServingEngine:
                         np.int32(n), np.int32(slot),
                         self.cache.ks, self.cache.vs)
                     self.cache.ks, self.cache.vs = list(ks), list(vs)
-            return np.asarray(jax.device_get(logits))
+                return self._fetch("serving.prefill.fetch", logits)
         cache = self.cache
         try:
             if req.rid not in cache._plans:
@@ -1967,7 +1970,8 @@ class ServingEngine:
             bucket = bucket_for(tail, self.min_bucket, self.max_len)
             self._m_prefill.labels(bucket=bucket).inc()
             with span("serving.prefill", request_id=request_id,
-                      slot=slot, bucket=bucket, prompt_len=n,
+                      bucket=bucket, prompt_tokens=n,
+                      program="extend" if start else "prefill",
                       shared_prefix=start,
                       replay=bool(req.out_tokens)):
                 padded = np.zeros((1, bucket), np.int64)
@@ -2004,8 +2008,8 @@ class ServingEngine:
                         cache.ks, cache.vs, cache.kss, cache.vss)
                     cache.ks, cache.vs = list(ks), list(vs)
                     cache.kss, cache.vss = list(kss), list(vss)
-            cache.register_prefix(slot, ids)
-            return np.asarray(jax.device_get(logits))
+                cache.register_prefix(slot, ids)
+                return self._fetch("serving.prefill.fetch", logits)
         except Exception:
             # the cross-group unwind: drop the staged prefill-side
             # span (if a handoff was in flight) AND any staged
@@ -2111,7 +2115,8 @@ class ServingEngine:
             bucket = bucket_for(t, self.min_bucket, self.max_len)
             self._m_prefill.labels(bucket=bucket).inc()
             with span("serving.chunk_prefill", request_id=req.rid,
-                      slot=slot, pos=pos, chunk=t, final=final,
+                      bucket=bucket, prompt_tokens=n,
+                      program="chunk", pos=pos, chunk=t, final=final,
                       replay=bool(req.out_tokens)):
                 padded = np.zeros((1, bucket), np.int64)
                 padded[0, :t] = ids[pos:pos + t]
@@ -2162,7 +2167,7 @@ class ServingEngine:
                 np.int32(pos), np.int32(t), np.int32(slot),
                 cache.ks, cache.vs)
             cache.ks, cache.vs = list(ks), list(vs)
-        return np.asarray(jax.device_get(logits))
+        return self._fetch("serving.prefill.fetch", logits)
 
     def _chunk_local_run(self, req: Request, padded, pos: int,
                          t: int) -> np.ndarray:
@@ -2171,7 +2176,7 @@ class ServingEngine:
             self._params_pf, self._buffers_pf, padded,
             np.int32(pos), np.int32(t), kb, vb)
         self._chunk_local[req.rid] = (list(kb2), list(vb2))
-        return np.asarray(jax.device_get(logits))
+        return self._fetch("serving.prefill.fetch", logits)
 
     def _chunk_finalize_handoff(self, slot: int, req: Request,
                                 n: int) -> None:
@@ -2204,11 +2209,12 @@ class ServingEngine:
                     and int(np.argmax(logits)) != req.out_tokens[-1]:
                 self._m_replay_mismatch.inc()
             return
-        tok = sample_token(logits, req.sampling, req._rng)
-        req.out_tokens.append(tok)
-        self.metrics.on_token(req.rid)
-        if self._is_finished(req, tok):
-            self._evict(slot, req, finished)
+        with span("serving.sample", rows=1):
+            tok = sample_token(logits, req.sampling, req._rng)
+            req.out_tokens.append(tok)
+            self.metrics.on_token(req.rid)
+            if self._is_finished(req, tok):
+                self._evict(slot, req, finished)
 
     def _clear_chunk_state(self, slot: int, req: Request) -> None:
         """Drop a PREFILLING request's chunk bookkeeping (fifo entry,
@@ -2286,6 +2292,33 @@ class ServingEngine:
             if new_caches[0][3] is not None else []
         return ks2, vs2, kss2, vss2
 
+    def _count_trace(self, kind: str, key=None) -> None:
+        """Called in the body of every engine program, so it runs only
+        while JAX traces it: one more compile of ``kind`` (for bucket
+        or shape ``key``) in ``trace_counts``, and a ``compile.<kind>``
+        event from the watched call around the trace."""
+        if key is None:
+            self.trace_counts[kind] += 1
+        else:
+            per_key = self.trace_counts[kind]
+            per_key[key] = per_key.get(key, 0) + 1
+        note_trace(kind, key)
+
+    def _jit(self, fn, **jit_kw):
+        """``jax.jit`` of one engine program under its stable name
+        (the device trace's module line reads ``jit_<name>``), its
+        compiles watched."""
+        return Watched(jax.jit(fn, **jit_kw), self.registry,
+                       sink=self._compiles)
+
+    def _fetch(self, name: str, x) -> np.ndarray:
+        """``device_get`` under its own span: the host WAITS for the
+        device here, so this time is not idle."""
+        with span(name) as sp:
+            out = np.asarray(jax.device_get(x))
+            sp.set_attr("bytes", out.nbytes)
+        return out
+
     def _prefill_fn(self):
         """Full-prompt prefill program, one compile per bucket length:
         run the prompt through a local [1, bucket] static cache, take
@@ -2309,8 +2342,7 @@ class ServingEngine:
 
         def local_run(params, buffers, ids, true_len):
             Lb = ids.shape[1]
-            self.trace_counts["prefill"][Lb] = \
-                self.trace_counts["prefill"].get(Lb, 0) + 1
+            self._count_trace("prefill", Lb)
             shape = (1, Lb, ad.kv_heads, ad.head_dim)
             local = [(jnp.zeros(shape, ad.dtype),
                       jnp.zeros(shape, ad.dtype), 0)
@@ -2327,7 +2359,7 @@ class ServingEngine:
 
         if not self.paged:
             if disagg:
-                def pure(params, buffers, ids, true_len):
+                def ptpu_prefill(params, buffers, ids, true_len):
                     logits, new_caches = local_run(params, buffers,
                                                    ids, true_len)
                     d = lambda c: getattr(c, "_data", c)
@@ -2336,12 +2368,12 @@ class ServingEngine:
                             [d(c[1]) for c in new_caches])
 
                 psh, bsh, R, kv, _ = self._prog_shardings("prefill")
-                self._prefill_jit = jax.jit(
-                    pure, in_shardings=(psh, bsh, R, R),
+                self._prefill_jit = self._jit(
+                    ptpu_prefill, in_shardings=(psh, bsh, R, R),
                     out_shardings=(R, kv, kv))
                 return self._prefill_jit
 
-            def pure(params, buffers, ids, true_len, slot, ks, vs):
+            def ptpu_prefill(params, buffers, ids, true_len, slot, ks, vs):
                 logits, new_caches = local_run(params, buffers, ids,
                                                true_len)
                 splice = lambda pool, c: jax.lax.dynamic_update_slice(
@@ -2356,7 +2388,7 @@ class ServingEngine:
                 psh, bsh, R, kv, _ = self._prog_shardings()
                 jit_kw = dict(in_shardings=(psh, bsh, R, R, R, kv, kv),
                               out_shardings=(R, kv, kv))
-            self._prefill_jit = jax.jit(pure,
+            self._prefill_jit = self._jit(ptpu_prefill,
                                         donate_argnums=self._donate(),
                                         **jit_kw)
             return self._prefill_jit
@@ -2374,7 +2406,7 @@ class ServingEngine:
             return paginate
 
         if disagg:
-            def pure(params, buffers, ids, true_len):
+            def ptpu_prefill(params, buffers, ids, true_len):
                 logits, new_caches = local_run(params, buffers, ids,
                                                true_len)
                 npg = (ids.shape[1] + P - 1) // P
@@ -2397,12 +2429,12 @@ class ServingEngine:
                 return logits, kb, vb, ksb, vsb
 
             psh, bsh, R, kv, sc = self._prog_shardings("prefill")
-            self._prefill_jit = jax.jit(
-                pure, in_shardings=(psh, bsh, R, R),
+            self._prefill_jit = self._jit(
+                ptpu_prefill, in_shardings=(psh, bsh, R, R),
                 out_shardings=(R, kv, kv, sc, sc))
             return self._prefill_jit
 
-        def pure(params, buffers, ids, true_len, page_ids, ks, vs,
+        def ptpu_prefill(params, buffers, ids, true_len, page_ids, ks, vs,
                  kss, vss):
             logits, new_caches = local_run(params, buffers, ids,
                                            true_len)
@@ -2431,8 +2463,8 @@ class ServingEngine:
             jit_kw = dict(
                 in_shardings=(psh, bsh, R, R, R, kv, kv, sc, sc),
                 out_shardings=(R, kv, kv, sc, sc))
-        self._prefill_jit = jax.jit(
-            pure, donate_argnums=self._donate_idx(5, 6, 7, 8),
+        self._prefill_jit = self._jit(
+            ptpu_prefill, donate_argnums=self._donate_idx(5, 6, 7, 8),
             **jit_kw)
         return self._prefill_jit
 
@@ -2458,11 +2490,10 @@ class ServingEngine:
                 in_shardings=(psh, bsh, R, R, R, R, kv, kv, sc, sc),
                 out_shardings=(R, kv, kv, sc, sc))
 
-        def pure(params, buffers, ids, start, true_tail, row, ks, vs,
+        def ptpu_extend(params, buffers, ids, start, true_tail, row, ks, vs,
                  kss, vss):
             Lb = ids.shape[1]
-            self.trace_counts["extend"][Lb] = \
-                self.trace_counts["extend"].get(Lb, 0) + 1
+            self._count_trace("extend", Lb)
             caches = self._paged_caches(ks, vs, kss, vss,
                                         row[None, :], start)
             with ad.model.bind_state(params, buffers):
@@ -2472,8 +2503,8 @@ class ServingEngine:
                 logits = ad.head(Tensor(h_last))._data[0, -1]
             return (logits,) + self._unpack_paged(new_caches)
 
-        self._extend_jit = jax.jit(
-            pure, donate_argnums=self._donate_idx(6, 7, 8, 9),
+        self._extend_jit = self._jit(
+            ptpu_extend, donate_argnums=self._donate_idx(6, 7, 8, 9),
             **jit_kw)
         return self._extend_jit
 
@@ -2509,11 +2540,10 @@ class ServingEngine:
                                   sc, sc),
                     out_shardings=(R, kv, kv, sc, sc))
 
-            def pure(params, buffers, ids, start, true_len, row, ks,
+            def ptpu_chunk(params, buffers, ids, start, true_len, row, ks,
                      vs, kss, vss):
                 Lb = ids.shape[1]
-                self.trace_counts["chunk"][Lb] = \
-                    self.trace_counts["chunk"].get(Lb, 0) + 1
+                self._count_trace("chunk", Lb)
                 caches = self._paged_caches(ks, vs, kss, vss,
                                             row[None, :], start)
                 with ad.model.bind_state(params, buffers):
@@ -2523,8 +2553,8 @@ class ServingEngine:
                     logits = ad.head(Tensor(h_last))._data[0, -1]
                 return (logits,) + self._unpack_paged(new_caches)
 
-            self._chunk_jit = jax.jit(
-                pure, donate_argnums=self._donate_idx(6, 7, 8, 9),
+            self._chunk_jit = self._jit(
+                ptpu_chunk, donate_argnums=self._donate_idx(6, 7, 8, 9),
                 **jit_kw)
             return self._chunk_jit
 
@@ -2535,10 +2565,9 @@ class ServingEngine:
                 in_shardings=(psh, bsh, R, R, R, R, kv, kv),
                 out_shardings=(R, kv, kv))
 
-        def pure(params, buffers, ids, start, true_len, slot, ks, vs):
+        def ptpu_chunk(params, buffers, ids, start, true_len, slot, ks, vs):
             Lb = ids.shape[1]
-            self.trace_counts["chunk"][Lb] = \
-                self.trace_counts["chunk"].get(Lb, 0) + 1
+            self._count_trace("chunk", Lb)
             rows = lambda pool: jax.lax.dynamic_slice(
                 pool, (slot, 0, 0, 0), (1,) + pool.shape[1:])
             wl = jnp.reshape(jnp.asarray(true_len, jnp.int32), (1,))
@@ -2556,8 +2585,8 @@ class ServingEngine:
             vs = [splice(p, c[1]) for p, c in zip(vs, new_caches)]
             return logits, ks, vs
 
-        self._chunk_jit = jax.jit(
-            pure, donate_argnums=self._donate_idx(6, 7), **jit_kw)
+        self._chunk_jit = self._jit(
+            ptpu_chunk, donate_argnums=self._donate_idx(6, 7), **jit_kw)
         return self._chunk_jit
 
     def _chunk_local_fn(self):
@@ -2572,11 +2601,10 @@ class ServingEngine:
             return self._chunk_local_jit
         ad = self.adapter
 
-        def pure(params, buffers, ids, start, true_len, kb, vb):
+        def ptpu_chunk(params, buffers, ids, start, true_len, kb, vb):
             Lb = ids.shape[1]
             key = ("local", Lb)
-            self.trace_counts["chunk"][key] = \
-                self.trace_counts["chunk"].get(key, 0) + 1
+            self._count_trace("chunk", key)
             wl = jnp.reshape(jnp.asarray(true_len, jnp.int32), (1,))
             caches = [(k, v, start, wl) for k, v in zip(kb, vb)]
             with ad.model.bind_state(params, buffers):
@@ -2589,8 +2617,8 @@ class ServingEngine:
             return logits, kb2, vb2
 
         psh, bsh, R, kv, _ = self._prog_shardings("prefill")
-        self._chunk_local_jit = jax.jit(
-            pure, in_shardings=(psh, bsh, R, R, R, kv, kv),
+        self._chunk_local_jit = self._jit(
+            ptpu_chunk, in_shardings=(psh, bsh, R, R, R, kv, kv),
             out_shardings=(R, kv, kv),
             donate_argnums=self._donate_idx(5, 6))
         return self._chunk_local_jit
@@ -2614,10 +2642,9 @@ class ServingEngine:
         kv = [m.kv_sharding("prefill")] * L
         sc = [m.scale_sharding("prefill")] * L if quant else []
 
-        def pure(kb, vb):
+        def ptpu_chunk_fin(kb, vb):
             key = ("fin", npg)
-            self.trace_counts["chunk"][key] = \
-                self.trace_counts["chunk"].get(key, 0) + 1
+            self._count_trace("chunk", key)
             kpg, vpg, kspg, vspg = [], [], [], []
             for k, v in zip(kb, vb):
                 kp = k[:, :npg * P].reshape(npg, P, *k.shape[2:])
@@ -2634,7 +2661,7 @@ class ServingEngine:
                     vpg.append(vp)
             return kpg, vpg, kspg, vspg
 
-        fn = jax.jit(pure, in_shardings=(kv, kv),
+        fn = self._jit(ptpu_chunk_fin, in_shardings=(kv, kv),
                      out_shardings=(kv, kv, sc, sc))
         self._chunk_fin_jit[npg] = fn
         return fn
@@ -2660,11 +2687,10 @@ class ServingEngine:
             if (self.paged and self.kv_quant) else []
 
         def count():
-            self.trace_counts["install"][key] = \
-                self.trace_counts["install"].get(key, 0) + 1
+            self._count_trace("install", key)
 
         if self.paged:
-            def pure(page_ids, kb, vb, ksb, vsb, ks, vs, kss, vss):
+            def ptpu_install(page_ids, kb, vb, ksb, vsb, ks, vs, kss, vss):
                 count()
                 ks = [p.at[page_ids].set(b.astype(p.dtype))
                       for p, b in zip(ks, kb)]
@@ -2676,20 +2702,20 @@ class ServingEngine:
                        for p, b in zip(vss, vsb)]
                 return ks, vs, kss, vss
 
-            fn = jax.jit(
-                pure,
+            fn = self._jit(
+                ptpu_install,
                 in_shardings=(R, kv, kv, sc, sc, kv, kv, sc, sc),
                 out_shardings=(kv, kv, sc, sc),
                 donate_argnums=self._donate_idx(5, 6, 7, 8))
         else:
-            def pure(slot, kb, vb, ks, vs):
+            def ptpu_install(slot, kb, vb, ks, vs):
                 count()
                 splice = lambda pool, b: jax.lax.dynamic_update_slice(
                     pool, b.astype(pool.dtype), (slot, 0, 0, 0))
                 return ([splice(p, b) for p, b in zip(ks, kb)],
                         [splice(p, b) for p, b in zip(vs, vb)])
 
-            fn = jax.jit(pure,
+            fn = self._jit(ptpu_install,
                          in_shardings=(R, kv, kv, kv, kv),
                          out_shardings=(kv, kv),
                          donate_argnums=self._donate_idx(3, 4))
@@ -2830,14 +2856,14 @@ class ServingEngine:
             jit_kw = dict(in_shardings=(R, R, kv, kv, sc, sc),
                           out_shardings=(kv, kv, sc, sc))
 
-        def pure(src, dst, ks, vs, kss, vss):
-            self.trace_counts["copy"] += 1
+        def ptpu_copy(src, dst, ks, vs, kss, vss):
+            self._count_trace("copy")
             cp = lambda pool: pool.at[dst].set(pool[src])
             return ([cp(p) for p in ks], [cp(p) for p in vs],
                     [cp(p) for p in kss], [cp(p) for p in vss])
 
-        self._copy_jit = jax.jit(
-            pure, donate_argnums=self._donate_idx(2, 3, 4, 5),
+        self._copy_jit = self._jit(
+            ptpu_copy, donate_argnums=self._donate_idx(2, 3, 4, 5),
             **jit_kw)
         return self._copy_jit
 
@@ -2850,8 +2876,8 @@ class ServingEngine:
         if self._promote_jit is not None:
             return self._promote_jit
 
-        def pure(dst, kb, vb, ksb, vsb, ks, vs, kss, vss):
-            self.trace_counts["promote"] += 1
+        def ptpu_promote(dst, kb, vb, ksb, vsb, ks, vs, kss, vss):
+            self._count_trace("promote")
             put = lambda pool, b: pool.at[dst].set(
                 b.astype(pool.dtype))
             return ([put(p, b) for p, b in zip(ks, kb)],
@@ -2859,8 +2885,8 @@ class ServingEngine:
                     [put(p, b) for p, b in zip(kss, ksb)],
                     [put(p, b) for p, b in zip(vss, vsb)])
 
-        self._promote_jit = jax.jit(
-            pure, donate_argnums=self._donate_idx(5, 6, 7, 8))
+        self._promote_jit = self._jit(
+            ptpu_promote, donate_argnums=self._donate_idx(5, 6, 7, 8))
         return self._promote_jit
 
     def _decode_fn(self):
@@ -2893,9 +2919,9 @@ class ServingEngine:
                               out_shardings=(R, kv, kv))
 
         if self.paged:
-            def pure(params, buffers, toks, pos, active, tables, ks,
+            def ptpu_decode(params, buffers, toks, pos, active, tables, ks,
                      vs, kss, vss):
-                self.trace_counts["decode"] += 1
+                self._count_trace("decode")
                 pos_eff = jnp.where(active, pos, 0).astype(jnp.int32)
                 tab_eff = jnp.where(active[:, None], tables, 0)
                 caches = self._paged_caches(ks, vs, kss, vss,
@@ -2906,15 +2932,15 @@ class ServingEngine:
                 logits = jnp.where(active[:, None], logits, 0.0)
                 return (logits,) + self._unpack_paged(new_caches)
 
-            self._decode_jit = jax.jit(
-                pure, donate_argnums=self._donate_idx(6, 7, 8, 9),
+            self._decode_jit = self._jit(
+                ptpu_decode, donate_argnums=self._donate_idx(6, 7, 8, 9),
                 **jit_kw)
             return self._decode_jit
 
         masked = self.prefill_chunk is not None
 
-        def pure(params, buffers, toks, pos, active, ks, vs):
-            self.trace_counts["decode"] += 1
+        def ptpu_decode(params, buffers, toks, pos, active, ks, vs):
+            self._count_trace("decode")
             pos_eff = jnp.where(active, pos, 0).astype(jnp.int32)
             if masked:
                 # chunked engines write-mask INACTIVE lanes: the plain
@@ -2937,8 +2963,8 @@ class ServingEngine:
             vs2 = [getattr(c[1], "_data", c[1]) for c in new_caches]
             return logits, ks2, vs2
 
-        self._decode_jit = jax.jit(pure, donate_argnums=self._donate(),
-                                   **jit_kw)
+        self._decode_jit = self._jit(
+            ptpu_decode, donate_argnums=self._donate(), **jit_kw)
         return self._decode_jit
 
     def _verify_fn(self):
@@ -2994,9 +3020,9 @@ class ServingEngine:
             return g, acc
 
         if self.paged:
-            def pure(params, buffers, toks, pos, active, wlen, tables,
+            def ptpu_verify(params, buffers, toks, pos, active, wlen, tables,
                      ks, vs, kss, vss):
-                self.trace_counts["verify"] += 1
+                self._count_trace("verify")
                 pos_eff = jnp.where(active, pos, 0).astype(jnp.int32)
                 wl_eff = jnp.where(active, wlen, 0).astype(jnp.int32)
                 tab_eff = jnp.where(active[:, None], tables, 0)
@@ -3011,13 +3037,13 @@ class ServingEngine:
                 return (logits, g, acc) \
                     + self._unpack_paged(new_caches)
 
-            self._verify_jit = jax.jit(
-                pure, donate_argnums=self._donate_idx(7, 8, 9, 10),
+            self._verify_jit = self._jit(
+                ptpu_verify, donate_argnums=self._donate_idx(7, 8, 9, 10),
                 **jit_kw)
             return self._verify_jit
 
-        def pure(params, buffers, toks, pos, active, wlen, ks, vs):
-            self.trace_counts["verify"] += 1
+        def ptpu_verify(params, buffers, toks, pos, active, wlen, ks, vs):
+            self._count_trace("verify")
             pos_eff = jnp.where(active, pos, 0).astype(jnp.int32)
             wl_eff = jnp.where(active, wlen, 0).astype(jnp.int32)
             caches = [(k, v, pos_eff, wl_eff)
@@ -3031,8 +3057,8 @@ class ServingEngine:
             vs2 = [getattr(c[1], "_data", c[1]) for c in new_caches]
             return logits, g, acc, ks2, vs2
 
-        self._verify_jit = jax.jit(
-            pure, donate_argnums=self._donate_idx(6, 7), **jit_kw)
+        self._verify_jit = self._jit(
+            ptpu_verify, donate_argnums=self._donate_idx(6, 7), **jit_kw)
         return self._verify_jit
 
     @staticmethod

@@ -41,7 +41,7 @@ import time
 from collections import deque
 from typing import Callable, Dict, List, Optional
 
-from ..observability import default_recorder, default_registry
+from ..observability import default_recorder, default_registry, span
 from ..resilience.faults import maybe_fail
 from .errors import (EngineClosed, QueueFull, RateLimited,
                      ServingError, Shed, TenantQueueFull)
@@ -385,7 +385,8 @@ class FrontDoor:
         """One front-door iteration: one backend step, then route
         tokens/results to client streams and audit deliveries. Returns
         the requests that reached the client this call."""
-        out = self._pump_locked()
+        with span("frontdoor.pump"):
+            out = self._pump_locked()
         # watchtower evaluation runs OUTSIDE the lock: between window
         # boundaries this is one clock read; at a boundary it reads
         # registry snapshots, which are internally synchronized
@@ -456,10 +457,14 @@ class FrontDoor:
                         return []
                 else:
                     return []
-            self._route_tokens()
-            out: List[Request] = []
-            for req in done:
-                self._finish(req, out)
+            with span("frontdoor.deliver") as dsp:
+                written = self._m_stream_ev.value
+                self._route_tokens()
+                out: List[Request] = []
+                for req in done:
+                    self._finish(req, out)
+                dsp.set_attr(
+                    "events", int(self._m_stream_ev.value - written))
             return out
 
     # requires-lock: _lock

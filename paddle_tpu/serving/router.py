@@ -425,7 +425,12 @@ class ReplicaRouter:
         failures). Returns every request delivered this round. Never
         raises out of a replica failure — a replica that cannot be
         saved is failed over, not surfaced as an exception."""
+        with span("router.step") as sp:
+            return self._step(sp)
+
+    def _step(self, sp) -> List[Request]:
         out: List[Request] = []
+        stepped = 0
         # _pending_out: delivery sink for requests surfacing OUTSIDE a
         # step (failover during submit-time probes would have no list
         # to land in) — step() always flushes it first
@@ -435,6 +440,7 @@ class ReplicaRouter:
         for rep in self.replicas:
             if not rep.live or not rep.engine.has_work():
                 continue
+            stepped += 1
             try:
                 done = rep.engine.step()
                 rep.step_failures = 0
@@ -470,6 +476,7 @@ class ReplicaRouter:
                 self._deliver(req, out)
             self._m_inflight.labels(replica=rep.id).set(rep.load())
         self._pending_out = []       # detach the sink
+        sp.set_attr("replicas_stepped", stepped)
         return out
 
     def _deliver(self, req: Request, out: List[Request]) -> None:

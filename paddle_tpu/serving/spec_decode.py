@@ -188,6 +188,7 @@ class DraftModelProposer:
         # to its own trace_counts dict so draft compiles surface as
         # trace_counts["draft"] next to decode/verify
         self.trace_counts = {"draft": 0}
+        self.registry = None      # the owning engine's, for the count
         self._jit = None
         self._ks = self._vs = None             # lazy [S, T, H, D] pools
         # rid -> {"slot": draft-pool slot, "n": confirmed tokens whose
@@ -253,10 +254,12 @@ class DraftModelProposer:
         import jax
         import jax.numpy as jnp
         from ..framework.tensor import Tensor
+        from ..utils.compile_cache import Watched, note_trace
         ad = self.adapter
 
-        def pure(params, buffers, toks, pos, active, wlen, ks, vs):
+        def ptpu_draft(params, buffers, toks, pos, active, wlen, ks, vs):
             self.trace_counts["draft"] += 1
+            note_trace("draft")
             pos_eff = jnp.where(active, pos, 0).astype(jnp.int32)
             wl_eff = jnp.where(active, wlen, 0).astype(jnp.int32)
             caches = [(k, v, pos_eff, wl_eff)
@@ -269,8 +272,9 @@ class DraftModelProposer:
             vs2 = [getattr(c[1], "_data", c[1]) for c in new_caches]
             return logits, ks2, vs2
 
-        self._jit = jax.jit(pure,
-                            donate_argnums=self._donate_idx(6, 7))
+        self._jit = Watched(
+            jax.jit(ptpu_draft, donate_argnums=self._donate_idx(6, 7)),
+            self.registry)
         return self._jit
 
     @staticmethod

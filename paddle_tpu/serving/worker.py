@@ -145,9 +145,11 @@ class WorkerServer:
         register_auth_failure_hook(self._on_auth_failure)
         # fresh buffer per engine incarnation: counters restart at 0,
         # which the host-side merger treats as a rebaseline (the
-        # supervisor calls telemetry.rebaseline after each reset)
+        # supervisor calls telemetry.rebaseline after each reset).
+        # An engine step writes about 13 records, so the default
+        # holds about 600 steps between two scrapes
         self._trace_buf = TraceBuffer(
-            capacity=int(self.spec.get("trace_capacity", 2048)),
+            capacity=int(self.spec.get("trace_capacity", 8192)),
             time_fn=self._now)
         install_trace_buffer(self._trace_buf)
         # flight ring spills to <spill_dir>/flight_<pid>.json every

@@ -418,17 +418,12 @@ def test_speculative_metrics_published():
     children = hist._sorted_children()
     assert sum(c.count for c in children) == st["rows"]
     assert sum(c.sum for c in children) == pytest.approx(st["emitted"])
-    assert reg.counter(
-        "ptpu_serving_spec_draft_tokens_total").value \
-        == st["draft_tokens"]
-    assert reg.counter(
-        "ptpu_serving_spec_accepted_draft_tokens_total").value \
-        == st["accepted_draft_tokens"]
-    assert reg.gauge("ptpu_serving_spec_draft_hit_rate").value \
-        == pytest.approx(st["draft_hit_rate"])
+    # draft totals and the hit rate are spec_stats()'s (PR 26 took the
+    # registry copies that nothing but this test read)
+    assert st["draft_tokens"] >= st["accepted_draft_tokens"] >= 0
     text = reg.to_prometheus()
     assert "# TYPE ptpu_serving_spec_accepted_length histogram" in text
-    assert "ptpu_serving_spec_draft_hit_rate" in text
+    assert "ptpu_serving_spec_draft_hit_rate" not in text
     # non-speculative engines do not grow the spec families
     reg2 = MetricRegistry()
     ServingEngine(model, max_slots=1, max_len=64, registry=reg2,
@@ -468,12 +463,10 @@ def test_chunked_prefill_metrics_and_spans(tmp_path):
 
     chunk_steps = reg.counter("ptpu_serving_chunk_steps_total").value
     assert chunk_steps >= 5                    # ceil(40/8) chunks
-    assert reg.gauge("ptpu_serving_chunk_queue_depth").value == 0
     stall = reg.histogram("ptpu_serving_decode_stall_seconds")
     assert stall.count >= 1                    # the short request
     text = reg.to_prometheus()
     assert "# TYPE ptpu_serving_chunk_steps_total counter" in text
-    assert "# TYPE ptpu_serving_chunk_queue_depth gauge" in text
     assert "# TYPE ptpu_serving_decode_stall_seconds histogram" in text
 
     trace_path = str(tmp_path / "trace.json")
@@ -495,8 +488,7 @@ def test_chunked_prefill_metrics_and_spans(tmp_path):
     reg2 = MetricRegistry()
     ServingEngine(model, max_slots=1, max_len=64, registry=reg2,
                   flight_recorder=FlightRecorder(capacity=4))
-    assert "ptpu_serving_chunk_steps_total" not in reg2.families()
-    assert "ptpu_serving_chunk_queue_depth" not in reg2.families()
+    assert not [f for f in reg2.families() if "chunk" in f]
 
 
 # -- acceptance: one serving run, three artifacts ----------------------
@@ -589,7 +581,9 @@ def test_one_run_three_artifacts(tmp_path):
     prefills = [e for e in evs if e["name"] == "serving.prefill"]
     assert {e["args"]["request_id"] for e in prefills} >= set(rids)
     decodes = [e for e in evs if e["name"] == "serving.decode"]
-    assert decodes and "request_ids" in decodes[0]["args"]
+    # batch spans carry the batch size; request ids ride along only
+    # where requests have trace contexts (cluster workers)
+    assert decodes and decodes[0]["args"]["batch"] >= 1
     assert [e for e in evs if e["name"] == "serving.step"]
 
     # artifact 3: a raising step dumps the flight recorder
@@ -614,7 +608,7 @@ def test_one_run_three_artifacts(tmp_path):
     for r in step_recs:
         assert {"step", "step_latency_s", "active_slots",
                 "queue_depth", "admitted", "evicted",
-                "compiles_decode", "compiles_prefill"} <= set(r)
+                "compiles"} <= set(r)
     # the virtual clock stamped the records: step latency is exactly
     # one 0.01 tick for every recorded step
     assert all(abs(r["step_latency_s"] - 0.01) < 1e-9
