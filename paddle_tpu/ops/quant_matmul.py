@@ -1,23 +1,46 @@
-"""Dynamic-quantized int8 matmul for TPU training forward passes.
+"""Dynamic-quantized int8 matmuls for TPU training: forward, dgrad, wgrad.
 
-The v5e MXU runs int8 x int8 -> int32 at ~2x the bf16 rate (measured
-294.8 vs 167.6 TOPS on [6144,2048]x[2048,8192]; the rounds-1-5 notes (git
-history before PR 23)).
-``int8_linear`` exploits that for the *forward* matmul only:
+The v5e MXU runs int8 x int8 -> int32 at twice the bf16 rate (393 TOP/s
+against 197 TFLOP/s published; the block dots of the flagship step
+measure 79-96% of that, PERF.md section 5). Five recipes, each a
+``custom_vjp`` over the same forward, differing in how much of the
+backward is also int8:
 
-  forward:  per-row activation scales + per-column weight scales
-            (symmetric, dynamic — no calibration), int8 MXU matmul,
-            fused dequant epilogue back to the activation dtype;
-  backward: exact bf16 dgrad/wgrad via custom_vjp (a straight-through
-            estimator w.r.t. the quantization rounding), so optimizer
-            updates see full-precision gradients.
+  ``int8_linear``          forward int8 (per-row activation scales,
+                           per-column weight scales, symmetric, dynamic,
+                           no calibration; dequant fused as the epilogue);
+                           dgrad and wgrad exact in the input dtype (a
+                           straight-through estimator w.r.t. the rounding).
+  ``int8_linear_dgrad8``   + the activation gradient on the int8 MXU
+                           (round-to-nearest per-row scales on the
+                           cotangent and on w's contraction dim).
+  ``int8_linear_all8``     + the WEIGHT gradient int8 too: both operands
+                           quantized along the token axis with STOCHASTIC
+                           rounding, so each quantization is unbiased and
+                           the noise integrates to zero in Adam's moments
+                           instead of drifting. This is the flagship's
+                           recipe (``GPTSpmdTrainer(quant8="wgrad")``).
+  ``int8_gelu_linear_all8``, ``int8_ln_linear_all8``
+                           ``all8`` with the producer (gelu, LayerNorm)
+                           computed inside the quantize kernels.
+
+The quantizers are single-pass Pallas kernels on a single-device TPU
+program and XLA elsewhere (the same arithmetic; the SR quantizers
+draw another random stream there). The weight-gradient
+contraction ``dequant(xq^T @ gq)`` contracts the major axis of both
+operands, a form XLA's dot fusion runs at anything between a third and
+86% of the int8 peak; where the Pallas quantizer runs, the left operand
+is quantized straight into [K, M] and the contraction is the plain
+``[K, M] @ [M, N]`` of the forward dots (``_wgrad_form``, counted per
+traced site in ``ptpu_int8_wgrad_sites_total{form}``). Either form
+accumulates exactly in int32 and applies the same f32 scales in the
+same order: the bits do not depend on the form.
 
 Reference behavior analog: the reference's QAT fake-quant linear
 (python/paddle/nn/quant/qat/linear.py) simulates int8 in fp32; this is
-the TPU-native real-int8 version that actually engages the int8 MXU
-path. W8A8 with per-row/per-channel scales keeps per-matmul relative
-error at the same order as bf16 rounding; bench_gpt_hybrid measures
-end-to-end loss parity (see the rounds-1-5 notes (git history before PR 23)).
+the TPU-native real-int8 version that engages the int8 MXU path.
+Loss parity of each recipe: the rounds-1-5 notes (git history before
+PR 23) and ``benchmarks/parity_int8.py``.
 """
 from __future__ import annotations
 
@@ -411,7 +434,8 @@ int8_linear_dgrad8.defvjp(_fwd8, _bwd8)
 # seed, drawn in-kernel from the TPU hardware PRNG (no HBM rng buffer —
 # the XLA lowering would write+read a full uint32 buffer per operand).
 
-def _colq_sr_kernel(seed_ref, x_ref, q_ref, s_ref, *, act=None):
+def _colq_sr_kernel(seed_ref, x_ref, q_ref, s_ref, *, act=None,
+                    transposed=False):
     from jax.experimental.pallas import tpu as pltpu
     x = _apply_act(x_ref[...].astype(jnp.float32), act)    # [M, bn]
     amax = jnp.max(jnp.abs(x), axis=0, keepdims=True)
@@ -420,32 +444,40 @@ def _colq_sr_kernel(seed_ref, x_ref, q_ref, s_ref, *, act=None):
     bits = pltpu.prng_random_bits(x.shape).astype(jnp.uint32)
     f = jax.lax.bitcast_convert_type(
         jnp.uint32(0x3F800000) | (bits >> 9), jnp.float32)
-    q_ref[...] = jnp.clip(jnp.floor(x / scale + (f - 1.0)),
-                          -127, 127).astype(jnp.int8)
+    q = jnp.clip(jnp.floor(x / scale + (f - 1.0)), -127, 127)
+    # the transpose runs on the f32 tile, before the cast: same values
+    # and the same random stream, written as [bn, M]
+    q_ref[...] = (q.T if transposed else q).astype(jnp.int8)
     s_ref[...] = scale
 
 
-@functools.partial(jax.jit, static_argnums=(2, 3))
-def _sr_colq_pallas(x2, seed_i, interpret, act=None):
+@functools.partial(jax.jit, static_argnums=(2, 3, 4))
+def _sr_colq_pallas(x2, seed_i, interpret, act=None, transposed=False):
     """Column-wise (per output channel) symmetric int8 SR quantize of
     [M, C] in ONE read of x: full-column blocks (M x 128 lanes) hold
     the whole reduction in VMEM, so amax, SR bits, and the cast happen
     in a single pass — the XLA lowering is a convert+abs+reduce pass
     PLUS a re-reading cast pass (~33 ms/step of abs_reduce fusions on
-    the GPT-1.3B step before this kernel)."""
+    the GPT-1.3B step before this kernel). ``transposed`` writes the
+    int8 values as [C, M] (the scales stay [1, C]): the layout the
+    weight gradient's left operand wants (``_wgrad_form``), at the cost
+    of the plain kernel (0.056 against 0.055 ms at [6144, 2048], 0.343
+    against 0.318 at [6144, 8192])."""
     M, C = x2.shape
     # f32 temps are M*bn*4 and several are live at once (x, bits, u,
     # q-pre-cast) plus double-buffered IO: ~4.5 copies must fit the
     # 16M scoped-vmem budget
     bn = 256 if (C % 256 == 0 and M * 256 * 4 * 9 // 2 <= (15 << 20)) \
         else 128
+    q_block, q_shape = (pl.BlockSpec((bn, M), lambda j: (j, 0)), (C, M)) \
+        if transposed else (pl.BlockSpec((M, bn), lambda j: (0, j)), (M, C))
     kernel = pl.pallas_call(
-        functools.partial(_colq_sr_kernel, act=act), grid=(C // bn,),
+        functools.partial(_colq_sr_kernel, act=act, transposed=transposed),
+        grid=(C // bn,),
         in_specs=[pl.BlockSpec(memory_space=pltpu_smem()),
                   pl.BlockSpec((M, bn), lambda j: (0, j))],
-        out_specs=[pl.BlockSpec((M, bn), lambda j: (0, j)),
-                   pl.BlockSpec((1, bn), lambda j: (0, j))],
-        out_shape=[jax.ShapeDtypeStruct((M, C), jnp.int8),
+        out_specs=[q_block, pl.BlockSpec((1, bn), lambda j: (0, j))],
+        out_shape=[jax.ShapeDtypeStruct(q_shape, jnp.int8),
                    jax.ShapeDtypeStruct((1, C), jnp.float32)],
         interpret=interpret)
     return kernel(seed_i.reshape(1), x2)
@@ -471,15 +503,83 @@ def _sr_colq_xla(x2, seed_i, act=None):
     return q, scale
 
 
-def sr_quantize_colwise(x2, seed_i, act=None):
+def _sr_colq_pallas_ok(M: int, C: int) -> bool:
+    """Whether ``sr_quantize_colwise`` runs its Pallas kernel on an
+    [M, C] operand: a single-device TPU program, lane-aligned columns,
+    and a full-column block that fits the scoped VMEM."""
+    return single_device_tpu() \
+        and C % 128 == 0 and M % 8 == 0 \
+        and M * 128 * 4 * 9 // 2 <= (15 << 20)
+
+
+def sr_quantize_colwise(x2, seed_i, act=None, transposed=False):
     """Unbiased int8 quantize of [M, C] with per-column scales;
-    ``act`` fuses an activation before quantization (one read)."""
-    M, C = x2.shape
-    if single_device_tpu() \
-            and C % 128 == 0 and M % 8 == 0 \
-            and M * 128 * 4 * 9 // 2 <= (15 << 20):
-        return _sr_colq_pallas(x2, seed_i, False, act)
-    return _sr_colq_xla(x2, seed_i, act)
+    ``act`` fuses an activation before quantization (one read);
+    ``transposed`` returns the int8 values as [C, M]."""
+    if _sr_colq_pallas_ok(*x2.shape):
+        return _sr_colq_pallas(x2, seed_i, False, act, transposed)
+    q, s = _sr_colq_xla(x2, seed_i, act)
+    return (q.T if transposed else q), s
+
+
+# ---------------------------------------------------------------------------
+# the int8 weight-gradient contraction (PR 28)
+# ---------------------------------------------------------------------------
+# dw[K, N] = dequant(xq[M, K]^T @ gq[M, N]) contracts the MAJOR axis
+# (tokens) of both operands. XLA's dot fusion runs that form at 82-86% of
+# the int8 peak at its best and at 34.5% at its worst: in the flagship
+# step (v5e, M=6144) ffn1's [2048, 8192] gradient took 1.519 ms against
+# 0.608 for ffn2's [8192, 2048], the same two operands in the other
+# order, and alone in a program the two shapes trade places (0.64 and
+# 1.54 ms); swapping the operands in the step changes nothing (1.516 ms).
+# What the forward and dgrad dots run at 90-93% everywhere is the plain
+# [K, M] @ [M, N]. So the left operand's SR quantize kernel writes its
+# int8 values transposed (form ``km``; an in-graph transpose would only
+# be folded back into the dot's layout): in the step 0.548 / 0.425 /
+# 0.560 ms a call at ffn1 / qkv / ffn2 against 1.519 / 0.478 / 0.608.
+# The arithmetic never changes: the same quantized values, an exact int32
+# accumulation (M * 127^2 < 2^31), then int32 -> f32, times the K-side
+# scale, times the N-side scale, then the cast, so both forms give the
+# same bits. The form is chosen from the operands' shapes and the
+# program's device count, at trace time, and counted there.
+
+def _wgrad_form(M: int, K: int) -> str:
+    """The form of the contraction for the weight gradient of a [K, N]
+    matrix over M tokens: ``km`` (left operand quantized straight into
+    [K, M]) where the Pallas SR quantize kernel runs on it and M fills
+    whole lanes of its transposed block; ``kn`` (XLA's token-major dot)
+    elsewhere. N has no say: ``km`` measured faster at every ratio the
+    flagship has (N = 3K, N = 4K, K = 4N); PERF.md section 6, PR 28."""
+    return "km" if _sr_colq_pallas_ok(M, K) and M % 128 == 0 else "kn"
+
+
+def _wgrad_int8(xq, xs, gq, gs, out_dtype, form):
+    """``dequant(xq^T @ gq)`` [K, N] of SR-quantized operands: ``gq``
+    [M, N] with column scales ``gs``; ``xq`` is [M, K] in form ``kn``
+    and [K, M] in form ``km`` (column scales ``xs`` either way). Counts
+    the traced site under its form."""
+    from ..observability.registry import default_registry
+    default_registry().counter(
+        "ptpu_int8_wgrad_sites_total",
+        "int8 weight-gradient sites traced, by the form of the "
+        "contraction their shapes chose",
+        labels=("form",)).labels(form=form).inc()
+    K, N = xs.size, gs.size
+    return int8_dot_dequant(
+        xq, xs.reshape(K, 1), gq, gs.reshape(1, N),
+        ((1,) if form == "km" else (0,), (0,)), out_dtype=out_dtype)
+
+
+def _wgrad_all8(x2, g2, seed, out_dtype, act=None):
+    """The SR int8 weight gradient of ``act(x2)`` [M, K] and ``g2``
+    [M, N]: both quantized along the tokens, streams decorrelated per
+    operand from the site's ``seed``."""
+    base = jnp.asarray(seed, jnp.int32) * jnp.int32(1000003)
+    form = _wgrad_form(*x2.shape)
+    xq, xs = sr_quantize_colwise(x2, base + jnp.int32(7919), act,
+                                 transposed=form == "km")
+    gq, gs = sr_quantize_colwise(g2, base + jnp.int32(104729))
+    return _wgrad_int8(xq, xs, gq, gs, out_dtype, form)
 
 
 @jax.custom_vjp
@@ -509,18 +609,10 @@ def _bwd_all8(res, g):
     dx = (y.astype(jnp.float32) * gs *
           jnp.reshape(ws, (1,) * (g.ndim - 1) + (-1,)))
     # wgrad: int8 with SR quantization along the contraction (tokens)
-    K = x.shape[-1]
-    N = g.shape[-1]
-    x2 = x.reshape(-1, K)
-    g2 = g.reshape(-1, N)
-    base = jnp.asarray(seed, jnp.int32) * jnp.int32(1000003)
-    xq, xs = sr_quantize_colwise(x2, base + jnp.int32(7919))
-    gq2, gs2 = sr_quantize_colwise(g2, base + jnp.int32(104729))
-    dwi = jax.lax.dot_general(xq, gq2, (((0,), (0,)), ((), ())),
-                              preferred_element_type=jnp.int32)
-    dw = dwi.astype(jnp.float32) * xs.reshape(K, 1) * gs2  # [K,N]
+    dw = _wgrad_all8(x.reshape(-1, x.shape[-1]),
+                     g.reshape(-1, g.shape[-1]), seed, w.dtype)  # [K,N]
     import numpy as np
-    return (dx.astype(x.dtype), dw.astype(w.dtype),
+    return (dx.astype(x.dtype), dw,
             np.zeros((), jax.dtypes.float0))
 
 
@@ -564,19 +656,11 @@ def _bwd_gelu_all8(res, g):
         x)
     dx = gelu_vjp(da)[0]
     # wgrad: SR int8 of a = gelu(x), fused in the colq kernel
-    K = x.shape[-1]
-    N = g.shape[-1]
-    x2 = x.reshape(-1, K)
-    g2 = g.reshape(-1, N)
-    base = jnp.asarray(seed, jnp.int32) * jnp.int32(1000003)
-    aq, as_ = sr_quantize_colwise(x2, base + jnp.int32(7919),
-                                  act="gelu")
-    gq2, gs2 = sr_quantize_colwise(g2, base + jnp.int32(104729))
-    dwi = jax.lax.dot_general(aq, gq2, (((0,), (0,)), ((), ())),
-                              preferred_element_type=jnp.int32)
-    dw = dwi.astype(jnp.float32) * as_.reshape(K, 1) * gs2
+    dw = _wgrad_all8(x.reshape(-1, x.shape[-1]),
+                     g.reshape(-1, g.shape[-1]), seed, w.dtype,
+                     act="gelu")
     import numpy as np
-    return (dx.astype(x.dtype), dw.astype(w.dtype),
+    return (dx.astype(x.dtype), dw,
             np.zeros((), jax.dtypes.float0))
 
 
@@ -667,21 +751,18 @@ def _bwd_ln_all8(fuse_bwd_colq, res, gy):
     # kernel — the bwd then matches the unfused path op-for-op (A/B
     # isolation knob).
     g2 = gy.reshape(-1, N)
-    base = jnp.asarray(seed, jnp.int32) * jnp.int32(1000003)
     if fuse_bwd_colq:
         m, r = stats
+        base = jnp.asarray(seed, jnp.int32) * jnp.int32(1000003)
         hq, hs = sr_quantize_colwise_ln(x.reshape(-1, K), m, r,
                                         g_ln, b_ln,
                                         base + jnp.int32(7919))
+        gq2, gs2 = sr_quantize_colwise(g2, base + jnp.int32(104729))
+        dw = _wgrad_int8(hq, hs, gq2, gs2, w.dtype, "kn")
     else:
-        hq, hs = sr_quantize_colwise(h.reshape(-1, K),
-                                     base + jnp.int32(7919))
-    gq2, gs2 = sr_quantize_colwise(g2, base + jnp.int32(104729))
-    dwi = jax.lax.dot_general(hq, gq2, (((0,), (0,)), ((), ())),
-                              preferred_element_type=jnp.int32)
-    dw = dwi.astype(jnp.float32) * hs.reshape(K, 1) * gs2
+        dw = _wgrad_all8(h.reshape(-1, K), g2, seed, w.dtype)
     import numpy as np
-    return (dx, dg_ln, db_ln, dw.astype(w.dtype),
+    return (dx, dg_ln, db_ln, dw,
             np.zeros((), jax.dtypes.float0))
 
 

@@ -173,6 +173,28 @@ def test_quantize_kernel_compiles_for_v5e(kernel, shape, one_chip):
     assert n == 1, n
 
 
+# -- the int8 weight-gradient contraction at the 1.3B sites (PR 28) -------
+
+@pytest.mark.parametrize("K,N", [(2048, 6144), (2048, 8192), (8192, 2048)],
+                         ids=["qkv", "ffn1", "ffn2"])
+def test_int8_wgrad_form_compiles_for_v5e(K, N, one_chip, monkeypatch):
+    """The form the rule chooses on the chip for each flagship site
+    (``km``: the left operand's SR quantize kernel writes [K, M])
+    compiles there at M = 6 x 1024 tokens: the two quantize kernels and
+    a dot that contracts the MINOR axis of its int8 left operand."""
+    from paddle_tpu.ops import quant_matmul as qm
+    # single_device_tpu() asks the backend, which is the CPU here
+    monkeypatch.setattr(qm, "single_device_tpu", lambda: True)
+    M = 6144
+    assert qm._wgrad_form(M, K) == "km"
+    txt = jax.jit(
+        lambda x, g, seed: qm._wgrad_all8(x, g, seed, BF16)).lower(
+            one_chip((M, K)), one_chip((M, N)),
+            one_chip((), I32)).compile().as_text()
+    assert txt.count('custom_call_target="tpu_custom_call"') == 2
+    assert f"s8[{K},{M}]" in txt and f"s8[{M},{K}]" not in txt
+
+
 # -- the server's paged decode-attention step, TinyLlama-1.1B widths ------
 
 def test_paged_decode_attention_compiles_for_v5e(one_chip):

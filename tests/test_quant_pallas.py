@@ -321,3 +321,75 @@ def test_sr_colq_ln_matches_ln_then_colq():
     # differences only at float boundaries
     dq = np.abs(np.asarray(q1, np.int32) - np.asarray(q2, np.int32))
     assert dq.max() <= 1 and (dq != 0).mean() < 0.01
+
+
+# -- PR 28: the form of the int8 weight-gradient contraction --------------
+
+def _wgrad_count(form):
+    from paddle_tpu.observability.registry import default_registry
+    fam = default_registry().get("ptpu_int8_wgrad_sites_total")
+    return 0.0 if fam is None else fam.labels(form=form).value
+
+
+@pytest.mark.parametrize("form", ["rule", "km"])
+@pytest.mark.parametrize("K,N", [(128, 384), (128, 512), (512, 128)],
+                         ids=["K<N", "K<<N", "K>>N"])
+@pytest.mark.parametrize("site", ["linear", "gelu_linear", "ln_linear"])
+def test_int8_wgrad_form_bit_identical(site, K, N, form, monkeypatch):
+    """In either form the weight gradient of every all-int8 site is the
+    reference ``dot_general(xq, gq, contract (0),(0))`` dequantised as
+    before PR 28, bit for bit, and the counter reports the form taken:
+    the one the shape rule promises (``rule``; on this backend ``kn``),
+    or ``km`` with the left operand handed over as [K, M] (the XLA
+    quantizer transposed stands in for the kernel, which needs the
+    chip's PRNG; tests/test_chip_compile.py compiles the kernel)."""
+    from paddle_tpu.ops import quant_matmul as qm
+    M = 64
+    if form == "rule":
+        form = qm._wgrad_form(M, K)
+    else:
+        monkeypatch.setattr(qm, "_wgrad_form", lambda M, K: form)
+    seen = []
+    inner = qm._wgrad_int8
+
+    def recording(xq, xs, gq, gs, out_dtype, form):
+        dw = inner(xq, xs, gq, gs, out_dtype, form)
+        seen.append((xq, xs, gq, gs, form, dw))
+        return dw
+
+    monkeypatch.setattr(qm, "_wgrad_int8", recording)
+    rng = np.random.RandomState(K + N)
+    x = jnp.asarray(rng.randn(2, M // 2, K).astype(np.float32))
+    w = jnp.asarray(rng.randn(K, N).astype(np.float32) * 0.1)
+    g_ln = jnp.asarray(rng.rand(K).astype(np.float32) + 0.5)
+    b_ln = jnp.asarray(rng.randn(K).astype(np.float32) * 0.1)
+    seed = jnp.int32(11)
+    fn = {"linear": lambda w: qm.int8_linear_all8(x, w, seed),
+          "gelu_linear": lambda w: qm.int8_gelu_linear_all8(x, w, seed),
+          "ln_linear": lambda w: qm.int8_ln_linear_all8(
+              x, g_ln, b_ln, w, seed)}[site]
+    before = _wgrad_count(form)
+    dw = jax.grad(lambda w: (fn(w) ** 2).sum())(w)
+    assert _wgrad_count(form) == before + 1
+    (xq, xs, gq, gs, taken, got), = seen
+    assert taken == form
+    assert xq.shape == ((K, M) if form == "km" else (M, K))
+    assert gq.shape == (M, N)
+    y = jax.lax.dot_general(xq.T if form == "km" else xq, gq,
+                            (((0,), (0,)), ((), ())),
+                            preferred_element_type=jnp.int32)
+    ref = (y.astype(jnp.float32) * xs.reshape(K, 1) * gs).astype(w.dtype)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+    np.testing.assert_array_equal(np.asarray(dw), np.asarray(ref))
+
+
+def test_int8_wgrad_form_rule(monkeypatch):
+    """``km`` exactly where the Pallas SR quantizer runs on the left
+    operand [M, K] and M fills whole lanes."""
+    from paddle_tpu.ops import quant_matmul as qm
+    assert {qm._wgrad_form(6144, K) for K in (2048, 8192)} == {"kn"}
+    monkeypatch.setattr(qm, "single_device_tpu", lambda: True)
+    assert {qm._wgrad_form(6144, K) for K in (2048, 8192)} == {"km"}
+    assert qm._wgrad_form(6144 + 8, 2048) == "kn"    # M % 128
+    assert qm._wgrad_form(6144, 2048 + 64) == "kn"   # K % 128
+    assert qm._wgrad_form(16384, 2048) == "kn"       # block > VMEM
