@@ -15,6 +15,14 @@ import sys
 from .. import manifest, reference, seeding
 from ..setup_marks import mark
 
+# the selftest's sizes: the shapes bench.build_flagship() builds on the
+# CPU backend
+TINY = {"kind": "gpt_trainer", "recipe": "bench.build_flagship",
+        "model": {"vocab_size": 1024, "hidden_size": 128, "num_layers": 2,
+                  "num_heads": 4, "head_dim": 32, "ffn_mult": 4,
+                  "max_seq_len": 128},
+        "batch": 4, "chips": 1, "mesh": {}}
+
 SHAPE_KEYS = ("vocab_size", "hidden_size", "num_layers", "num_heads",
               "max_seq_len", "ffn_mult")
 

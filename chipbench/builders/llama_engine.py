@@ -22,35 +22,59 @@ MODEL_KEYS = ("vocab_size", "hidden_size", "intermediate_size",
               "rms_norm_eps", "rope_theta", "tie_word_embeddings")
 
 
+# the selftest's sizes (CPU, float32: the engine and the plain reference
+# then agree to rounding)
+TINY = {
+    "kind": "llama_engine", "dtype": "float32",
+    "model": {"vocab_size": 512, "hidden_size": 64,
+              "intermediate_size": 128, "num_hidden_layers": 2,
+              "num_attention_heads": 4, "num_key_value_heads": 2,
+              "head_dim": 16, "max_position_embeddings": 512,
+              "rms_norm_eps": 1e-5, "rope_theta": 1e6,
+              "tie_word_embeddings": False},
+    "chips": 1, "mesh": {},
+    "engine": {"max_slots": 4, "max_len": 128, "kv_layout": "paged",
+               "page_size": 16}}
+
+# prompt + outputs of a request are padded to a multiple of this for the
+# reference, so that one run compiles one or two programs for it
+REFERENCE_PAD = 512
+
+
 class LlamaSystem:
-    def __init__(self, model, cfg, engine, front, chips: int):
+    def __init__(self, model, cfg, weights, engine, front, chips: int):
         self.model, self.cfg = model, cfg
+        self.weights = weights      # name -> array, drawn by seeding.redraw
         self.engine, self.front = engine, front
         self.vocab = int(cfg.vocab_size)
         self.chips = int(chips)
+        # parameters a token's hidden state is multiplied by in the
+        # layers, and in the head (the embedding is a row read)
+        self.head_params = int(weights["lm_head.weight"].size)
+        self.layer_params = sum(int(a.size) for a in weights.values()) \
+            - int(weights["llama.embed_tokens.weight"].size) \
+            - self.head_params
 
     def programs(self) -> int:
         """Programs the engine has traced so far, of every kind."""
         return sum(sum(v.values()) if isinstance(v, dict) else int(v)
                    for v in self.engine.trace_counts.values())
 
-    def logit_deficits(self, prompt, outputs) -> list:
-        """For each generated token: how far the plain reference's logit
-        of the token the engine chose lies under the reference's largest
-        logit at that position, in standard deviations of that
-        position's logits (0 = the reference agrees)."""
-        ids = np.concatenate([np.asarray(prompt, np.int64),
-                              np.asarray(outputs, np.int64)])
-        c, n = self.cfg, len(outputs)
-        params, _ = self.model.raw_state()
-        # logits at position p predict token p + 1: the rows that
-        # predicted the n outputs are the n before the last
-        logits = reference.llama_logits(
-            params, ids[:-1], layers=c.num_hidden_layers,
+    def free(self) -> None:
+        """Drop the engine with its cache pool and the front door; the
+        benchmark's weights stay for the reference."""
+        import gc
+        self.engine = self.front = None
+        gc.collect()
+
+    def served_gaps(self, prompt, outputs, control: bool = False):
+        """``reference.llama_served_gaps`` at this system's sizes."""
+        c = self.cfg
+        return reference.llama_served_gaps(
+            self.weights, prompt, outputs, pad_to=REFERENCE_PAD,
+            control=control, layers=c.num_hidden_layers,
             heads=c.num_attention_heads, kv_heads=c.kv_heads,
-            eps=c.rms_norm_eps, theta=c.rope_theta, last=n)
-        chosen = logits[np.arange(n), np.asarray(outputs)]
-        return list((logits.max(axis=1) - chosen) / logits.std(axis=1))
+            eps=c.rms_norm_eps, theta=c.rope_theta)
 
 
 def _assemble(cfg, dtype: str):
@@ -113,4 +137,4 @@ def build(config: dict, seed: int) -> LlamaSystem:
     front = FrontDoor(ReplicaRouter([engine], registry=front_registry),
                       registry=front_registry)
     mark("engine_built")
-    return LlamaSystem(model, cfg, engine, front, chips)
+    return LlamaSystem(model, cfg, drawn, engine, front, chips)

@@ -4,6 +4,12 @@ from __future__ import annotations
 
 from .. import serving_loop
 
+# what the selftest lays over the cell's traffic file
+TINY = {"prompt_tokens": {"log_uniform": [8, 64]},
+        "output_tokens": {"log_uniform": [4, 24]},
+        "clients": 8, "block": 8, "ramp_seconds": 0.3,
+        "verify_requests": 4, "trace_seconds": 0.3}
+
 
 def run(system, traffic: dict, seed: int, seconds: float, tracer) -> dict:
     clients = int(traffic["clients"])

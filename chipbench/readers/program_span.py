@@ -21,16 +21,10 @@ spans in the phase: ms of sampling a ``serving.step``), times
 program from before PR 26) or no such span, and where the driver took
 nothing on the host's clock (no window to cut); a driver that gives no
 ``window_s`` leaves the window open at its end.
-
-A package and not ``program_span.py`` on purpose: ``selftest.
-check_readers`` holds a fixed dict of cases, fails on a ``readers/*.py``
-without one, and is a file only a ``benchmark`` PR may edit. Until one
-adds the case (PERF.md, Open questions), this reader's cases are
-``tests/test_program_spans.py``'s.
 """
 import sys
 
-from ...setup_marks import MARKS, T0
+from ..setup_marks import MARKS, T0
 
 
 def _phase(name: str, host: dict):
@@ -86,3 +80,10 @@ def read(args: dict, obs: dict):
         per = len(query(args["per"], *phase)["spans"])
         return sum(values) / per * scale if per else None
     raise SystemExit(f"chipbench: program_span: unknown stat {stat!r}")
+
+
+# its cases with numbers need the program's ring on a fake clock and are
+# tests/test_program_spans.py's (eight of them); here only that a span
+# nobody recorded reads nothing
+SELFTEST_CASE = ({"span": "no.such.span", "phase": "window",
+                  "stat": "count"}, {}, None)

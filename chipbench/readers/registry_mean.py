@@ -7,3 +7,7 @@ def read(args: dict, obs: dict):
     if not h or not h["count"]:
         return None
     return h["sum"] / h["count"] * args.get("scale", 1.0)
+
+
+SELFTEST_CASE = ({"family": "fam", "scale": 1000.0},
+                 {"registry": {"fam": {"sum": 3.0, "count": 60}}}, 50.0)

@@ -86,11 +86,22 @@ def _union(intervals: List[Tuple[int, int]]) -> List[List[int]]:
     return merged
 
 
+MODULE_LINE = "XLA Modules"
+
+
+def module_symbol(event_name: str) -> str:
+    """``jit_ptpu_decode(1234)`` -> ``jit_ptpu_decode``: a program's
+    name without its fingerprint."""
+    return event_name.split("(", 1)[0]
+
+
 def _device_lines(path: str, plane_filter: str,
                   line_filter: Optional[str]):
-    """({plane_name: (line_name, [(op, start_ps, end_ps)])} of each
-    device plane's per-operation line — 'XLA Ops' where present, else
-    its busiest — and the benchmark's own host spans of every plane)."""
+    """({plane_name: (line_name, [(op, start_ps, end_ps)], module events)}
+    of each device plane's per-operation line — 'XLA Ops' where present,
+    else its busiest — with its line of whole programs (``MODULE_LINE``,
+    empty where the backend writes none), and the benchmark's own host
+    spans of every plane)."""
     out = {}
     host = []
     for pname, lines in xplane.planes_abs(path):
@@ -106,7 +117,8 @@ def _device_lines(path: str, plane_filter: str,
         line = "XLA Ops" if "XLA Ops" in by_name else \
             max(by_name, key=lambda k: len(by_name[k]))
         if by_name[line]:
-            out[pname] = (line, by_name[line])
+            out[pname] = (line, by_name[line],
+                          dict(lines).get(MODULE_LINE, []))
     return out, host
 
 
@@ -118,7 +130,9 @@ def reduce(path: str, window_s: float, steps: int,
     ``busy_s`` is the union of the operation intervals of a device's
     line, averaged over the ``chips`` device planes found; ``ops`` are
     self times (nested envelopes keep only their remainder) of the
-    first device, per ``xplane.op_symbol``; ``gaps`` are that device's
+    first device, per ``xplane.op_symbol``, with ``ops_n`` the events
+    behind each; ``modules`` are that device's whole programs, seconds
+    and calls per ``module_symbol``; ``gaps`` are that device's
     longest idle intervals, each with the host span of ours that
     covered most of it."""
     dev, host = _device_lines(path, plane_filter, line_filter)
@@ -130,11 +144,19 @@ def reduce(path: str, window_s: float, steps: int,
         merged = _union([(s, e) for _, s, e in dev[n][1]])
         busy.append(sum(e - s for s, e in merged) / 1e12)
     first = names[0]
-    line, events = dev[first]
+    line, events, module_events = dev[first]
     self_ms = xplane.self_times(events)
     by_symbol: Dict[str, float] = defaultdict(float)
     for name, ms in self_ms.items():
         by_symbol[xplane.op_symbol(name)] += ms / 1e3
+    calls: Dict[str, int] = defaultdict(int)
+    for name, _, _ in events:
+        calls[xplane.op_symbol(name)] += 1
+    modules: Dict[str, List[float]] = defaultdict(lambda: [0.0, 0])
+    for name, s, e in module_events:
+        m = modules[module_symbol(name)]
+        m[0] += (e - s) / 1e12
+        m[1] += 1
     budget = step_budget.budget_from_times(
         self_ms, steps=max(steps, 1), line=line, plane=first,
         collectives=step_budget.collective_detail(
@@ -158,6 +180,8 @@ def reduce(path: str, window_s: float, steps: int,
         "steps": int(steps),
         "devices": len(names),
         "ops_s": dict(by_symbol),
+        "ops_n": dict(calls),
+        "modules": {k: list(v) for k, v in modules.items()},
         "buckets_ms_per_step": budget["buckets"],
         "collectives": budget["collectives"],
         "gaps": labelled,

@@ -72,6 +72,42 @@ def log_uniform_grid(lo: int, hi: int, n: int) -> np.ndarray:
                    ).astype(np.int64)
 
 
+def truncated_exponential_grid(mean: float, lo: int, hi: int,
+                               n: int) -> np.ndarray:
+    """``n`` midpoint quantiles of the exponential law cut to [lo, hi]
+    whose mean is ``mean``: the law of largest entropy for a published
+    mean between published limits, so nothing but those is chosen. The
+    scale is found by bisection on the cut law's own mean."""
+    if not lo < mean < (lo + hi) / 2:
+        raise ValueError(f"mean {mean} outside ({lo}, {(lo + hi) / 2})")
+    w = float(hi - lo)
+
+    def cut_mean(scale):
+        return lo + scale - w / math.expm1(w / scale)
+
+    a, b = 1e-3 * w, 1e3 * w
+    for _ in range(200):
+        mid = math.sqrt(a * b)
+        a, b = (mid, b) if cut_mean(mid) < mean else (a, mid)
+    scale = math.sqrt(a * b)
+    u = (np.arange(n) + 0.5) / n
+    return np.rint(lo - scale * np.log1p(-u * -math.expm1(-w / scale))
+                   ).astype(np.int64)
+
+
+def length_grid(spec: dict, n: int) -> np.ndarray:
+    """A traffic file's ``prompt_tokens`` or ``output_tokens`` as ``n``
+    quantile points: ``{"log_uniform": [lo, hi]}`` or
+    ``{"truncated_exponential": {"mean": m, "min": lo, "max": hi}}``."""
+    (law, arg), = spec.items()
+    if law == "log_uniform":
+        return log_uniform_grid(arg[0], arg[1], n)
+    if law == "truncated_exponential":
+        return truncated_exponential_grid(
+            float(arg["mean"]), int(arg["min"]), int(arg["max"]), n)
+    raise ValueError(f"no length law {law!r}")
+
+
 def exponential_grid(n: int) -> np.ndarray:
     """``n`` midpoint quantiles of the unit exponential law, scaled to
     mean exactly 1: a block of Poisson gaps that always spans ``n``."""
@@ -85,13 +121,14 @@ class RequestMix:
 
     def __init__(self, traffic: dict, vocab: int, seed: int):
         self.block = n = int(traffic["block"])
-        lo, hi = traffic["prompt_tokens"]["log_uniform"]
-        self._prompts = log_uniform_grid(lo, hi, n)
-        lo, hi = traffic["output_tokens"]["log_uniform"]
+        self.prompt_lengths = length_grid(traffic["prompt_tokens"], n)
         # prompts and outputs are paired by one fixed permutation, so a
         # block holds the same (prompt, output) pairs under every seed
-        self._outputs = log_uniform_grid(lo, hi, n)[
+        self._outputs = length_grid(traffic["output_tokens"], n)[
             seeding.host_rng(traffic["pairing_seed"], 0).permutation(n)]
+        # a tokenizer's first id: every prompt of a deployment starts
+        # with it, so every request shares a one-token prefix
+        self.bos = traffic.get("bos_token_id")
         self._gaps = None
         if "rate_rps" in traffic:
             self._gaps = exponential_grid(n) / float(traffic["rate_rps"])
@@ -105,9 +142,12 @@ class RequestMix:
         """(prompt token ids, max_new_tokens), for ever."""
         while True:
             for i in self._order.permutation(self.block):
-                yield (self._tokens.integers(
-                    1, self._vocab, int(self._prompts[i]),
-                    dtype=np.int64), int(self._outputs[i]))
+                ids = self._tokens.integers(
+                    1, self._vocab, int(self.prompt_lengths[i]),
+                    dtype=np.int64)
+                if self.bos is not None:
+                    ids[0] = self.bos
+                yield ids, int(self._outputs[i])
 
     def arrivals(self, start: float) -> Iterator[float]:
         """Due times from ``start`` on, for ever (open loop)."""
