@@ -62,12 +62,38 @@ attend.
 """
 from __future__ import annotations
 
+import dataclasses
+from typing import Any, Tuple
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["cache_attend", "check_cache_pos", "paged_cache_attend",
-           "quantize_kv_page"]
+__all__ = ["CacheSpec", "cache_attend", "check_cache_pos",
+           "paged_cache_attend", "quantize_kv_page"]
+
+
+@dataclasses.dataclass(frozen=True)
+class CacheSpec:
+    """What a causal LM asks the serving engine to hold for it between
+    steps (its ``cache_spec()``), a layer and a slot:
+
+    - ``kind="kv"``: K and V by position, ``[positions, kv_heads,
+      head_dim]`` each, in ``dtype``; the engine chooses the pool (a row
+      a slot, or pages) and the forward's cache tuples are
+      ``models/_decode_cache``'s;
+    - ``kind="state"``: fixed-size arrays whatever the length, ``state``
+      naming each with its shape a slot and its dtype; a prefill builds
+      them (cache ``(None, None, true_len)``), a decode step reads and
+      rewrites them (``(*arrays, pos, active)``).
+    """
+    kind: str
+    num_layers: int
+    kv_heads: int
+    head_dim: int
+    dtype: Any                  # the model's
+    max_positions: int
+    state: Tuple[Tuple[str, Tuple[int, ...], Any], ...] = ()
 
 
 def check_cache_pos(pos, t: int, Tmax: int) -> bool:

@@ -43,7 +43,7 @@ from ..nn.layer.container import LayerList
 from ..framework.tensor import Tensor, apply_op
 from ..observability import span
 from ..utils.compile_cache import Watched
-from ._decode_cache import (cache_attend, check_cache_pos,
+from ._decode_cache import (CacheSpec, cache_attend, check_cache_pos,
                             paged_cache_attend)
 
 __all__ = ["GPTConfig", "GPTModel", "GPTForCausalLM", "GPTSpmdTrainer",
@@ -294,6 +294,21 @@ class GPTForCausalLM(Layer):
         return F.cross_entropy(
             logits.reshape([-1, self.cfg.vocab_size]),
             labels.reshape([-1]))
+
+    # -- what the serving engine asks of a model -------------------------
+    tp_param_spec = staticmethod(tp_param_spec)
+
+    def cached_forward(self, ids, caches):
+        return self.gpt(ids, caches=caches)
+
+    def cache_spec(self) -> CacheSpec:
+        cfg = self.cfg
+        qw = self.gpt.blocks[0].qkv.weight
+        return CacheSpec(
+            kind="kv", num_layers=len(self.gpt.blocks),
+            kv_heads=qw.shape[-1] // (3 * cfg.head_dim),
+            head_dim=cfg.head_dim, dtype=self.gpt.wte.weight._data.dtype,
+            max_positions=cfg.max_seq_len)
 
 
 # ---------------------------------------------------------------------------

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..framework.tensor import apply_op
-from ._decode_cache import (cache_attend, check_cache_pos,
+from ._decode_cache import (CacheSpec, cache_attend, check_cache_pos,
                             paged_cache_attend)
 from ..nn import functional as F
 from ..nn.layer_base import Layer
@@ -460,6 +460,24 @@ class LlamaForCausalLM(Layer):
             return matmul(h, self.llama.embed_tokens.weight,
                           transpose_y=True)
         return self.lm_head(h)
+
+    # -- what the serving engine asks of a model -------------------------
+    tp_param_spec = staticmethod(tp_param_spec)
+
+    def cached_forward(self, ids, caches):
+        return self.llama(ids, None, caches)
+
+    def cache_spec(self) -> CacheSpec:
+        cfg = self.config
+        # k_proj may be a Linear (weight [in, out]) or a weight-only
+        # Int8Linear (wq [in, out] int8) after quantization
+        kp = self.llama.layers[0].self_attn.k_proj
+        kw = kp.weight if hasattr(kp, "weight") else kp.wq
+        return CacheSpec(
+            kind="kv", num_layers=len(self.llama.layers),
+            kv_heads=kw.shape[-1] // cfg.head_dim, head_dim=cfg.head_dim,
+            dtype=self.llama.embed_tokens.weight._data.dtype,
+            max_positions=cfg.max_position_embeddings)
 
     def generate(self, input_ids, max_new_tokens: int = 16,
                  temperature: float = 0.0, top_p: float = 1.0,

@@ -53,7 +53,7 @@ from .errors import (DeadlineExceeded, EngineBroken,  # noqa: F401
                      EngineClosed, EngineIdle, NoHealthyReplicas,
                      QueueFull, RateLimited, RemoteError, ReplicaDead,
                      RequestCancelled, ServingError, Shed,
-                     TenantQueueFull)
+                     StateCacheUnsupported, TenantQueueFull)
 from .frontdoor import (ClientStream, FrontDoor,  # noqa: F401
                         FrontDoorHandle, FrontDoorHTTPServer,
                         TenantPolicy, TokenBucket)
@@ -63,7 +63,8 @@ from .router import Replica, ReplicaRouter  # noqa: F401
 from .sampling import SamplingParams, sample_token  # noqa: F401
 from .scheduler import (FIFOScheduler, Request, bucket_for,  # noqa: F401
                         prefill_buckets)
-from .slot_cache import PagedKVCache, SlotKVCache  # noqa: F401
+from .slot_cache import (PagedKVCache, SlotKVCache,  # noqa: F401
+                         SlotStateCache)
 from .spec_decode import (DraftModelProposer,  # noqa: F401
                           NgramProposer)
 from .spec_tune import SpecTuner  # noqa: F401
@@ -72,6 +73,7 @@ __all__ = ["ServingEngine", "EngineMetrics", "MeshContext",
            "SamplingParams",
            "sample_token", "FIFOScheduler", "Request", "bucket_for",
            "prefill_buckets", "SlotKVCache", "PagedKVCache",
+           "SlotStateCache", "StateCacheUnsupported",
            "NgramProposer", "DraftModelProposer", "SpecTuner",
            "ServingError",
            "QueueFull", "DeadlineExceeded", "EngineBroken",

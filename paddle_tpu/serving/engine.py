@@ -53,6 +53,7 @@ deadlines (cancelled at step boundaries with ``finish_reason ==
 """
 from __future__ import annotations
 
+import contextlib
 import time
 from typing import Callable, List, Optional, Sequence
 
@@ -66,13 +67,14 @@ from ..observability.tracing import has_bindings
 from ..resilience.faults import InjectedFault, maybe_fail
 from ..utils.compile_cache import Watched, note_trace
 from .errors import (DeadlineExceeded, EngineBroken, EngineClosed,
-                     EngineIdle, QueueFull, RequestCancelled)
+                     EngineIdle, QueueFull, RequestCancelled,
+                     StateCacheUnsupported)
 from .kv_tier import HostPageTier, PersistentPrefixStore
 from .mesh import MeshContext
 from .metrics import EngineMetrics
 from .sampling import SamplingParams, sample_token, sampling_dist
 from .scheduler import FIFOScheduler, Request, bucket_for
-from .slot_cache import PagedKVCache, SlotKVCache
+from .slot_cache import PagedKVCache, SlotKVCache, SlotStateCache
 from .spec_decode import DraftModelProposer, NgramProposer
 from .spec_tune import SpecTuner
 
@@ -80,50 +82,54 @@ __all__ = ["ServingEngine"]
 
 
 class _ModelAdapter:
-    """Uniform view over the causal LMs that expose the static-cache
-    path (models/llama.py natively; models/gpt.py via its cache-aware
-    forward): a backbone callable taking (ids, caches), a logits head,
-    and the cache geometry."""
+    """Uniform view over the causal LMs the engine can serve. The model
+    states what it needs held between steps (``cache_spec()``: a
+    ``models/_decode_cache.CacheSpec``, K and V by position or a
+    fixed-size recurrent state), runs its backbone over the matching
+    cache tuples (``cached_forward(ids, caches)``) and has a logits
+    head (``_head``); this class turns the engine's pools into those
+    tuples and knows nothing else of a model family."""
 
     def __init__(self, model):
         self.model = model
+        if not hasattr(model, "cache_spec"):
+            raise TypeError(
+                f"{type(model).__name__} exposes no static-cache decode "
+                "path the serving engine can drive (expected "
+                "cache_spec(), cached_forward(ids, caches) and _head)")
+        self.spec = spec = model.cache_spec()
         # tensor-parallel shard rules for raw_state() param names
         # (serving/mesh.py builds NamedShardings from these); None =
         # every param replicated, which is always correct
-        self.tp_param_spec = None
-        if hasattr(model, "llama"):          # LlamaForCausalLM
-            from ..models.llama import tp_param_spec
-            self.tp_param_spec = tp_param_spec
-            cfg = model.config
-            backbone = model.llama
-            self.call = lambda ids, caches: backbone(ids, None, caches)
-            self.head = model._head
-            self.num_layers = len(backbone.layers)
-            self.head_dim = cfg.head_dim
-            attn0 = backbone.layers[0].self_attn
-            kp = attn0.k_proj       # Linear (weight) or Int8Linear (wq)
-            kw = kp.weight if hasattr(kp, "weight") else kp.wq
-            self.kv_heads = kw.shape[-1] // cfg.head_dim
-            self.max_positions = cfg.max_position_embeddings
-            self.dtype = backbone.embed_tokens.weight._data.dtype
-        elif hasattr(model, "gpt"):          # GPTForCausalLM
-            from ..models.gpt import tp_param_spec
-            self.tp_param_spec = tp_param_spec
-            cfg = model.cfg
-            backbone = model.gpt
-            self.call = lambda ids, caches: backbone(ids, caches=caches)
-            self.head = model._head
-            self.num_layers = len(backbone.blocks)
-            self.head_dim = cfg.head_dim
-            qw = backbone.blocks[0].qkv.weight
-            self.kv_heads = qw.shape[-1] // (3 * cfg.head_dim)
-            self.max_positions = cfg.max_seq_len
-            self.dtype = backbone.wte.weight._data.dtype
-        else:
-            raise TypeError(
-                f"{type(model).__name__} exposes no static-cache decode "
-                "path the serving engine can drive (expected a .llama "
-                "or .gpt backbone with a (k, v, pos) cache forward)")
+        self.tp_param_spec = getattr(model, "tp_param_spec", None)
+        self.call = model.cached_forward
+        self.head = model._head
+        self.num_layers = spec.num_layers
+        self.kv_heads = spec.kv_heads
+        self.head_dim = spec.head_dim
+        self.max_positions = spec.max_positions
+        self.dtype = spec.dtype
+
+    @property
+    def stateful(self) -> bool:
+        """The model keeps a fixed-size state a slot, no K/V."""
+        return self.spec.kind == "state"
+
+    def prefill_caches(self, bucket: int, true_len):
+        """Per-layer cache tuples for one prompt run from scratch."""
+        if self.stateful:
+            return [(None, None, true_len)] * self.num_layers
+        shape = (1, bucket, self.kv_heads, self.head_dim)
+        return [(jnp.zeros(shape, self.dtype),
+                 jnp.zeros(shape, self.dtype), 0)
+                for _ in range(self.num_layers)]
+
+    def decode_caches(self, pools, pos, active):
+        """Per-layer cache tuples of the slot-row pools for one decode
+        step at per-slot positions."""
+        if self.stateful:
+            return [layer + (pos, active) for layer in zip(*pools)]
+        return [layer + (pos,) for layer in zip(*pools)]
 
 
 class ServingEngine:
@@ -138,7 +144,7 @@ class ServingEngine:
                  registry=None, flight_recorder=None,
                  auditor=None,
                  cancel_probe: Optional[Callable] = None,
-                 kv_layout: str = "paged",
+                 kv_layout: Optional[str] = None,
                  page_size: Optional[int] = None,
                  num_pages: Optional[int] = None,
                  kv_dtype: Optional[str] = None,
@@ -210,7 +216,43 @@ class ServingEngine:
                 f"admission_lookahead must be >= 0, got "
                 f"{admission_lookahead}")
         self.admission_lookahead = int(admission_lookahead)
-        if kv_layout not in ("paged", "contiguous"):
+        if self.adapter.stateful:
+            # the model keeps a fixed-size state a slot and no K/V: what
+            # reads, shares or moves K and V by position cannot work on
+            # it yet and is refused by name, never silently ignored
+            for option, value, asked in (
+                    ("kv_layout", kv_layout,
+                     kv_layout not in (None, "state")),
+                    ("page_size", page_size, page_size is not None),
+                    ("num_pages", num_pages, num_pages is not None),
+                    ("kv_dtype", kv_dtype, kv_dtype is not None),
+                    ("prefix_sharing", prefix_sharing,
+                     bool(prefix_sharing)),
+                    ("speculative", speculative, bool(speculative)),
+                    ("draft_model", draft_model, draft_model is not None),
+                    ("mesh", mesh, mesh is not None),
+                    ("prefill_devices", prefill_devices,
+                     bool(prefill_devices)),
+                    ("prefill_chunk", prefill_chunk,
+                     prefill_chunk is not None),
+                    ("kv_host_tier", kv_host_tier, bool(kv_host_tier)),
+                    ("host_tier_pages", host_tier_pages,
+                     host_tier_pages is not None),
+                    ("prefix_store_dir", prefix_store_dir,
+                     prefix_store_dir is not None),
+                    ("kv_transport", kv_transport,
+                     kv_transport is not None)):
+                if asked:
+                    raise StateCacheUnsupported(option, value)
+            kv_layout = "state"
+        elif kv_layout is None:
+            kv_layout = "paged"
+        elif kv_layout == "state":
+            raise ValueError(
+                f"kv_layout='state' is for a model whose cache_spec() "
+                f"is a recurrent state; {type(model).__name__} caches "
+                f"K and V")
+        if kv_layout not in ("paged", "contiguous", "state"):
             raise ValueError(
                 f"kv_layout must be 'paged' or 'contiguous', got "
                 f"{kv_layout!r}")
@@ -237,6 +279,11 @@ class ServingEngine:
             self.kv_quant = kv_dtype == "int8"
             self.prefix_sharing = True if prefix_sharing is None \
                 else bool(prefix_sharing)
+        else:
+            # a slot's row, or its state, is its one page: nothing is
+            # shared and nothing quantized
+            self.page_size, self.num_pages = self.max_len, None
+            self.kv_quant = self.prefix_sharing = False
         # KV tiering (docs/SERVING.md "KV tiering"): demote cold
         # refcount-0 prefix pages to pinned host RAM instead of
         # destroying them, promote back on radix hit; an optional
@@ -483,6 +530,14 @@ class ServingEngine:
             self._m_chunk_steps = reg.counter(
                 "ptpu_serving_chunk_steps_total",
                 "chunked-prefill chunk program runs")
+        if self.adapter.stateful:
+            self._m_state_bytes = reg.gauge(
+                "ptpu_serving_state_bytes",
+                "total device bytes of the recurrent-state pool")
+            self._m_state_bytes.set(self.cache.state_bytes())
+            self._m_state_slots = reg.gauge(
+                "ptpu_serving_state_slots_in_use",
+                "slots whose recurrent state belongs to a request")
         if self.paged:
             self._m_pages_free = reg.gauge(
                 "ptpu_serving_pages_free", "KV pages on the free list")
@@ -548,6 +603,14 @@ class ServingEngine:
         if self.meshctx is not None:
             kv_sh = self.meshctx.kv_sharding()
             sc_sh = self.meshctx.scale_sharding()
+        if ad.stateful:
+            if len(ad.spec.state) != 2:
+                raise NotImplementedError(
+                    "the slot-row programs carry two arrays a layer "
+                    f"(K and V, or a state and its normaliser), not "
+                    f"{len(ad.spec.state)}")
+            return SlotStateCache(ad.num_layers, self.max_slots,
+                                  ad.spec.state)
         if self.paged:
             return PagedKVCache(
                 ad.num_layers, self.max_slots, self.max_len,
@@ -1061,6 +1124,14 @@ class ServingEngine:
                 self.peak_active_slots = max(self.peak_active_slots,
                                              len(active))
                 self._publish_page_stats(sp)
+            elif self.adapter.stateful:
+                in_use = len(self.cache.active_slots())
+                self._m_state_slots.set(in_use)
+                if sp is not None:
+                    sp.set_attr("state_slots_in_use", in_use)
+                    sp.set_attr("state_slots_total", self.max_slots)
+                    sp.set_attr("state_bytes",
+                                in_use * self.cache.slot_bytes)
         return admitted, len(active)
 
     def _decode_plain(self, active, finished: List[Request]) -> None:
@@ -1108,10 +1179,10 @@ class ServingEngine:
                     self.cache.kss, self.cache.vss = \
                         list(kss), list(vss)
                 else:
-                    logits, ks, vs = self._decode_fn()(
+                    logits, *pools = self._decode_fn()(
                         self._params, self._buffers, toks, pos, mask,
-                        self.cache.ks, self.cache.vs)
-                    self.cache.ks, self.cache.vs = list(ks), list(vs)
+                        *self.cache.pools)
+                    self.cache.pools = pools
             logits = self._fetch("serving.decode.fetch", logits)
         with span("serving.sample", rows=len(active)) as sp:
             n0 = len(finished)
@@ -1905,7 +1976,8 @@ class ServingEngine:
             with span("serving.prefill", request_id=request_id,
                       bucket=bucket, prompt_tokens=n,
                       program="prefill",
-                      replay=bool(req is not None and req.out_tokens)):
+                      replay=bool(req is not None
+                                  and req.out_tokens)) as sp:
                 padded = np.zeros((1, bucket), np.int64)
                 padded[0, :n] = ids
                 if disagg:
@@ -1925,11 +1997,12 @@ class ServingEngine:
                             self._staged_handoffs.pop(req.rid, None)
                         raise
                 else:
-                    logits, ks, vs = self._prefill_fn()(
-                        self._params, self._buffers, padded,
-                        np.int32(n), np.int32(slot),
-                        self.cache.ks, self.cache.vs)
-                    self.cache.ks, self.cache.vs = list(ks), list(vs)
+                    with self._state_reset(slot, sp):
+                        logits, *pools = self._prefill_fn()(
+                            self._params, self._buffers, padded,
+                            np.int32(n), np.int32(slot),
+                            *self.cache.pools)
+                        self.cache.pools = pools
                 return self._fetch("serving.prefill.fetch", logits)
         cache = self.cache
         try:
@@ -2019,6 +2092,19 @@ class ServingEngine:
             self._staged_promotions.pop(req.rid, None)
             cache.abort_sequence(slot, req)
             raise
+
+    def _state_reset(self, slot: int, prefill_span):
+        """Around the prefill program's dispatch. For a model that keeps
+        a state it is the slot's reset on reuse and its new state's
+        installation (the program builds the state from nothing and
+        overwrites the slot's whole row): span ``serving.state.reset``.
+        K and V need none: the causal mask hides a row's stale tail."""
+        if not self.adapter.stateful:
+            return contextlib.nullcontext()
+        prefill_span.set_attr("state_reset", True)
+        self.cache.reset(slot)
+        return span("serving.state.reset", slot=slot,
+                    state_bytes=self.cache.slot_bytes)
 
     # -- chunked prefill ----------------------------------------------
     @staticmethod
@@ -2343,10 +2429,7 @@ class ServingEngine:
         def local_run(params, buffers, ids, true_len):
             Lb = ids.shape[1]
             self._count_trace("prefill", Lb)
-            shape = (1, Lb, ad.kv_heads, ad.head_dim)
-            local = [(jnp.zeros(shape, ad.dtype),
-                      jnp.zeros(shape, ad.dtype), 0)
-                     for _ in range(ad.num_layers)]
+            local = ad.prefill_caches(Lb, true_len)
             with ad.model.bind_state(params, buffers):
                 h, new_caches = ad.call(Tensor(ids), local)
                 h_last = jax.lax.dynamic_slice_in_dim(
@@ -2374,11 +2457,14 @@ class ServingEngine:
                 return self._prefill_jit
 
             def ptpu_prefill(params, buffers, ids, true_len, slot, ks, vs):
+                # ks/vs: the slot-row pools (K and V, or a stateful
+                # model's two state arrays): the slot's whole row is
+                # overwritten, which is a state's reset on reuse
                 logits, new_caches = local_run(params, buffers, ids,
                                                true_len)
                 splice = lambda pool, c: jax.lax.dynamic_update_slice(
                     pool, getattr(c, "_data", c).astype(pool.dtype),
-                    (slot, 0, 0, 0))
+                    (slot,) + (0,) * (pool.ndim - 1))
                 ks = [splice(p, c[0]) for p, c in zip(ks, new_caches)]
                 vs = [splice(p, c[1]) for p, c in zip(vs, new_caches)]
                 return logits, ks, vs
@@ -2954,7 +3040,7 @@ class ServingEngine:
                 wl = jnp.where(active, 1, 0).astype(jnp.int32)
                 caches = [(k, v, pos_eff, wl) for k, v in zip(ks, vs)]
             else:
-                caches = [(k, v, pos_eff) for k, v in zip(ks, vs)]
+                caches = ad.decode_caches((ks, vs), pos_eff, active)
             with ad.model.bind_state(params, buffers):
                 h, new_caches = ad.call(Tensor(toks), caches)
                 logits = ad.head(h[:, -1:])._data[:, -1]

@@ -15,6 +15,11 @@ Callers branch on these instead of parsing RuntimeError strings:
 - :class:`EngineIdle` — ``step()`` with no queued or in-flight work
   (guard loops with ``has_work()``).
 - :class:`EngineClosed` — ``submit()`` after ``drain()``.
+- :class:`StateCacheUnsupported` — ``ServingEngine(...)`` was given an
+  option that reads, shares or moves K and V by position (paged layout,
+  prefix sharing, int8 KV, speculation, the KV tier or wire, a mesh,
+  chunked prefill) for a model that keeps a fixed-size recurrent state
+  a slot instead; refused at construction, never silently ignored.
 - :class:`RequestCancelled` — set as ``Request.error`` by
   ``cancel()``/``drain(max_steps=...)`` cutoffs, and (with reason
   ``"disconnect"``) when the front door observes the client gone.
@@ -39,7 +44,7 @@ from __future__ import annotations
 
 __all__ = ["ServingError", "QueueFull", "DeadlineExceeded",
            "EngineBroken", "EngineIdle", "EngineClosed",
-           "RequestCancelled", "RateLimited", "TenantQueueFull",
+           "RequestCancelled", "StateCacheUnsupported", "RateLimited", "TenantQueueFull",
            "Shed", "ReplicaDead", "NoHealthyReplicas", "RemoteError"]
 
 
@@ -106,6 +111,15 @@ class EngineClosed(ServingError):
     def __init__(self):
         super().__init__(
             "ServingEngine is draining/closed; submit() refused")
+
+
+class StateCacheUnsupported(ServingError):
+    def __init__(self, option: str, value=None):
+        super().__init__(
+            f"{option}={value!r} needs K and V by position; this model "
+            f"keeps a fixed-size recurrent state a slot "
+            f"(kv_layout='state'), on which it is not supported yet")
+        self.option = option
 
 
 class RequestCancelled(ServingError):

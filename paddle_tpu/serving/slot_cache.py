@@ -1,5 +1,6 @@
-"""KV-cache pools for the serving engine: the contiguous slot pool and
-its block-paged successor.
+"""Cache pools for the serving engine: the contiguous KV slot pool, its
+block-paged successor, and the recurrent-state pool of models that keep
+no K/V (``SlotStateCache``, at the end of the module docstring).
 
 ``SlotKVCache`` is the original fixed ``(max_slots, max_len)`` pool:
 one full row reserved per slot, so concurrency is capped by the
@@ -39,6 +40,15 @@ at it, and masked/padded writes land in it, so stale table rows can
 never corrupt live data. Rows are never cleared on the device — the
 per-slot causal mask (``kpos <= qpos``) keeps any stale tail beyond
 the current length invisible, so recycling costs zero device work.
+
+``SlotStateCache`` holds, a layer, the fixed-size arrays a model's
+``cache_spec()`` names (power retention: ``S [max_slots, kv_heads, P,
+D]`` and ``z [max_slots, kv_heads, P]``, float32), a row a slot whatever
+the request's length: admission is by free slots alone. A state has no
+mask to hide a stale tail behind, so a slot is RESET on reuse: the
+prefill program builds the new request's state from nothing and
+overwrites the slot's whole row (``reset`` is the host's side of it), and
+the decode program rewrites active slots only.
 """
 from __future__ import annotations
 
@@ -48,7 +58,7 @@ from typing import Dict, List, Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["SlotKVCache", "PagedKVCache"]
+__all__ = ["SlotKVCache", "SlotStateCache", "PagedKVCache"]
 
 
 def _validate_geometry(num_layers: int, max_slots: int, max_len: int,
@@ -138,6 +148,61 @@ class SlotKVCache(_SlotTable):
         self.vs = _place_pools(
             [jnp.zeros(shape, dtype) for _ in range(num_layers)],
             kv_sharding)
+
+    @property
+    def pools(self):
+        """The per-layer device arrays a slot-row program reads and
+        returns, in argument order."""
+        return self.ks, self.vs
+
+    @pools.setter
+    def pools(self, new) -> None:
+        self.ks, self.vs = (list(p) for p in new)
+
+
+class SlotStateCache(_SlotTable):
+    """Per-layer fixed-size state arrays, a row a slot, plus the slot
+    lease table. ``state`` is ``CacheSpec.state``: ``(name, shape a
+    slot, dtype)`` for each array of a layer."""
+
+    def __init__(self, num_layers: int, max_slots: int, state):
+        if num_layers < 1:
+            raise ValueError(f"num_layers must be >= 1, got {num_layers}")
+        if max_slots < 1:
+            raise ValueError(f"max_slots must be >= 1, got {max_slots}")
+        if not state:
+            raise ValueError("a state cache needs at least one array")
+        super().__init__(max_slots)
+        self.pools = [[jnp.zeros((max_slots,) + tuple(shape), dtype)
+                       for _ in range(num_layers)]
+                      for _, shape, dtype in state]
+        # bytes one slot holds over all layers; what a reset rebuilds
+        self.slot_bytes = self.state_bytes() // max_slots
+        self.resets = 0
+
+    @property
+    def pools(self):
+        return self._pools
+
+    @pools.setter
+    def pools(self, new) -> None:
+        self._pools = tuple(list(p) for p in new)
+
+    def reset(self, slot: int) -> None:
+        """The host's side of a slot's reset on reuse (admission, or
+        ``recover()``'s re-prefill of a slot it has leased again): the
+        prefill program about to run overwrites the slot's whole row
+        with a state built from nothing."""
+        if not 0 <= slot < self.max_slots:
+            raise IndexError(f"no slot {slot}")
+        self.resets += 1
+
+    def state_bytes(self) -> int:
+        """Total device bytes of the state pools."""
+        return sum(a.size * a.dtype.itemsize
+                   for p in self._pools for a in p)
+
+    kv_bytes = state_bytes
 
 
 class _PrefixNode:

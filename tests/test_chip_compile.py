@@ -219,6 +219,30 @@ def test_paged_decode_attention_compiles_for_v5e(one_chip):
     assert compiled.memory_analysis().alias_size_in_bytes >= 2 * pool_bytes
 
 
+# -- the retention decode kernel at Brumby-14B's shapes ---------------------
+# 16 slots x 8 KV heads of [8256, 128] float32 state: a whole state block
+# a grid step in VMEM (in and out, double-buffered), rewritten in place
+
+def test_retention_decode_kernel_compiles_for_v5e(one_chip):
+    from paddle_tpu.ops import power_retention as pr
+    B, KV, rep, D = 16, 8, 5, 128
+    P = pr.phi_size(D)
+    compiled = jax.jit(
+        lambda q, kvg, S, on: pr._decode_pallas(q, kvg, S, on, False),
+        donate_argnums=2).lower(
+            one_chip((B, KV, rep, D), F32), one_chip((B, KV, 3, D), F32),
+            one_chip((B, KV, P, D), F32),
+            one_chip((B,), jnp.bool_)).compile()
+    txt = compiled.as_text()
+    assert txt.count('custom_call_target="tpu_custom_call"') == 1
+    assert "retention_decode" in txt
+    # the state is aliased through the kernel: no second copy of it
+    mem = compiled.memory_analysis()
+    state = B * KV * P * D * 4
+    assert mem.alias_size_in_bytes >= state
+    assert mem.temp_size_in_bytes < state // 100
+
+
 # -- P11: gradient collectives in the compiled DP / FSDP step -------------
 
 def _abstract_trainer(mesh):

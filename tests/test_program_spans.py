@@ -546,17 +546,18 @@ def test_program_span_reader_returns_nothing_on_an_older_program(
 
 
 def test_new_layer_metrics_report_on_the_tiny_training_cell():
-    """BENCHMARK.json's three program_span entries through
+    """BENCHMARK.json's three program_span entries of the training cell
+    (at least those: a serving configuration may bring its own) through
     ``chipbench.run.run_cell`` at the selftest's tiny sizes (the
     selftest itself runs ``unproved/manifest.json``, which no PR but a
     benchmark one may extend)."""
     from chipbench import manifest, run, selftest
     bench = manifest.load()
     assert not manifest.check(bench)
-    new = {m["name"]: m for m in bench["per_layer"]
-           if m["source"] == "program_span"}
-    assert set(new) == {"host_dispatch_ms.train", "program_ready_s",
-                        "state_init_s.train"}
+    new = {"host_dispatch_ms.train", "program_ready_s",
+           "state_init_s.train"}
+    assert new <= {m["name"] for m in bench["per_layer"]
+                   if m["source"] == "program_span"}
     cell = manifest.cell(bench, "gpt3-1.3b.pretrain-s1024")
     cell["config"] = selftest.TINY_CONFIG["gpt_trainer"]
     cell["traffic"] = dict(cell["traffic"],
@@ -566,7 +567,7 @@ def test_new_layer_metrics_report_on_the_tiny_training_cell():
                          plane_filter="CPU", line_filter="CpuClient",
                          log=quiet)
     m = r["metrics"]
-    assert r["correct"] and set(new) <= set(m)
+    assert r["correct"] and new <= set(m)
     assert m["compiles_in_window"]["value"] == 0
     assert 0 < m["host_dispatch_ms.train"]["value"] \
         < m["step_ms.train"]["value"] * 1.5
