@@ -59,6 +59,19 @@ token-identical to the slot-pool path. Optional int8 storage keeps the
 pools in int8 with per-page f32 scales (one scale per page slot ×
 position × kv-head, absmax over head_dim) and dequantizes inside the
 attend.
+
+The token-identity contracts above are stated for the EINSUM. The
+one-token-a-row case on the model-dtype pools (the engine's decode
+program) has a second implementation, the Pallas kernel of
+``ops/paged_attention.py``, which reads a row's live pages only and is
+what one TPU device runs: the same softmax over the same positions with
+the query at float32, accumulated a page at a time (online softmax), so
+it agrees with the einsum to float32 rounding (on bfloat16 pools: to
+the bfloat16 rounding of a probability), not bitwise. Everything else
+keeps the einsum: extend, prefill and chunk (``t > 1``), speculative
+verify (``wlen``), int8 pages, a program traced under a mesh (the
+sharded token-identity law rests on the einsum), ``cache_attend`` and
+every CPU run.
 """
 from __future__ import annotations
 
@@ -68,6 +81,9 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from ..ops import paged_attention, pallas_ops
+from ..utils.compile_cache import note_fact
 
 __all__ = ["CacheSpec", "cache_attend", "check_cache_pos",
            "paged_cache_attend", "quantize_kv_page"]
@@ -173,6 +189,7 @@ def cache_attend(qr, kr, v, kc, vc, p, per_row: bool, wlen=None):
         kpos = jnp.arange(Tmax)[None, :]                      # [1, Tmax]
         mask = kpos <= qpos                          # causal over buffer
         maskx = mask[None, None, None]                 # [1,1,1,t,Tmax]
+    note_fact("attend", "einsum")
     qg = qr.reshape(b, t, kv, rep, D)
     scores = jnp.einsum("bqgrd,bkgd->bgrqk",
                         qg.astype(jnp.float32),
@@ -204,7 +221,7 @@ def _dequant(pool_rows, scale_rows):
 
 
 def paged_cache_attend(qr, kr, v, kp, vp, ks, vs, table, p,
-                       out_dtype, wlen=None):
+                       out_dtype, wlen=None, kernel=None):
     """Masked paged-pool cache attention (see module docstring).
 
     qr: [B, t, H, D] position-encoded queries; kr/v: [B, t, KV, D] new
@@ -215,7 +232,13 @@ def paged_cache_attend(qr, kr, v, kp, vp, ks, vs, table, p,
     reserved trash page 0); p: int32 write position, scalar or [B];
     ``wlen`` ([B] int32): only the first ``wlen[b]`` incoming tokens
     of row ``b`` write (speculative verify — masked writes land in the
-    trash page); None = every token writes.
+    trash page); None = every token writes. ``kernel``: for one token
+    a row on model-dtype pools, the live-pages Pallas kernel (``True``;
+    interpreted on the CPU) or the einsum (``False``); by default the
+    kernel where one un-partitioned TPU program is traced and Mosaic
+    takes the shapes. The kernel has no gradient rule: the engine's
+    programs trace under ``no_grad``, and so does any other caller
+    that can reach it.
 
     Returns (out [B, t, H*D], kp', vp', ks', vs').
     """
@@ -242,6 +265,9 @@ def paged_cache_attend(qr, kr, v, kp, vp, ks, vs, table, p,
                     0)                                       # [B, t]
     off = jnp.where(w_ok, qpos % page, 0)
     quant = ks is not None
+    live_pages = t == 1 and wlen is None and not quant and (
+        pallas_ops.single_device_tpu() and paged_attention.kernel_fits(D)
+        if kernel is None else kernel)
     if quant:
         kq, ksc = quantize_kv_page(kr)
         vq, vsc = quantize_kv_page(v)
@@ -252,6 +278,13 @@ def paged_cache_attend(qr, kr, v, kp, vp, ks, vs, table, p,
     else:
         kp = kp.at[pid, off].set(kr.astype(kp.dtype))
         vp = vp.at[pid, off].set(v.astype(vp.dtype))
+    if live_pages:
+        # the new token is in the pool: it attends to itself
+        note_fact("attend", "paged_kernel")
+        out = paged_attention.paged_decode_attention(
+            qr[:, 0], kp, vp, table, pv, out_dtype)
+        return out.reshape(b, 1, h * D), kp, vp, ks, vs
+    note_fact("attend", "einsum")
     # gather the row's pages into the contiguous attend view; with
     # pages_per_seq * page == Tmax this is value-identical to the
     # contiguous buffer, so the einsum below matches cache_attend's

@@ -61,11 +61,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..framework.tensor import Tensor
+from ..framework.tensor import Tensor, no_grad
 from ..observability import default_recorder, default_registry, span
 from ..observability.tracing import has_bindings
 from ..resilience.faults import InjectedFault, maybe_fail
-from ..utils.compile_cache import Watched, note_trace
+from ..utils.compile_cache import Watched, note_trace, noted_fact
 from .errors import (DeadlineExceeded, EngineBroken, EngineClosed,
                      EngineIdle, QueueFull, RequestCancelled,
                      StateCacheUnsupported)
@@ -495,6 +495,10 @@ class ServingEngine:
                              "prefill": {},
                              "extend": {}, "copy": 0, "install": {},
                              "chunk": {}, "promote": 0}
+        # what the decode program's attention traced as, also on its
+        # compile.decode span: "paged_kernel" (the live-pages Pallas
+        # kernel) or "einsum"; None until it has traced
+        self.decode_attend: Optional[str] = None
         if self.speculative and "draft" in self._proposers:
             # the draft proposer's ONE compiled program bumps the
             # engine's own trace-count ledger, so the compile contract
@@ -1136,7 +1140,7 @@ class ServingEngine:
 
     def _decode_plain(self, active, finished: List[Request]) -> None:
         """The k=1 decode step (non-speculative engines)."""
-        with self._decode_span("serving.decode", active):
+        with self._decode_span("serving.decode", active) as dsp:
             with span("serving.decode.build") as sp:
                 toks = np.zeros((self.max_slots, 1), np.int64)
                 pos = np.zeros((self.max_slots,), np.int32)
@@ -1161,6 +1165,9 @@ class ServingEngine:
                 # table when the fault can fire
                 if self.paged:
                     self._run_copies(copies)
+                    # the pages a length-aware attention has to read
+                    dsp.set_attr("live_pages", int(
+                        (pos[mask] // self.cache.page_size + 1).sum()))
                 sp.set_attr("cow_copies", len(copies))
             maybe_fail("serving.step.decode", step=self._step_idx - 1)
             if self.meshctx is not None:
@@ -2393,8 +2400,12 @@ class ServingEngine:
     def _jit(self, fn, **jit_kw):
         """``jax.jit`` of one engine program under its stable name
         (the device trace's module line reads ``jit_<name>``), its
-        compiles watched."""
-        return Watched(jax.jit(fn, **jit_kw), self.registry,
+        compiles watched. It traces under ``no_grad``: serving never
+        differentiates, and the op dispatch would otherwise trace every
+        op of a trainable model under ``jax.vjp``, where the JVP of a
+        cache write whose indices may repeat (the trash page) computes
+        even its primal by selects over the whole pool."""
+        return Watched(jax.jit(no_grad()(fn), **jit_kw), self.registry,
                        sink=self._compiles)
 
     def _fetch(self, name: str, x) -> np.ndarray:
@@ -3015,6 +3026,7 @@ class ServingEngine:
                 with ad.model.bind_state(params, buffers):
                     h, new_caches = ad.call(Tensor(toks), caches)
                     logits = ad.head(h[:, -1:])._data[:, -1]
+                self.decode_attend = noted_fact("attend")
                 logits = jnp.where(active[:, None], logits, 0.0)
                 return (logits,) + self._unpack_paged(new_caches)
 
@@ -3044,6 +3056,7 @@ class ServingEngine:
             with ad.model.bind_state(params, buffers):
                 h, new_caches = ad.call(Tensor(toks), caches)
                 logits = ad.head(h[:, -1:])._data[:, -1]
+            self.decode_attend = noted_fact("attend")
             logits = jnp.where(active[:, None], logits, 0.0)
             ks2 = [getattr(c[0], "_data", c[0]) for c in new_caches]
             vs2 = [getattr(c[1], "_data", c[1]) for c in new_caches]

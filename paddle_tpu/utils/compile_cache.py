@@ -82,6 +82,20 @@ def note_trace(kind: str, key=None) -> None:
         w["noted"].append((kind, key))
 
 
+def note_fact(name: str, value) -> None:
+    """Called like :func:`note_trace`, while a watched program traces:
+    one more attribute (``name``) of its ``compile.<kind>`` span."""
+    w = getattr(_tls, "watch", None)
+    if w is not None:
+        w[name] = value
+
+
+def noted_fact(name: str, default=None):
+    """What :func:`note_fact` last said of ``name`` in the watched call
+    that is tracing now."""
+    return (getattr(_tls, "watch", None) or {}).get(name, default)
+
+
 class Watched:
     """A jitted program whose every compile is an event.
 

@@ -3,8 +3,9 @@ suite lives in THIS file (one xdist worker loads libtpu; a second file
 could land on another worker and skip in silence).
 
 1. The flagship step's Pallas kernels at the real GPT-1.3B shapes and
-   the server's paged decode-attention step at the TinyLlama widths,
-   compiled ahead of time for a described ``v5e:2x2`` device with
+   the server's paged decode-attention step (the einsum at the
+   TinyLlama widths, the live-pages kernel at Mistral-7B's), compiled
+   ahead of time for a described ``v5e:2x2`` device with
    ``interpret=False``: what interpret mode on the CPU cannot refuse
    (tiling, VMEM, Mosaic lowering) is refused here, at no chip time.
 
@@ -217,6 +218,41 @@ def test_paged_decode_attention_compiles_for_v5e(one_chip):
     # the pools are updated in place: both donated inputs alias outputs
     pool_bytes = np.prod(pool.shape) * 2
     assert compiled.memory_analysis().alias_size_in_bytes >= 2 * pool_bytes
+
+
+# -- the live-pages decode kernel at Mistral-7B's serving shapes -----------
+# 32 slots, 16 pages of 128 a slot, 8 KV heads of 128 under 32 query
+# heads, bf16: the decode step's attention of both Mistral cells
+
+def test_paged_decode_kernel_compiles_for_v5e(one_chip, monkeypatch):
+    """The step as the engine's decode program runs it on one TPU (the
+    scatter, then the kernel on the donated pools): one Pallas kernel
+    under its name, the pools aliased through the step and read where
+    they lie (a copy of one would be 134 MB of temporaries)."""
+    from paddle_tpu.models._decode_cache import paged_cache_attend
+    from paddle_tpu.ops import pallas_ops
+    # both ask the backend, which is the CPU here: steered in the test
+    monkeypatch.setattr(pallas_ops, "_interpret", lambda: False)
+    monkeypatch.setattr(pallas_ops, "single_device_tpu", lambda: True)
+    B, H, KV, D, page, per_seq = 32, 32, 8, 128, 128, 16
+    pool = one_chip((B * per_seq + 1, page, KV, D))
+
+    def step(q, k, v, kp, vp, table, pos):
+        out, kp, vp, _, _ = paged_cache_attend(
+            q, k, v, kp, vp, None, None, table, pos, BF16)
+        return out, kp, vp
+
+    compiled = jax.jit(step, donate_argnums=(3, 4)).lower(
+        one_chip((B, 1, H, D), F32), one_chip((B, 1, KV, D)),
+        one_chip((B, 1, KV, D)), pool, pool,
+        one_chip((B, per_seq), I32), one_chip((B,), I32)).compile()
+    txt = compiled.as_text()
+    assert txt.count('custom_call_target="tpu_custom_call"') == 1
+    assert "paged_decode_attention" in txt
+    mem = compiled.memory_analysis()
+    pool_bytes = int(np.prod(pool.shape)) * 2
+    assert mem.alias_size_in_bytes >= 2 * pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes // 100
 
 
 # -- the retention decode kernel at Brumby-14B's shapes ---------------------

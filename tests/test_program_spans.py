@@ -334,6 +334,25 @@ def test_compile_spans_equal_trace_counts_for_every_kind(engine_run):
     assert fam.labels(program="prefill").value == 2
 
 
+def test_decode_spans_say_what_the_attention_read(engine_run):
+    """``compile.decode`` names the attention the decode program traced
+    as (the engine keeps the word beside ``trace_counts``), and every
+    ``serving.decode`` counts the pages a length-aware attention reads:
+    ``pos // page + 1`` over the active slots."""
+    eng, spans = engine_run
+    comp = [s for s in spans if s["name"] == "compile.decode"]
+    assert [s["attrs"]["attend"] for s in comp] == ["einsum"]   # a CPU
+    assert eng.decode_attend == "einsum"
+    dec = [s for s in spans if s["name"] == "serving.decode"]
+    assert dec
+    for d in dec:
+        live, batch = d["attrs"]["live_pages"], d["attrs"]["batch"]
+        assert batch <= live <= batch * eng.cache.pages_per_slot
+    # 40 and 20 prompt tokens in pages of 8: the first decode step
+    # writes positions 40 and 20, in the sixth and the third page
+    assert dec[0]["attrs"]["live_pages"] == 6 + 3
+
+
 def test_compile_events_are_logged_and_kept_for_the_recorder(caplog):
     from paddle_tpu.observability import FlightRecorder
     rec = FlightRecorder(capacity=8)
