@@ -45,12 +45,13 @@ SLO artifact, bars in tests/test_benchmarks_smoke.py.
 
 ``--prefix-share``: paged-KV concurrency mode — production-chat-shaped
 traffic (N-way shared system prompts + short unique suffixes, burst
-submitted) against three engines holding the SAME KV-pool byte
-budget: the contiguous slot pool, the paged pool (model dtype, prefix
-sharing), and the paged pool with int8 KV. Headline: max sustained
-concurrent requests per budget — the paged engine must reach >= 4x
-the contiguous pool's concurrency, >= 10x with int8 + shared
-prefixes (ISSUE 6 acceptance). Emits a schema-guarded ``PAGED_KV``
+submitted) against two engines holding the SAME KV-pool byte
+budget, what ``contig_slots`` full-length rows (one a slot, the
+contiguous pool the engine once had) would take: the paged pool
+(model dtype, prefix sharing), and the paged pool with int8 KV.
+Headline: max sustained concurrent requests per budget — the paged
+engine must reach >= 4x the rows' concurrency (their slot count),
+>= 10x with int8 + shared prefixes (ISSUE 6 acceptance). Emits a schema-guarded ``PAGED_KV``
 summary line (prefix hit rate, pages/token, peak concurrency, gains)
 asserted in tests/test_benchmarks_smoke.py.
 
@@ -301,7 +302,8 @@ def _run_burst(model, prompts, new, *, max_slots, max_len, min_bucket,
 def run_prefix_share(model, max_len, min_bucket, page_size, sys_lens,
                      n_req, suffix_len, max_new, contig_slots, seed=0):
     """--prefix-share: N-way shared system prompts under one KV byte
-    budget, across contiguous / paged / paged-int8 engines."""
+    budget (``contig_slots`` full-length rows), across paged /
+    paged-int8 engines."""
     rng = np.random.RandomState(seed)
     systems = [rng.randint(1, 100, (L,)).astype(np.int64)
                for L in sys_lens]
@@ -311,21 +313,28 @@ def run_prefix_share(model, max_len, min_bucket, page_size, sys_lens,
         for i in range(n_req)]
     new = [max_new] * n_req
 
-    # the shared byte budget = the contiguous pool's allocation
-    contig = _run_burst(model, prompts, new, max_slots=contig_slots,
-                        max_len=max_len, min_bucket=min_bucket,
-                        kv_layout="contiguous")
-    budget = contig["engine"].cache.kv_bytes()
+    # the shared byte budget = a full-length K and V row a slot for
+    # `contig_slots` slots; such a pool runs one request a row, so a
+    # burst fills exactly its slots
+    ad = model.cache_spec()
+    itemsize = np.dtype(ad.dtype).itemsize
+    budget = contig_slots * max_len * ad.num_layers * 2 * ad.kv_heads \
+        * ad.head_dim * itemsize
+    # reference outputs: the same requests through an unshared paged
+    # engine (token-identical to generate(): tests/test_paged_kv.py)
+    ref_outputs = _run_burst(
+        model, prompts, new, max_slots=contig_slots, max_len=max_len,
+        min_bucket=min_bucket, page_size=page_size,
+        prefix_sharing=False)["outputs"]
 
     def pages_for(quant):
-        ad = contig["engine"].adapter
         per_page = ad.num_layers * 2 * page_size * ad.kv_heads \
-            * ad.head_dim * (1 if quant else ad.dtype.itemsize)
+            * ad.head_dim * (1 if quant else itemsize)
         if quant:
             per_page += ad.num_layers * 2 * page_size * ad.kv_heads * 4
         return max(int(budget // per_page), max_len // page_size + 1)
 
-    results = {"contiguous": contig}
+    results = {}
     for name, quant in (("paged", None), ("paged_int8", "int8")):
         n_pages = pages_for(quant is not None)
         res = _run_burst(
@@ -338,19 +347,17 @@ def run_prefix_share(model, max_len, min_bucket, page_size, sys_lens,
         assert over <= budget, (name, over, budget)
         results[name] = res
     # bf16/model-dtype paged path must stay token-identical
-    assert results["paged"]["outputs"] == contig["outputs"], \
-        "paged shared-prefix outputs diverged from contiguous"
+    assert results["paged"]["outputs"] == ref_outputs, \
+        "paged shared-prefix outputs diverged from the unshared engine"
     int8_agree = np.mean([float(a == b)
                           for x, y in zip(results["paged_int8"]["outputs"],
-                                          contig["outputs"])
+                                          ref_outputs)
                           for a, b in zip(x, y)])
 
     stats = results["paged"]["engine"].paged_stats()
     stats8 = results["paged_int8"]["engine"].paged_stats()
-    gain = results["paged"]["peak_concurrency"] \
-        / max(1, contig["peak_concurrency"])
-    gain8 = results["paged_int8"]["peak_concurrency"] \
-        / max(1, contig["peak_concurrency"])
+    gain = results["paged"]["peak_concurrency"] / contig_slots
+    gain8 = results["paged_int8"]["peak_concurrency"] / contig_slots
     print(json.dumps({
         "metric": (
             f"paged-KV max concurrency under one KV byte budget "
@@ -362,8 +369,8 @@ def run_prefix_share(model, max_len, min_bucket, page_size, sys_lens,
             f"{results['paged_int8']['peak_concurrency']} "
             f"({gain8:.1f}x), prefix hit rate "
             f"{stats['prefix_hit_rate']:.2f}, int8 greedy agreement "
-            f"{int8_agree:.3f}; baseline=contiguous slot pool "
-            f"({contig['peak_concurrency']} concurrent)"),
+            f"{int8_agree:.3f}; baseline=one full-length row a slot "
+            f"({contig_slots} concurrent)"),
         "value": round(gain8, 2),
         "unit": "x concurrency",
         "vs_baseline": 1.0}))
@@ -371,7 +378,7 @@ def run_prefix_share(model, max_len, min_bucket, page_size, sys_lens,
         "budget_bytes": int(budget),
         "page_size": page_size,
         "num_pages": int(stats8["num_pages"]),
-        "peak_concurrency_contiguous": contig["peak_concurrency"],
+        "peak_concurrency_contiguous": contig_slots,
         "peak_concurrency_paged": results["paged"]["peak_concurrency"],
         "peak_concurrency_paged_int8":
             results["paged_int8"]["peak_concurrency"],
@@ -384,8 +391,6 @@ def run_prefix_share(model, max_len, min_bucket, page_size, sys_lens,
         "int8_greedy_agreement": round(float(int8_agree), 4),
         "tokens_per_s_paged":
             round(results["paged"]["tokens_per_s"], 1),
-        "tokens_per_s_contiguous":
-            round(contig["tokens_per_s"], 1),
         "decode_compiles":
             results["paged"]["engine"].trace_counts["decode"],
     }))

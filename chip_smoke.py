@@ -213,8 +213,7 @@ def _serve(model, cfg, **engine_kw):
     import numpy as np
     from paddle_tpu.observability import MetricRegistry
     from paddle_tpu.serving import FrontDoor, ReplicaRouter, ServingEngine
-    eng = ServingEngine(model, max_slots=16, max_len=512,
-                        kv_layout="paged", **engine_kw)
+    eng = ServingEngine(model, max_slots=16, max_len=512, **engine_kw)
     front = FrontDoor(ReplicaRouter([eng], registry=MetricRegistry()),
                       registry=MetricRegistry())
     rng = np.random.RandomState(0)

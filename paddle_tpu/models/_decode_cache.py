@@ -1,26 +1,25 @@
-"""Shared fixed-buffer KV-cache attention for serving decode.
+"""Shared KV-cache attention for serving decode.
 
-One pure-jax routine used by every causal LM's static-cache path
-(llama RoPE attention, gpt learned-position attention): write the new
-k/v block into the fixed ``[B, Tmax, KV, D]`` buffers at the write
-position (``dynamic_update_slice``) and attend over the causally
-masked full buffer.
+``cache_attend`` is the pure-jax routine of every causal LM's
+fixed-buffer path (llama RoPE attention, gpt learned-position
+attention): write the new k/v block into the fixed ``[B, Tmax, KV, D]``
+buffers at the write position and attend over the causally masked full
+buffer. Its write position ``p`` is a SCALAR, the whole batch at one
+position (``dynamic_update_slice``): the synchronized ``generate()``
+decode and every whole-prompt prefill. ``paged_cache_attend`` (below)
+is what the serving engine's per-row programs run. Both lower to the
+same einsum contraction, so per-row results are bitwise identical to
+the scalar path's, which is what makes the serving engine's greedy
+outputs token-identical to ``generate()``'s.
 
-The write position ``p`` is either a SCALAR (the whole batch is at one
-position — the synchronized ``generate()`` decode) or a PER-ROW
-``[B]`` vector (every row at its own position — the continuous-batching
-slot-pool decode, ``paddle_tpu/serving``). Both lower to the same
-einsum contraction so per-row results are bitwise identical to the
-scalar path's, which is what makes the serving engine's greedy outputs
-token-identical to ``generate()``'s.
-
-Both flavors also take an optional per-row write length ``wlen``
-(``[B]`` int32) — the SPECULATIVE-VERIFY contract: row ``b`` carries
-``wlen[b]`` real tokens (the last emitted token + its draft window)
-followed by ``t - wlen[b]`` padding, and only the real tokens write
-their k/v (token ``j``'s write is DROPPED when ``j >= wlen[b]`` —
-out-of-range scatter index on the contiguous path, trash-page redirect
-on the paged path), so padded lanes can never clobber live positions
+Both also take an optional per-row write length ``wlen`` (``[B]``
+int32; ``cache_attend`` then takes a per-row ``[B]`` position too) —
+the SPECULATIVE-VERIFY contract: row ``b`` carries ``wlen[b]`` real
+tokens (the last emitted token + its draft window) followed by
+``t - wlen[b]`` padding, and only the real tokens write their k/v
+(token ``j``'s write is DROPPED when ``j >= wlen[b]`` — out-of-range
+scatter index in the fixed buffers, trash-page redirect in the
+pages), so padded lanes can never clobber live positions
 or run past a row's budget. Reads are untouched: position ``j`` still
 attends causally over everything ``<= pos + j``, so the per-position
 outputs for ``j < wlen[b]`` are bitwise what a sequential
@@ -46,16 +45,16 @@ partitioning preserves BITWISE identity with the single-chip program.
 The serving engine relies on this for its sharded token-identity law.
 
 ``paged_cache_attend`` is the PAGE-TABLE flavor of the same attention:
-instead of one contiguous ``[B, Tmax, KV, D]`` row per sequence, k/v
-live in a shared pool of fixed-size pages ``[num_pages, page, KV, D]``
+instead of one ``[B, Tmax, KV, D]`` row per sequence, k/v live in a
+shared pool of fixed-size pages ``[num_pages, page, KV, D]``
 and each row carries a static ``[B, pages_per_seq]`` int32 page table.
 Writes scatter the new tokens through the table (flat position ``f``
 lands in page ``table[b, f // page]`` at offset ``f % page``); reads
 gather the row's pages back into a ``[B, pages_per_seq * page, KV, D]``
 view and run the IDENTICAL masked einsum as ``cache_attend`` — when
 ``pages_per_seq * page == Tmax`` the contraction shapes match the
-contiguous path exactly, which is what keeps paged greedy decode
-token-identical to the slot-pool path. Optional int8 storage keeps the
+fixed-buffer path exactly, which is what keeps paged greedy decode
+token-identical to ``generate()``. Optional int8 storage keeps the
 pools in int8 with per-page f32 scales (one scale per page slot ×
 position × kv-head, absmax over head_dim) and dequantizes inside the
 attend.
@@ -95,9 +94,8 @@ class CacheSpec:
     steps (its ``cache_spec()``), a layer and a slot:
 
     - ``kind="kv"``: K and V by position, ``[positions, kv_heads,
-      head_dim]`` each, in ``dtype``; the engine chooses the pool (a row
-      a slot, or pages) and the forward's cache tuples are
-      ``models/_decode_cache``'s;
+      head_dim]`` each, in ``dtype``; the engine holds them in pages
+      and the forward's cache tuples are ``models/_decode_cache``'s;
     - ``kind="state"``: fixed-size arrays whatever the length, ``state``
       naming each with its shape a slot and its dtype; a prefill builds
       them (cache ``(None, None, true_len)``), a decode step reads and
@@ -131,15 +129,16 @@ def check_cache_pos(pos, t: int, Tmax: int) -> bool:
     return per_row
 
 
-def cache_attend(qr, kr, v, kc, vc, p, per_row: bool, wlen=None):
+def cache_attend(qr, kr, v, kc, vc, p, wlen=None):
     """Masked fixed-buffer cache attention.
 
     qr: [B, t, H, D] position-encoded queries; kr/v: [B, t, KV, D] new
     keys (position-encoded) / values; kc/vc: [B, Tmax, KV, D] cache
-    buffers; p: int32 write position — scalar, or [B] when ``per_row``.
-    ``wlen`` ([B] int32, per_row only): only the first ``wlen[b]``
-    incoming tokens of row ``b`` write their k/v (speculative verify —
-    see module docstring); None = every token writes. GQA folds the
+    buffers; p: int32 write position — a scalar, or with ``wlen`` also
+    [B]. ``wlen`` ([B] int32): only the first ``wlen[b]`` incoming
+    tokens of row ``b`` write their k/v (speculative verify, chunked
+    prefill — see module docstring); None = every token writes, the
+    whole batch at one position. GQA folds the
     query-group dim into the einsum against kv-head caches instead of
     materializing a head-repeated cache copy.
 
@@ -149,38 +148,31 @@ def cache_attend(qr, kr, v, kc, vc, p, per_row: bool, wlen=None):
     kv = kr.shape[2]
     rep = h // kv
     Tmax = kc.shape[1]
-    if wlen is not None and not per_row:
-        # scalar-pos + wlen is the CHUNKED-PREFILL flavor (one row at
-        # one position, a real-token count gating the padded tail):
-        # broadcast the position and take the per-row masked-scatter
-        # path, which is bitwise-identical for the same positions
+    if wlen is not None:
+        # a scalar position with wlen is the CHUNKED-PREFILL flavor
+        # (one row at one position, a real-token count gating the
+        # padded tail): broadcast it onto the per-row path
         p = jnp.broadcast_to(jnp.asarray(p, jnp.int32), (b,))
-        per_row = True
-    if per_row:
-        if wlen is None:
-            upd = lambda c, u, pi: jax.lax.dynamic_update_slice(
-                c, u.astype(c.dtype), (pi, 0, 0))
-            kc = jax.vmap(upd)(kc, kr, p)
-            vc = jax.vmap(upd)(vc, v, p)
-        else:
-            # write-masked scatter: token j of row b lands at p[b]+j
-            # only when j < wlen[b] AND in range; everything else gets
-            # index Tmax and mode="drop" discards it (a clamped
-            # dynamic_update_slice would smear masked/overflowing
-            # writes over the live tail instead)
-            idx = p[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
-            ok = (jnp.arange(t, dtype=jnp.int32)[None, :]
-                  < wlen[:, None]) & (idx < Tmax)
-            widx = jnp.where(ok, idx, Tmax)
-            bidx = jnp.arange(b)[:, None]
-            kc = kc.at[bidx, widx].set(kr.astype(kc.dtype),
-                                       mode="drop")
-            vc = vc.at[bidx, widx].set(v.astype(vc.dtype),
-                                       mode="drop")
+        # write-masked scatter: token j of row b lands at p[b]+j only
+        # when j < wlen[b] AND in range; everything else gets index
+        # Tmax and mode="drop" discards it (a clamped
+        # dynamic_update_slice would smear masked/overflowing writes
+        # over the live tail instead)
+        idx = p[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
+        ok = (jnp.arange(t, dtype=jnp.int32)[None, :]
+              < wlen[:, None]) & (idx < Tmax)
+        widx = jnp.where(ok, idx, Tmax)
+        bidx = jnp.arange(b)[:, None]
+        kc = kc.at[bidx, widx].set(kr.astype(kc.dtype), mode="drop")
+        vc = vc.at[bidx, widx].set(v.astype(vc.dtype), mode="drop")
         qpos = p[:, None] + jnp.arange(t)[None, :]            # [B, t]
         mask = jnp.arange(Tmax)[None, None, :] <= qpos[:, :, None]
         maskx = mask[:, None, None]                    # [B,1,1,t,Tmax]
     else:
+        if jnp.ndim(p):
+            raise ValueError(
+                "per-row cache positions need per-row write lengths: "
+                "pass the 4-tuple cache (k, v, pos, wlen)")
         kc = jax.lax.dynamic_update_slice(
             kc, kr.astype(kc.dtype), (0, p, 0, 0))
         vc = jax.lax.dynamic_update_slice(
@@ -285,9 +277,9 @@ def paged_cache_attend(qr, kr, v, kp, vp, ks, vs, table, p,
             qr[:, 0], kp, vp, table, pv, out_dtype)
         return out.reshape(b, 1, h * D), kp, vp, ks, vs
     note_fact("attend", "einsum")
-    # gather the row's pages into the contiguous attend view; with
-    # pages_per_seq * page == Tmax this is value-identical to the
-    # contiguous buffer, so the einsum below matches cache_attend's
+    # gather the row's pages into one [B, Tmax] attend view; with
+    # pages_per_seq * page == Tmax this is value-identical to a fixed
+    # buffer, so the einsum below matches cache_attend's
     gather = lambda pool: pool[table].reshape(
         b, Tmax, *pool.shape[2:])
     kc = _dequant(gather(kp), gather(ks)) if quant else gather(kp)
@@ -303,7 +295,7 @@ def paged_cache_attend(qr, kr, v, kp, vp, ks, vs, table, p,
         probs = jax.nn.softmax(scores, axis=-1).astype(out_dtype)
     else:
         # bf16 non-shared token-identity contract: same probs dtype
-        # and same value einsum as the contiguous cache_attend
+        # and same value einsum as cache_attend
         vc = gather(vp)
         probs = jax.nn.softmax(scores, axis=-1).astype(vc.dtype)
     out = jnp.einsum("bgrqk,bkgd->bqgrd", probs, vc)

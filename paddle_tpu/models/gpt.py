@@ -133,8 +133,8 @@ class GPTBlock(Layer):
     def forward(self, x, cache=None):
         """cache: optional (k_cache [b, Tmax, H, D], v_cache, pos) — the
         fixed-buffer serving decode path (mirrors llama's static cache;
-        pos is a scalar or a per-row [b] vector of write positions).
-        Returns (out, cache') when given."""
+        pos is a scalar, or with `wlen` also a per-row [b] vector of
+        write positions). Returns (out, cache') when given."""
         b, t, d = x.shape
         h = self.ln1(x)
         qkv = self.qkv(h)
@@ -189,14 +189,13 @@ class GPTBlock(Layer):
                 wlen = None
             # verify writes past the buffer are index-dropped, so only
             # the start position is checked on that flavor
-            per_row = check_cache_pos(
+            check_cache_pos(
                 pos, 1 if wlen is not None else t, k_cache.shape[1])
 
             def f(q, k, v, kc, vc, p, *rest):
                 wl = jnp.asarray(rest[0], jnp.int32) if rest else None
                 return cache_attend(q, k, v, kc, vc,
-                                    jnp.asarray(p, jnp.int32), per_row,
-                                    wlen=wl)
+                                    jnp.asarray(p, jnp.int32), wlen=wl)
 
             args = (q, k, v, k_cache, v_cache, pos) \
                 + ((wlen,) if wlen is not None else ())
@@ -235,9 +234,9 @@ class GPTModel(Layer):
         from ..ops.creation import arange
         if caches is not None:
             # serving decode: learned positions come from the cache's
-            # write position (scalar, or per-row for the slot pool);
-            # pos is the LAST element of the contiguous 3-tuple and
-            # paged 6-tuple flavors, second-to-last in the speculative
+            # write position (scalar, or per-row for the engine's
+            # slots); pos is the LAST element of the fixed-buffer
+            # 3-tuple and paged 6-tuple flavors, second-to-last in the
             # VERIFY flavors (4/7-tuples, which append `wlen`)
             verify = len(caches[0]) in (4, 7)
             base = caches[0][-2] if verify else caches[0][-1]

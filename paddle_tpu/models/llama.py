@@ -101,7 +101,7 @@ def _apply_rope(x, cos, sin):
     """x [B, T, H, D]; rotate pairs (x0,x1) per RoPE.
 
     cos/sin are [T, D/2] (shared positions) or [B, T, D/2] (per-row
-    positions — the serving slot-pool decode)."""
+    positions — the serving engine's decode)."""
     d2 = x.shape[-1] // 2
     x1 = x[..., :d2]
     x2 = x[..., d2:]
@@ -154,7 +154,7 @@ class LlamaAttention(Layer):
         (out, (k_cache', v_cache')) — the serving decode path."""
         cfg = self.cfg
         b, t, _ = x.shape
-        # cache flavors: len 3 = contiguous static buffers (k, v, pos);
+        # cache flavors: len 3 = fixed static buffers (k, v, pos);
         # len 6 = paged pool (k_pool, v_pool, k_scale, v_scale,
         # page_table, pos) — paddle_tpu/serving's paged KV cache;
         # len 4 / len 7 append a per-row write-length `wlen` — the
@@ -237,9 +237,10 @@ class LlamaAttention(Layer):
         (dynamic_update_slice), attend over the masked full buffer.
         q/k/v arrive reshaped [b, t, heads_local, D]; cache =
         (k_cache [b, Tmax, KV, D], v_cache, pos). ``pos`` is a scalar
-        (whole batch at one position — generate()) or a [b] vector of
+        (whole batch at one position — generate()); a [b] vector of
         per-row positions (every row independent — the continuous-
-        batching slot pool, paddle_tpu/serving).
+        batching engine, paddle_tpu/serving) goes with pages, or with
+        a ``wlen``.
 
         The 6-tuple flavor routes through paged_cache_attend instead:
         (k_pool, v_pool, k_scale, v_scale, page_table, pos) with
@@ -342,7 +343,7 @@ class LlamaAttention(Layer):
             p = jnp.asarray(p, jnp.int32)
             wl = jnp.asarray(rest[0], jnp.int32) if rest else None
             qr, kr = _rope(q, k, p)
-            return cache_attend(qr, kr, v, kc, vc, p, per_row, wlen=wl)
+            return cache_attend(qr, kr, v, kc, vc, p, wlen=wl)
 
         args = (q, k, v, k_cache, v_cache, pos) \
             + ((wlen,) if has_wl else ())

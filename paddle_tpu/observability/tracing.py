@@ -57,10 +57,13 @@ __all__ = ["Span", "span", "TraceContext", "TraceBuffer",
            "bind_request", "unbind_request", "clear_bindings",
            "context_for", "active_context", "query"]
 
-# the default ring holds a 50 s window of a saturated serving cell
-# (~1,100 engine steps x ~13 spans, ~15,000) twice over, about 20 MB
-# when full; ``dropped_total`` says when it did not
-DEFAULT_CAPACITY = 32768
+# the default ring holds a 50 s window of a serving cell twice over at
+# the shortest step the engine can run on the chip, so that set-up's
+# ``compile.*`` spans are still there when the window has closed: a
+# decode program bound by its 9.7 ms of HBM time a step (PERF.md
+# section 5) is ~5,200 steps x ~13 spans, ~67,000; about 80 MB when
+# full; ``dropped_total`` says when it did not
+DEFAULT_CAPACITY = 131072
 
 
 @dataclasses.dataclass(frozen=True)

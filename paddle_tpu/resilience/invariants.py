@@ -337,7 +337,8 @@ def page_leak_violations(engine) -> List[str]:
     recover) dropped a refcount on the floor — exactly the class of
     bug paging adds to the engine's failure surface.
 
-    No-op (empty) for a contiguous-pool engine."""
+    No-op (empty) for an engine that holds no pages (a state
+    engine)."""
     cache = engine.cache
     if not getattr(engine, "paged", False):
         return []

@@ -8,8 +8,9 @@ design): one compiled decode-step program serves ANY mix of in-flight
 requests, admission is gated by free PAGES (worst-case span reserved,
 so decode never preempts), and finished sequences (EOS / length cap)
 are evicted immediately, their shared prompt pages staying cached for
-later requests. ``kv_layout="contiguous"`` selects the original
-slot-pool flavor (fixed ``(max_slots, max_len)`` rows) for A/B.
+later requests. A model that keeps a recurrent state and no K/V
+(``cache_spec().kind == "state"``) is served from a state row a slot
+instead; nothing chooses between the two, the model's kind does.
 
     engine = ServingEngine(model, max_slots=8, max_len=512, eos_id=2)
     req = engine.submit(prompt_ids, max_new_tokens=64)
@@ -63,8 +64,7 @@ from .router import Replica, ReplicaRouter  # noqa: F401
 from .sampling import SamplingParams, sample_token  # noqa: F401
 from .scheduler import (FIFOScheduler, Request, bucket_for,  # noqa: F401
                         prefill_buckets)
-from .slot_cache import (PagedKVCache, SlotKVCache,  # noqa: F401
-                         SlotStateCache)
+from .slot_cache import PagedKVCache, SlotStateCache  # noqa: F401
 from .spec_decode import (DraftModelProposer,  # noqa: F401
                           NgramProposer)
 from .spec_tune import SpecTuner  # noqa: F401
@@ -72,7 +72,7 @@ from .spec_tune import SpecTuner  # noqa: F401
 __all__ = ["ServingEngine", "EngineMetrics", "MeshContext",
            "SamplingParams",
            "sample_token", "FIFOScheduler", "Request", "bucket_for",
-           "prefill_buckets", "SlotKVCache", "PagedKVCache",
+           "prefill_buckets", "PagedKVCache",
            "SlotStateCache", "StateCacheUnsupported",
            "NgramProposer", "DraftModelProposer", "SpecTuner",
            "ServingError",

@@ -2,8 +2,8 @@
 path.
 
 ONE compiled decode-step program (fixed ``[max_slots, 1]`` token block,
-per-slot positions, active-slot mask — and, on the default PAGED
-layout, the static page table) serves any mix of in-flight requests;
+per-slot positions, active-slot mask and, for a model that caches K
+and V, the static page table) serves any mix of in-flight requests;
 prefill compiles once per power-of-2 length bucket (full-prompt and
 shared-prefix-extend flavors). Compare
 ``benchmarks/bench_llama_decode.py``'s synchronized path, where every
@@ -42,7 +42,7 @@ handoff between them (docs/SERVING.md "Multi-chip serving").
 
 Resilience contract (docs/RESILIENCE.md): a step that fails with
 donated cache pools marks the engine broken — ``recover()`` rebuilds
-the slot-pool KV cache from host-side request state (re-prefilling
+the cache pool from host-side request state (re-prefilling
 in-flight requests; greedy replay is verified token-identical) instead
 of the old permanently-poisoned dead-end. Admission is bounded
 (``max_queue`` → typed ``QueueFull``), requests carry optional
@@ -53,7 +53,6 @@ deadlines (cancelled at step boundaries with ``finish_reason ==
 """
 from __future__ import annotations
 
-import contextlib
 import time
 from typing import Callable, List, Optional, Sequence
 
@@ -74,7 +73,7 @@ from .mesh import MeshContext
 from .metrics import EngineMetrics
 from .sampling import SamplingParams, sample_token, sampling_dist
 from .scheduler import FIFOScheduler, Request, bucket_for
-from .slot_cache import PagedKVCache, SlotKVCache, SlotStateCache
+from .slot_cache import PagedKVCache, SlotStateCache
 from .spec_decode import DraftModelProposer, NgramProposer
 from .spec_tune import SpecTuner
 
@@ -125,11 +124,9 @@ class _ModelAdapter:
                 for _ in range(self.num_layers)]
 
     def decode_caches(self, pools, pos, active):
-        """Per-layer cache tuples of the slot-row pools for one decode
-        step at per-slot positions."""
-        if self.stateful:
-            return [layer + (pos, active) for layer in zip(*pools)]
-        return [layer + (pos,) for layer in zip(*pools)]
+        """Per-layer cache tuples of a stateful model's slot-row pools
+        for one decode step at per-slot positions."""
+        return [layer + (pos, active) for layer in zip(*pools)]
 
 
 class ServingEngine:
@@ -244,29 +241,27 @@ class ServingEngine:
                      kv_transport is not None)):
                 if asked:
                     raise StateCacheUnsupported(option, value)
-            kv_layout = "state"
-        elif kv_layout is None:
-            kv_layout = "paged"
         elif kv_layout == "state":
             raise ValueError(
                 f"kv_layout='state' is for a model whose cache_spec() "
                 f"is a recurrent state; {type(model).__name__} caches "
                 f"K and V")
-        if kv_layout not in ("paged", "contiguous", "state"):
+        elif kv_layout == "contiguous":
             raise ValueError(
-                f"kv_layout must be 'paged' or 'contiguous', got "
+                "kv_layout='contiguous': the contiguous slot pool is "
+                "gone, pages serve K and V (leave kv_layout unset)")
+        elif kv_layout not in (None, "paged"):
+            raise ValueError(
+                f"kv_layout follows from the model's cache_spec() "
+                f"('paged' for {type(model).__name__}), got "
                 f"{kv_layout!r}")
         if kv_dtype not in (None, "int8"):
             raise ValueError(
                 f"kv_dtype must be None (model dtype) or 'int8', got "
                 f"{kv_dtype!r}")
-        if kv_layout == "contiguous" and (
-                page_size is not None or num_pages is not None
-                or kv_dtype is not None or prefix_sharing is not None):
-            raise ValueError(
-                "page_size/num_pages/kv_dtype/prefix_sharing only "
-                "apply to the paged kv_layout")
-        self.paged = kv_layout == "paged"
+        # the layout follows from what the model caches: K and V by
+        # position live in pages, a recurrent state in a row a slot
+        self.paged = not self.adapter.stateful
         if self.paged:
             if page_size is None:
                 # largest power-of-2 divisor of max_len, capped at 128
@@ -280,8 +275,8 @@ class ServingEngine:
             self.prefix_sharing = True if prefix_sharing is None \
                 else bool(prefix_sharing)
         else:
-            # a slot's row, or its state, is its one page: nothing is
-            # shared and nothing quantized
+            # a slot's state is its one page: nothing is shared and
+            # nothing quantized
             self.page_size, self.num_pages = self.max_len, None
             self.kv_quant = self.prefix_sharing = False
         # KV tiering (docs/SERVING.md "KV tiering"): demote cold
@@ -297,11 +292,10 @@ class ServingEngine:
                 "host_tier_pages requires kv_host_tier=True (or "
                 "prefix_store_dir=)")
         if self.kv_host_tier:
-            if not (self.paged and self.prefix_sharing):
+            if not self.prefix_sharing:
                 raise ValueError(
-                    "kv_host_tier requires the paged kv_layout with "
-                    "prefix_sharing enabled (the tier is keyed by "
-                    "radix chunks)")
+                    "kv_host_tier requires prefix_sharing enabled "
+                    "(the tier is keyed by radix chunks)")
             if mesh is not None:
                 raise ValueError(
                     "kv_host_tier is not supported on mesh engines "
@@ -598,15 +592,12 @@ class ServingEngine:
                           "acc_len_hist": [0] * (self.spec_k + 1)}
 
     def _new_cache(self):
-        """Fresh KV pool in the configured layout (init + recover).
-        On a mesh engine the pools are committed SHARDED (kv_heads
-        over the `model` axis) to the DECODE group, which owns all
-        pool state — disaggregated prefills hand their KV over."""
+        """Fresh pool of what the model caches (init + recover): K/V
+        pages, or a state row a slot. On a mesh engine the pages are
+        committed SHARDED (kv_heads over the `model` axis) to the
+        DECODE group, which owns all pool state — disaggregated
+        prefills hand their KV over."""
         ad = self.adapter
-        kv_sh = sc_sh = None
-        if self.meshctx is not None:
-            kv_sh = self.meshctx.kv_sharding()
-            sc_sh = self.meshctx.scale_sharding()
         if ad.stateful:
             if len(ad.spec.state) != 2:
                 raise NotImplementedError(
@@ -615,18 +606,18 @@ class ServingEngine:
                     f"{len(ad.spec.state)}")
             return SlotStateCache(ad.num_layers, self.max_slots,
                                   ad.spec.state)
-        if self.paged:
-            return PagedKVCache(
-                ad.num_layers, self.max_slots, self.max_len,
-                ad.kv_heads, ad.head_dim, ad.dtype,
-                page_size=self.page_size, num_pages=self.num_pages,
-                quant=self.kv_quant,
-                prefix_sharing=self.prefix_sharing,
-                kv_sharding=kv_sh, scale_sharding=sc_sh,
-                tier=self._kv_tier)
-        return SlotKVCache(
+        kv_sh = sc_sh = None
+        if self.meshctx is not None:
+            kv_sh = self.meshctx.kv_sharding()
+            sc_sh = self.meshctx.scale_sharding()
+        return PagedKVCache(
             ad.num_layers, self.max_slots, self.max_len,
-            ad.kv_heads, ad.head_dim, ad.dtype, kv_sharding=kv_sh)
+            ad.kv_heads, ad.head_dim, ad.dtype,
+            page_size=self.page_size, num_pages=self.num_pages,
+            quant=self.kv_quant,
+            prefix_sharing=self.prefix_sharing,
+            kv_sharding=kv_sh, scale_sharding=sc_sh,
+            tier=self._kv_tier)
 
     def _refresh_state(self) -> None:
         """Re-snapshot the model weights (checkpoint loads /
@@ -691,8 +682,6 @@ class ServingEngine:
     def _publish_page_stats(self, sp=None) -> None:
         """Pool and prefix counters into the registry and, as this
         step's counts, onto the ``serving.step`` span ``sp``."""
-        if not self.paged:
-            return
         c = self.cache
         in_use = c.active_page_count()
         self._m_pages_free.set(c.free_page_count())
@@ -753,10 +742,10 @@ class ServingEngine:
 
     def paged_stats(self) -> dict:
         """Paged-pool snapshot for benchmarks/dashboards (raises on a
-        contiguous engine): cache page/prefix/COW counters plus the
-        peak concurrent in-flight requests this engine reached."""
+        state engine): cache page/prefix/COW counters plus the peak
+        concurrent in-flight requests this engine reached."""
         if not self.paged:
-            raise RuntimeError("paged_stats() on a contiguous engine")
+            raise RuntimeError("paged_stats() on a state engine")
         s = self.cache.stats()
         s["peak_active_slots"] = self.peak_active_slots
         s["prefix_hit_rate"] = (
@@ -1128,7 +1117,7 @@ class ServingEngine:
                 self.peak_active_slots = max(self.peak_active_slots,
                                              len(active))
                 self._publish_page_stats(sp)
-            elif self.adapter.stateful:
+            else:
                 in_use = len(self.cache.active_slots())
                 self._m_state_slots.set(in_use)
                 if sp is not None:
@@ -1334,17 +1323,16 @@ class ServingEngine:
             return
         copies = []
         try:
-            if self.paged:
-                with span("serving.decode.build") as sp:
-                    for s in active:
-                        copies += self.cache.ensure_decode_range(
-                            s, self.cache.slots[s].next_pos,
-                            int(wlen[s]))
-                    # COW copies BEFORE the kill point (same reason as
-                    # the plain decode: flipped table rows must never
-                    # outrun their copies)
-                    self._run_copies(copies)
-                    sp.set_attr("cow_copies", len(copies))
+            with span("serving.decode.build") as sp:
+                for s in active:
+                    copies += self.cache.ensure_decode_range(
+                        s, self.cache.slots[s].next_pos,
+                        int(wlen[s]))
+                # COW copies BEFORE the kill point (same reason as
+                # the plain decode: flipped table rows must never
+                # outrun their copies)
+                self._run_copies(copies)
+                sp.set_attr("cow_copies", len(copies))
             # mid-verify-step kill point: drafts built, pages
             # claimed/COW'd, nothing emitted yet — recovery must
             # replay token-identically and leak no pages
@@ -1357,26 +1345,16 @@ class ServingEngine:
                            tp=self.meshctx.tp)
             with self._decode_span("serving.verify", active, k=K):
                 with span("serving.decode.enqueue", batch=len(active)):
-                    if self.paged:
-                        logits, greedy, acc, ks, vs, kss, vss = \
-                            self._verify_fn()(
-                                self._params, self._buffers, toks,
-                                pos, mask, wlen,
-                                self.cache.page_table.copy(),
-                                self.cache.ks, self.cache.vs,
-                                self.cache.kss, self.cache.vss)
-                        self.cache.ks, self.cache.vs = \
-                            list(ks), list(vs)
-                        self.cache.kss, self.cache.vss = \
-                            list(kss), list(vss)
-                    else:
-                        logits, greedy, acc, ks, vs = \
-                            self._verify_fn()(
-                                self._params, self._buffers, toks,
-                                pos, mask, wlen,
-                                self.cache.ks, self.cache.vs)
-                        self.cache.ks, self.cache.vs = \
-                            list(ks), list(vs)
+                    logits, greedy, acc, ks, vs, kss, vss = \
+                        self._verify_fn()(
+                            self._params, self._buffers, toks,
+                            pos, mask, wlen,
+                            self.cache.page_table.copy(),
+                            self.cache.ks, self.cache.vs,
+                            self.cache.kss, self.cache.vss)
+                    self.cache.ks, self.cache.vs = list(ks), list(vs)
+                    self.cache.kss, self.cache.vss = \
+                        list(kss), list(vss)
                 logits, greedy, acc = (
                     self._fetch("serving.decode.fetch", x)
                     for x in (logits, greedy, acc))
@@ -1390,12 +1368,10 @@ class ServingEngine:
             # the admission pool on every faulted step. Return them
             # NOW; the retried step re-claims idempotently (the page
             # holding next_pos itself is kept — the retry writes it).
-            if self.paged:
-                for s in active:
-                    req = self.cache.slots[s]
-                    if req is not None:
-                        self.cache.rollback_speculation(
-                            s, req.next_pos)
+            for s in active:
+                req = self.cache.slots[s]
+                if req is not None:
+                    self.cache.rollback_speculation(s, req.next_pos)
             raise
         emitted_by_slot = {}
         try:
@@ -1413,7 +1389,7 @@ class ServingEngine:
                     self._m_spec_acc.labels(
                         proposer=row_kind.get(s, "none")).observe(
                             float(emitted))
-                    if self.paged and not req.finished:
+                    if not req.finished:
                         # return pages past the next write position that
                         # only rejected draft tokens touched (finished
                         # rows release everything below)
@@ -1426,12 +1402,10 @@ class ServingEngine:
             # same debt the pre-verify except arm pays. Tokens already
             # appended stay appended (out_tokens only ever grows; the
             # retried step continues from the advanced next_pos).
-            if self.paged:
-                for s in active:
-                    req = self.cache.slots[s]
-                    if req is not None and not req.finished:
-                        self.cache.rollback_speculation(
-                            s, req.next_pos)
+            for s in active:
+                req = self.cache.slots[s]
+                if req is not None and not req.finished:
+                    self.cache.rollback_speculation(s, req.next_pos)
             raise
         self._spec["steps"] += 1
         # feed the tuner every ATTEMPTED row's accepted length (an
@@ -1919,7 +1893,8 @@ class ServingEngine:
 
     def _prefill(self, slot: int, req: Request) -> None:
         """Run the bucketed prefill program for one request, write its
-        k/v into the slot row, and sample its first token (TTFT).
+        k/v into the slot's pages (or its state into the slot's row),
+        and sample its first token (TTFT).
 
         A request adopted mid-flight (router failover: it already
         carries delivered tokens) re-prefills prompt + those tokens
@@ -1955,12 +1930,13 @@ class ServingEngine:
                      request_id=None, req=None,
                      cancel_check: bool = False) -> np.ndarray:
         """Write ``ids``'s k/v into positions ``0..len-1`` of the slot
-        row via the bucketed prefill program and return the host
-        logits at the last real token. Shared by admission prefill and
+        (a stateful model: build the slot's state from them) via the
+        bucketed prefill program and return the host logits at the
+        last real token. Shared by admission prefill and
         ``recover()``'s re-prefill (which replays prompt + delivered
         tokens through the same program).
 
-        Paged: the prompt is first matched against the prefix index —
+        K/V: the prompt is first matched against the prefix index —
         matched pages are referenced instead of recomputed and only
         the tail runs through a prefill program (the full-prompt
         program when nothing matched, the paged EXTEND program — which
@@ -1968,8 +1944,6 @@ class ServingEngine:
         pages were claimed unwinds them (abort_sequence)."""
         maybe_fail("serving.step.prefill", slot=slot)
         n = int(ids.shape[0])
-        disagg = self.meshctx is not None \
-            and self.meshctx.disaggregated
         if not self.paged:
             if cancel_check and req is not None \
                     and self._cancel_requested(req):
@@ -1987,31 +1961,16 @@ class ServingEngine:
                                   and req.out_tokens)) as sp:
                 padded = np.zeros((1, bucket), np.int64)
                 padded[0, :n] = ids
-                if disagg:
-                    # compute on the PREFILL group, then hand the
-                    # finished rows to the decode-owned pool; a
-                    # failed handoff's staged span dies with this
-                    # frame (the contiguous pool has no page claims
-                    # to unwind — the slot was never assigned)
-                    logits, kb, vb = self._prefill_fn()(
-                        self._params_pf, self._buffers_pf, padded,
-                        np.int32(n))
-                    try:
-                        self._kv_handoff(req, slot, (kb, vb),
-                                         cancel_check=cancel_check)
-                    except Exception:
-                        if req is not None:
-                            self._staged_handoffs.pop(req.rid, None)
-                        raise
-                else:
-                    with self._state_reset(slot, sp):
-                        logits, *pools = self._prefill_fn()(
-                            self._params, self._buffers, padded,
-                            np.int32(n), np.int32(slot),
-                            *self.cache.pools)
-                        self.cache.pools = pools
+                with self._state_reset(slot, sp):
+                    logits, *pools = self._prefill_fn()(
+                        self._params, self._buffers, padded,
+                        np.int32(n), np.int32(slot),
+                        *self.cache.pools)
+                    self.cache.pools = pools
                 return self._fetch("serving.prefill.fetch", logits)
         cache = self.cache
+        disagg = self.meshctx is not None \
+            and self.meshctx.disaggregated
         try:
             if req.rid not in cache._plans:
                 # admission reserves at claim time; recover()'s
@@ -2101,13 +2060,11 @@ class ServingEngine:
             raise
 
     def _state_reset(self, slot: int, prefill_span):
-        """Around the prefill program's dispatch. For a model that keeps
-        a state it is the slot's reset on reuse and its new state's
-        installation (the program builds the state from nothing and
-        overwrites the slot's whole row): span ``serving.state.reset``.
-        K and V need none: the causal mask hides a row's stale tail."""
-        if not self.adapter.stateful:
-            return contextlib.nullcontext()
+        """Around a stateful model's prefill dispatch: the slot's reset
+        on reuse and its new state's installation (the program builds
+        the state from nothing and overwrites the slot's whole row):
+        span ``serving.state.reset``. K and V in pages need none: the
+        causal mask hides a stale tail."""
         prefill_span.set_attr("state_reset", True)
         self.cache.reset(slot)
         return span("serving.state.reset", slot=slot,
@@ -2129,47 +2086,44 @@ class ServingEngine:
         compute: the request enters the PREFILLING state (slot leased,
         pages placed, ``prefill_pos`` at the shared-prefix boundary)
         and advances one chunk per step from the fifo head
-        (``_chunk_step``). Paged admission already committed the
-        worst-case page reservation at claim time, so chunking can
-        never run out of pages mid-prompt."""
+        (``_chunk_step``). Admission already committed the worst-case
+        page reservation at claim time, so chunking can never run out
+        of pages mid-prompt."""
         self.metrics.on_first_prefill(req.rid)   # queue wait ends here
         ids = self._replay_ids(req)
-        start = 0
-        if self.paged:
-            cache = self.cache
-            try:
-                if req.rid not in cache._plans:
-                    # inside the unwind scope (abort_sequence no-ops
-                    # on a missing plan), so a reservation that fails
-                    # halfway can never strand its pinned pages
-                    if not cache.try_reserve(req, ids,
-                                             req.prompt_len
-                                             + req.max_new_tokens):
-                        raise RuntimeError(
-                            f"request {req.rid}: page reservation "
-                            f"failed at chunked admission")
-                cache.refresh_reservation(req, ids)
-                start, copies = cache.begin_sequence(slot, req, ids)
-                self._run_copies(copies)
-                self._stage_promotions(req, slot)
-            except Exception:
-                # pages claimed but the slot never assigned: the
-                # standard abort path returns every claim, and the
-                # caller (_step_inner) requeues the request
-                self._staged_promotions.pop(req.rid, None)
-                cache.abort_sequence(slot, req)
-                raise
-        self.cache.assign(slot, req)
+        cache = self.cache
+        try:
+            if req.rid not in cache._plans:
+                # inside the unwind scope (abort_sequence no-ops on a
+                # missing plan), so a reservation that fails halfway
+                # can never strand its pinned pages
+                if not cache.try_reserve(req, ids,
+                                         req.prompt_len
+                                         + req.max_new_tokens):
+                    raise RuntimeError(
+                        f"request {req.rid}: page reservation "
+                        f"failed at chunked admission")
+            cache.refresh_reservation(req, ids)
+            start, copies = cache.begin_sequence(slot, req, ids)
+            self._run_copies(copies)
+            self._stage_promotions(req, slot)
+        except Exception:
+            # pages claimed but the slot never assigned: the standard
+            # abort path returns every claim, and the caller
+            # (_step_inner) requeues the request
+            self._staged_promotions.pop(req.rid, None)
+            cache.abort_sequence(slot, req)
+            raise
+        cache.assign(slot, req)
         req.slot = slot
         req.prefill_pos = int(start)
         self._chunk_fifo.append(slot)
-        if self._params_pf is not None and \
-                (not self.paged or start == 0):
+        if self._params_pf is not None and start == 0:
             # disaggregated: chunks accumulate in local buffers on the
             # PREFILL group; the final span hands off to the decode
-            # pool. Paged prefix-hit admissions (start > 0) instead
-            # chunk through the decode-group program, like extends —
-            # they attend over shared pages resident in that pool.
+            # pool. Prefix-hit admissions (start > 0) instead chunk
+            # through the decode-group program, like extends — they
+            # attend over shared pages resident in that pool.
             self._chunk_local[req.rid] = self._new_chunk_local()
 
     def _new_chunk_local(self):
@@ -2229,37 +2183,27 @@ class ServingEngine:
 
     def _run_chunk(self, slot: int, req: Request, padded, pos: int,
                    t: int, final: bool, ids) -> np.ndarray:
-        """Run one chunk program in the layout/mesh-appropriate
-        flavor and return the host logits at the chunk's last real
-        token (only the FINAL chunk's logits are consumed)."""
+        """Run one chunk program (on the prefill group's local buffers
+        or through the decode group's pages) and return the host
+        logits at the chunk's last real token (only the FINAL chunk's
+        logits are consumed)."""
         if req.rid in self._chunk_local:
-            # disaggregated local-buffer mode (contiguous, or paged
-            # full prefill): compute on the prefill group; the final
-            # span ships through the _kv_handoff staging contract
+            # disaggregated local-buffer mode (a full prefill):
+            # compute on the prefill group; the final span ships
+            # through the _kv_handoff staging contract
             logits = self._chunk_local_run(req, padded, pos, t)
             if final:
-                if self.paged:
-                    self._chunk_finalize_handoff(slot, req,
-                                                 int(ids.shape[0]))
-                else:
-                    kb, vb = self._chunk_local[req.rid]
-                    self._kv_handoff(req, slot, (kb, vb))
+                self._chunk_finalize_handoff(slot, req,
+                                             int(ids.shape[0]))
             return logits
         cache = self.cache
-        if self.paged:
-            row = cache.page_table[slot]
-            logits, ks, vs, kss, vss = self._chunk_fn()(
-                self._params, self._buffers, padded,
-                np.int32(pos), np.int32(t), row.copy(),
-                cache.ks, cache.vs, cache.kss, cache.vss)
-            cache.ks, cache.vs = list(ks), list(vs)
-            cache.kss, cache.vss = list(kss), list(vss)
-        else:
-            logits, ks, vs = self._chunk_fn()(
-                self._params, self._buffers, padded,
-                np.int32(pos), np.int32(t), np.int32(slot),
-                cache.ks, cache.vs)
-            cache.ks, cache.vs = list(ks), list(vs)
+        row = cache.page_table[slot]
+        logits, ks, vs, kss, vss = self._chunk_fn()(
+            self._params, self._buffers, padded,
+            np.int32(pos), np.int32(t), row.copy(),
+            cache.ks, cache.vs, cache.kss, cache.vss)
+        cache.ks, cache.vs = list(ks), list(vs)
+        cache.kss, cache.vss = list(kss), list(vss)
         return self._fetch("serving.prefill.fetch", logits)
 
     def _chunk_local_run(self, req: Request, padded, pos: int,
@@ -2273,7 +2217,7 @@ class ServingEngine:
 
     def _chunk_finalize_handoff(self, slot: int, req: Request,
                                 n: int) -> None:
-        """Paged disaggregated final chunk: paginate (and int8-
+        """Disaggregated final chunk: paginate (and int8-
         quantize, when configured) the accumulated local buffers and
         install them at the claimed page ids via the standard KV
         handoff."""
@@ -2295,8 +2239,7 @@ class ServingEngine:
         self._chunk_fifo.pop(0)
         req.prefill_pos = None
         self._chunk_local.pop(req.rid, None)
-        if self.paged:
-            self.cache.register_prefix(slot, ids)
+        self.cache.register_prefix(slot, ids)
         if req.out_tokens:
             if req.sampling.temperature <= 0 \
                     and int(np.argmax(logits)) != req.out_tokens[-1]:
@@ -2325,15 +2268,14 @@ class ServingEngine:
     def _unwind_chunk(self, slot: int, req: Request,
                       requeue: bool) -> None:
         """Unwind a PREFILLING slot after a mid-chunk fault or
-        cancel: chunk bookkeeping dies, the paged claims return via
+        cancel: chunk bookkeeping dies, the page claims return via
         the standard abort path, and the lease frees (abort_sequence
         zeroed the table row and popped the plan, so release() has
         nothing left to double-unref). ``requeue`` puts the request
         back at the queue head — its replay re-chunks
         token-identically."""
         self._clear_chunk_state(slot, req)
-        if self.paged:
-            self.cache.abort_sequence(slot, req)
+        self.cache.abort_sequence(slot, req)
         self.cache.release(slot)
         req.slot = None
         if requeue:
@@ -2361,8 +2303,7 @@ class ServingEngine:
                 m.replicated_tree(bufs, group),
                 m.repl(group),
                 [m.kv_sharding(group)] * L,
-                [m.scale_sharding(group)] * L
-                if (self.paged and self.kv_quant) else [])
+                [m.scale_sharding(group)] * L if self.kv_quant else [])
 
     def _paged_caches(self, ks, vs, kss, vss, table, pos, wlen=None):
         """Per-layer paged cache tuples for the model forward
@@ -2420,18 +2361,18 @@ class ServingEngine:
         """Full-prompt prefill program, one compile per bucket length:
         run the prompt through a local [1, bucket] static cache, take
         the logits at the LAST REAL token (the bucket tail is
-        padding), and splice the local k/v into the pool — the slot
-        row of the contiguous pool, or the allocated pages (quantized
-        on the int8 path) of the paged pool. Pad-tail garbage is
+        padding), and splice the local k/v into the slot's allocated
+        pages (quantized on the int8 path); a stateful model's new
+        state overwrites the slot's row. Pad-tail garbage is
         harmless: the per-slot causal mask hides positions > the
         current length, and each decode step overwrites position
         ``len`` right before attending it; padded PAGE slots point at
         the reserved trash page.
 
         DISAGGREGATED engines compile a COMPUTE-ONLY flavor on the
-        PREFILL group instead: it returns the finished KV span (local
-        rows, or paginated + int8-quantized page blocks) rather than
-        writing the pool — the decode group owns the pool, and
+        PREFILL group instead: it returns the finished KV span
+        (paginated + int8-quantized page blocks) rather than writing
+        the pool — the decode group owns the pool, and
         ``_kv_handoff`` ships + installs the span explicitly."""
         if self._prefill_jit is not None:
             return self._prefill_jit
@@ -2448,29 +2389,11 @@ class ServingEngine:
                 logits = ad.head(Tensor(h_last))._data[0, -1]
             return logits, new_caches
 
-        disagg = self.meshctx is not None \
-            and self.meshctx.disaggregated
-
         if not self.paged:
-            if disagg:
-                def ptpu_prefill(params, buffers, ids, true_len):
-                    logits, new_caches = local_run(params, buffers,
-                                                   ids, true_len)
-                    d = lambda c: getattr(c, "_data", c)
-                    return (logits,
-                            [d(c[0]) for c in new_caches],
-                            [d(c[1]) for c in new_caches])
-
-                psh, bsh, R, kv, _ = self._prog_shardings("prefill")
-                self._prefill_jit = self._jit(
-                    ptpu_prefill, in_shardings=(psh, bsh, R, R),
-                    out_shardings=(R, kv, kv))
-                return self._prefill_jit
-
             def ptpu_prefill(params, buffers, ids, true_len, slot, ks, vs):
-                # ks/vs: the slot-row pools (K and V, or a stateful
-                # model's two state arrays): the slot's whole row is
-                # overwritten, which is a state's reset on reuse
+                # ks/vs: the slot-row pools (a stateful model's two
+                # state arrays): the slot's whole row is overwritten,
+                # which is a state's reset on reuse
                 logits, new_caches = local_run(params, buffers, ids,
                                                true_len)
                 splice = lambda pool, c: jax.lax.dynamic_update_slice(
@@ -2480,19 +2403,15 @@ class ServingEngine:
                 vs = [splice(p, c[1]) for p, c in zip(vs, new_caches)]
                 return logits, ks, vs
 
-            jit_kw = {}
-            if self.meshctx is not None:
-                psh, bsh, R, kv, _ = self._prog_shardings()
-                jit_kw = dict(in_shardings=(psh, bsh, R, R, R, kv, kv),
-                              out_shardings=(R, kv, kv))
             self._prefill_jit = self._jit(ptpu_prefill,
-                                        donate_argnums=self._donate(),
-                                        **jit_kw)
+                                        donate_argnums=self._donate())
             return self._prefill_jit
 
         from ..models._decode_cache import quantize_kv_page
         P = self.cache.page_size
         quant = self.kv_quant
+        disagg = self.meshctx is not None \
+            and self.meshctx.disaggregated
 
         def paginate_fn(npg, pad):
             def paginate(c):
@@ -2615,83 +2534,45 @@ class ServingEngine:
         argument, docs/SERVING.md "Chunked prefill"). Non-final
         chunks are exactly ``prefill_chunk`` tokens — their own
         bucket, zero padding; the final chunk's bucket padding is
-        write-masked by ``true_len`` (contiguous) or trash-redirected
-        (paged), the standard stale-tail story.
+        trash-redirected, the standard stale-tail story.
 
-        Paged flavor: the paged EXTEND machinery verbatim (page-table
-        writes at a mid-prompt start), counted under "chunk" so the
-        compile-budget pins see chunk programs separately. Contiguous
-        flavor: slice the slot row out of the pool, run the
-        write-masked static-cache path at a scalar start, splice the
-        row back."""
+        It is the EXTEND machinery verbatim (page-table writes at a
+        mid-prompt start), counted under "chunk" so the
+        compile-budget pins see chunk programs separately."""
         if self._chunk_jit is not None:
             return self._chunk_jit
         ad = self.adapter
-
-        if self.paged:
-            jit_kw = {}
-            if self.meshctx is not None:
-                psh, bsh, R, kv, sc = self._prog_shardings()
-                jit_kw = dict(
-                    in_shardings=(psh, bsh, R, R, R, R, kv, kv,
-                                  sc, sc),
-                    out_shardings=(R, kv, kv, sc, sc))
-
-            def ptpu_chunk(params, buffers, ids, start, true_len, row, ks,
-                     vs, kss, vss):
-                Lb = ids.shape[1]
-                self._count_trace("chunk", Lb)
-                caches = self._paged_caches(ks, vs, kss, vss,
-                                            row[None, :], start)
-                with ad.model.bind_state(params, buffers):
-                    h, new_caches = ad.call(Tensor(ids), caches)
-                    h_last = jax.lax.dynamic_slice_in_dim(
-                        h._data, true_len - 1, 1, axis=1)
-                    logits = ad.head(Tensor(h_last))._data[0, -1]
-                return (logits,) + self._unpack_paged(new_caches)
-
-            self._chunk_jit = self._jit(
-                ptpu_chunk, donate_argnums=self._donate_idx(6, 7, 8, 9),
-                **jit_kw)
-            return self._chunk_jit
-
         jit_kw = {}
         if self.meshctx is not None:
-            psh, bsh, R, kv, _ = self._prog_shardings()
+            psh, bsh, R, kv, sc = self._prog_shardings()
             jit_kw = dict(
-                in_shardings=(psh, bsh, R, R, R, R, kv, kv),
-                out_shardings=(R, kv, kv))
+                in_shardings=(psh, bsh, R, R, R, R, kv, kv, sc, sc),
+                out_shardings=(R, kv, kv, sc, sc))
 
-        def ptpu_chunk(params, buffers, ids, start, true_len, slot, ks, vs):
+        def ptpu_chunk(params, buffers, ids, start, true_len, row, ks,
+                 vs, kss, vss):
             Lb = ids.shape[1]
             self._count_trace("chunk", Lb)
-            rows = lambda pool: jax.lax.dynamic_slice(
-                pool, (slot, 0, 0, 0), (1,) + pool.shape[1:])
-            wl = jnp.reshape(jnp.asarray(true_len, jnp.int32), (1,))
-            caches = [(rows(k), rows(v), start, wl)
-                      for k, v in zip(ks, vs)]
+            caches = self._paged_caches(ks, vs, kss, vss,
+                                        row[None, :], start)
             with ad.model.bind_state(params, buffers):
                 h, new_caches = ad.call(Tensor(ids), caches)
                 h_last = jax.lax.dynamic_slice_in_dim(
                     h._data, true_len - 1, 1, axis=1)
                 logits = ad.head(Tensor(h_last))._data[0, -1]
-            splice = lambda pool, c: jax.lax.dynamic_update_slice(
-                pool, getattr(c, "_data", c).astype(pool.dtype),
-                (slot, 0, 0, 0))
-            ks = [splice(p, c[0]) for p, c in zip(ks, new_caches)]
-            vs = [splice(p, c[1]) for p, c in zip(vs, new_caches)]
-            return logits, ks, vs
+            return (logits,) + self._unpack_paged(new_caches)
 
         self._chunk_jit = self._jit(
-            ptpu_chunk, donate_argnums=self._donate_idx(6, 7), **jit_kw)
+            ptpu_chunk, donate_argnums=self._donate_idx(6, 7, 8, 9),
+            **jit_kw)
         return self._chunk_jit
 
     def _chunk_local_fn(self):
         """Disaggregated chunk program on the PREFILL group: advance
-        one chunk through the request's local [1, max_len] contiguous
-        buffers (write-masked past ``true_len``); the final span
-        ships via ``_kv_handoff`` (contiguous) or the paged finalize
-        program. One compile per chunk bucket — the buffers are
+        one chunk through the request's local [1, max_len] buffers
+        (write-masked past ``true_len``); the final span ships via
+        the finalize program and ``_kv_handoff``. One compile per
+        chunk bucket — the buffers are
         always full-length, so the key space is the ids bucket
         alone."""
         if self._chunk_local_jit is not None:
@@ -2721,7 +2602,7 @@ class ServingEngine:
         return self._chunk_local_jit
 
     def _chunk_fin_fn(self, npg: int):
-        """Paged disaggregated finalize program, one compile per page
+        """Disaggregated finalize program, one compile per page
         count: paginate the accumulated local buffers into the
         request's ``npg`` page blocks (int8-quantized here on the
         quantized path — every page is complete by now, so per-page
@@ -2763,63 +2644,45 @@ class ServingEngine:
         self._chunk_fin_jit[npg] = fn
         return fn
 
-    def _install_fn(self, key):
+    def _install_fn(self, npg: int):
         """Decode-group INSTALL program for one handed-off KV span
-        (disaggregated engines only), compiled once per block shape:
-        paged — scatter the shipped page blocks (int8 + scales on the
-        quantized path) into the pool at the claimed page ids;
-        contiguous — splice the shipped rows into the slot row. The
+        (disaggregated engines only), compiled once per page count:
+        scatter the shipped page blocks (int8 + scales on the
+        quantized path) into the pool at the claimed page ids. The
         shape key space is the prefill bucket set, so installs stay
         inside the same O(log max_len) compile budget as prefills."""
         if self._install_jit is None:
             self._install_jit = {}
-        fn = self._install_jit.get(key)
+        fn = self._install_jit.get(npg)
         if fn is not None:
             return fn
         m = self.meshctx
         L = self.adapter.num_layers
         R = m.repl()
         kv = [m.kv_sharding()] * L
-        sc = [m.scale_sharding()] * L \
-            if (self.paged and self.kv_quant) else []
+        sc = [m.scale_sharding()] * L if self.kv_quant else []
 
-        def count():
-            self._count_trace("install", key)
+        def ptpu_install(page_ids, kb, vb, ksb, vsb, ks, vs, kss, vss):
+            self._count_trace("install", npg)
+            ks = [p.at[page_ids].set(b.astype(p.dtype))
+                  for p, b in zip(ks, kb)]
+            vs = [p.at[page_ids].set(b.astype(p.dtype))
+                  for p, b in zip(vs, vb)]
+            kss = [p.at[page_ids].set(b)
+                   for p, b in zip(kss, ksb)]
+            vss = [p.at[page_ids].set(b)
+                   for p, b in zip(vss, vsb)]
+            return ks, vs, kss, vss
 
-        if self.paged:
-            def ptpu_install(page_ids, kb, vb, ksb, vsb, ks, vs, kss, vss):
-                count()
-                ks = [p.at[page_ids].set(b.astype(p.dtype))
-                      for p, b in zip(ks, kb)]
-                vs = [p.at[page_ids].set(b.astype(p.dtype))
-                      for p, b in zip(vs, vb)]
-                kss = [p.at[page_ids].set(b)
-                       for p, b in zip(kss, ksb)]
-                vss = [p.at[page_ids].set(b)
-                       for p, b in zip(vss, vsb)]
-                return ks, vs, kss, vss
-
-            fn = self._jit(
-                ptpu_install,
-                in_shardings=(R, kv, kv, sc, sc, kv, kv, sc, sc),
-                out_shardings=(kv, kv, sc, sc),
-                donate_argnums=self._donate_idx(5, 6, 7, 8))
-        else:
-            def ptpu_install(slot, kb, vb, ks, vs):
-                count()
-                splice = lambda pool, b: jax.lax.dynamic_update_slice(
-                    pool, b.astype(pool.dtype), (slot, 0, 0, 0))
-                return ([splice(p, b) for p, b in zip(ks, kb)],
-                        [splice(p, b) for p, b in zip(vs, vb)])
-
-            fn = self._jit(ptpu_install,
-                         in_shardings=(R, kv, kv, kv, kv),
-                         out_shardings=(kv, kv),
-                         donate_argnums=self._donate_idx(3, 4))
-        self._install_jit[key] = fn
+        fn = self._jit(
+            ptpu_install,
+            in_shardings=(R, kv, kv, sc, sc, kv, kv, sc, sc),
+            out_shardings=(kv, kv, sc, sc),
+            donate_argnums=self._donate_idx(5, 6, 7, 8))
+        self._install_jit[npg] = fn
         return fn
 
-    def _kv_handoff(self, req, slot, blocks, page_ids=None,
+    def _kv_handoff(self, req, slot, blocks, page_ids,
                     cancel_check: bool = False) -> None:
         """Disaggregated prefill -> decode KV handoff: ship a finished
         prefill's KV span from the prefill group to the decode group
@@ -2833,7 +2696,7 @@ class ServingEngine:
         staging ledger `_staged_handoffs` is audited empty at quiesce
         (cross-group no-leak law, resilience/invariants.py)."""
         m = self.meshctx
-        rid = req.rid if req is not None else -1
+        rid = req.rid
         # staged BEFORE the kill point; popped on successful install,
         # or by the caller's ABORT path on any raise below — the same
         # path that returns the decode-side page claims, so a
@@ -2842,13 +2705,12 @@ class ServingEngine:
         # unconditionally and make that audit vacuous)
         self._staged_handoffs[rid] = slot
         maybe_fail("serving.kv.handoff", slot=slot, rid=rid)
-        if cancel_check and req is not None \
-                and self._cancel_requested(req):
+        if cancel_check and self._cancel_requested(req):
             # the client vanished while its KV sat staged on the
             # prefill group: don't ship or install a span nobody
             # will decode — the abort path frees the page claims
             raise RequestCancelled(
-                req.rid, "client disconnected mid-KV-handoff")
+                rid, "client disconnected mid-KV-handoff")
         if self.kv_transport is not None:
             # cross-host hop: the blocks leave as bytes on a real
             # socket and come back digest-verified (kv_wire.py) —
@@ -2868,28 +2730,18 @@ class ServingEngine:
         dec_kv = [m.kv_sharding()] * L
         c = self.cache
         with span("serving.kv_handoff", slot=slot, request_id=rid):
-            if self.paged:
-                kb, vb, ksb, vsb = blocks
-                kb = jax.device_put(list(kb), dec_kv)
-                vb = jax.device_put(list(vb), dec_kv)
-                if self.kv_quant:
-                    dec_sc = [m.scale_sharding()] * L
-                    ksb = jax.device_put(list(ksb), dec_sc)
-                    vsb = jax.device_put(list(vsb), dec_sc)
-                out = self._install_fn(
-                    ("paged", int(page_ids.shape[0])))(
-                    page_ids, kb, vb, list(ksb), list(vsb),
-                    c.ks, c.vs, c.kss, c.vss)
-                c.ks, c.vs = list(out[0]), list(out[1])
-                c.kss, c.vss = list(out[2]), list(out[3])
-            else:
-                kb, vb = blocks
-                kb = jax.device_put(list(kb), dec_kv)
-                vb = jax.device_put(list(vb), dec_kv)
-                ks, vs = self._install_fn(
-                    ("contig", int(kb[0].shape[1])))(
-                    np.int32(slot), kb, vb, c.ks, c.vs)
-                c.ks, c.vs = list(ks), list(vs)
+            kb, vb, ksb, vsb = blocks
+            kb = jax.device_put(list(kb), dec_kv)
+            vb = jax.device_put(list(vb), dec_kv)
+            if self.kv_quant:
+                dec_sc = [m.scale_sharding()] * L
+                ksb = jax.device_put(list(ksb), dec_sc)
+                vsb = jax.device_put(list(vsb), dec_sc)
+            out = self._install_fn(int(page_ids.shape[0]))(
+                page_ids, kb, vb, list(ksb), list(vsb),
+                c.ks, c.vs, c.kss, c.vss)
+            c.ks, c.vs = list(out[0]), list(out[1])
+            c.kss, c.vss = list(out[2]), list(out[3])
         self._staged_handoffs.pop(rid, None)
 
     def _stage_promotions(self, req, slot: int) -> None:
@@ -2991,9 +2843,9 @@ class ServingEngine:
         advances one token at its own position; the active-slot mask
         pins inactive lanes to position 0 and zeroes their logits so
         they stay numerically inert whatever garbage their row holds.
-        Paged flavor: same contract, but k/v flow through the page
-        tables (inactive rows pinned to the trash page) — paging adds
-        ZERO decode compiles beyond this one program.
+        K and V flow through the page tables (inactive rows pinned to
+        the trash page); a stateful model's step reads and rewrites
+        its slot rows, active slots only.
 
         Mesh flavor: the SAME program jitted under the decode group's
         mesh with explicit in/out shardings — params by the family's
@@ -3004,18 +2856,15 @@ class ServingEngine:
         if self._decode_jit is not None:
             return self._decode_jit
         ad = self.adapter
-        jit_kw = {}
-        if self.meshctx is not None:
-            psh, bsh, R, kv, sc = self._prog_shardings()
-            if self.paged:
+
+        if self.paged:
+            jit_kw = {}
+            if self.meshctx is not None:
+                psh, bsh, R, kv, sc = self._prog_shardings()
                 jit_kw = dict(
                     in_shardings=(psh, bsh, R, R, R, R, kv, kv, sc, sc),
                     out_shardings=(R, kv, kv, sc, sc))
-            else:
-                jit_kw = dict(in_shardings=(psh, bsh, R, R, R, kv, kv),
-                              out_shardings=(R, kv, kv))
 
-        if self.paged:
             def ptpu_decode(params, buffers, toks, pos, active, tables, ks,
                      vs, kss, vss):
                 self._count_trace("decode")
@@ -3035,24 +2884,10 @@ class ServingEngine:
                 **jit_kw)
             return self._decode_jit
 
-        masked = self.prefill_chunk is not None
-
         def ptpu_decode(params, buffers, toks, pos, active, ks, vs):
             self._count_trace("decode")
             pos_eff = jnp.where(active, pos, 0).astype(jnp.int32)
-            if masked:
-                # chunked engines write-mask INACTIVE lanes: the plain
-                # flavor writes every lane's k/v at position 0, which
-                # was harmless while every admission rewrote the whole
-                # row — but a PREFILLING slot's row must survive the
-                # decode steps interleaved between its chunks. Active
-                # lanes' writes/attends are bitwise unchanged (the
-                # wlen scatter lands the same k/v at the same
-                # positions), so greedy outputs stay identical.
-                wl = jnp.where(active, 1, 0).astype(jnp.int32)
-                caches = [(k, v, pos_eff, wl) for k, v in zip(ks, vs)]
-            else:
-                caches = ad.decode_caches((ks, vs), pos_eff, active)
+            caches = ad.decode_caches((ks, vs), pos_eff, active)
             with ad.model.bind_state(params, buffers):
                 h, new_caches = ad.call(Tensor(toks), caches)
                 logits = ad.head(h[:, -1:])._data[:, -1]
@@ -3063,7 +2898,7 @@ class ServingEngine:
             return logits, ks2, vs2
 
         self._decode_jit = self._jit(
-            ptpu_decode, donate_argnums=self._donate(), **jit_kw)
+            ptpu_decode, donate_argnums=self._donate())
         return self._decode_jit
 
     def _verify_fn(self):
@@ -3090,15 +2925,9 @@ class ServingEngine:
         jit_kw = {}
         if self.meshctx is not None:
             psh, bsh, R, kv, sc = self._prog_shardings()
-            if self.paged:
-                jit_kw = dict(
-                    in_shardings=(psh, bsh, R, R, R, R, R,
-                                  kv, kv, sc, sc),
-                    out_shardings=(R, R, R, kv, kv, sc, sc))
-            else:
-                jit_kw = dict(
-                    in_shardings=(psh, bsh, R, R, R, R, kv, kv),
-                    out_shardings=(R, R, R, kv, kv))
+            jit_kw = dict(
+                in_shardings=(psh, bsh, R, R, R, R, R, kv, kv, sc, sc),
+                out_shardings=(R, R, R, kv, kv, sc, sc))
 
         def accept(toks, logits, wl_eff, active):
             K = toks.shape[1]
@@ -3118,51 +2947,29 @@ class ServingEngine:
             acc = jnp.where(active, acc, 0).astype(jnp.int32)
             return g, acc
 
-        if self.paged:
-            def ptpu_verify(params, buffers, toks, pos, active, wlen, tables,
-                     ks, vs, kss, vss):
-                self._count_trace("verify")
-                pos_eff = jnp.where(active, pos, 0).astype(jnp.int32)
-                wl_eff = jnp.where(active, wlen, 0).astype(jnp.int32)
-                tab_eff = jnp.where(active[:, None], tables, 0)
-                caches = self._paged_caches(ks, vs, kss, vss,
-                                            tab_eff, pos_eff,
-                                            wlen=wl_eff)
-                with ad.model.bind_state(params, buffers):
-                    h, new_caches = ad.call(Tensor(toks), caches)
-                    logits = ad.head(h)._data        # [B, K, vocab]
-                logits = jnp.where(active[:, None, None], logits, 0.0)
-                g, acc = accept(toks, logits, wl_eff, active)
-                return (logits, g, acc) \
-                    + self._unpack_paged(new_caches)
-
-            self._verify_jit = self._jit(
-                ptpu_verify, donate_argnums=self._donate_idx(7, 8, 9, 10),
-                **jit_kw)
-            return self._verify_jit
-
-        def ptpu_verify(params, buffers, toks, pos, active, wlen, ks, vs):
+        def ptpu_verify(params, buffers, toks, pos, active, wlen, tables,
+                 ks, vs, kss, vss):
             self._count_trace("verify")
             pos_eff = jnp.where(active, pos, 0).astype(jnp.int32)
             wl_eff = jnp.where(active, wlen, 0).astype(jnp.int32)
-            caches = [(k, v, pos_eff, wl_eff)
-                      for k, v in zip(ks, vs)]
+            tab_eff = jnp.where(active[:, None], tables, 0)
+            caches = self._paged_caches(ks, vs, kss, vss,
+                                        tab_eff, pos_eff, wlen=wl_eff)
             with ad.model.bind_state(params, buffers):
                 h, new_caches = ad.call(Tensor(toks), caches)
                 logits = ad.head(h)._data            # [B, K, vocab]
             logits = jnp.where(active[:, None, None], logits, 0.0)
             g, acc = accept(toks, logits, wl_eff, active)
-            ks2 = [getattr(c[0], "_data", c[0]) for c in new_caches]
-            vs2 = [getattr(c[1], "_data", c[1]) for c in new_caches]
-            return logits, g, acc, ks2, vs2
+            return (logits, g, acc) + self._unpack_paged(new_caches)
 
         self._verify_jit = self._jit(
-            ptpu_verify, donate_argnums=self._donate_idx(6, 7), **jit_kw)
+            ptpu_verify, donate_argnums=self._donate_idx(7, 8, 9, 10),
+            **jit_kw)
         return self._verify_jit
 
     @staticmethod
     def _donate():
-        """Donation enable flag + the contiguous programs' pool
+        """Donation enable flag + the slot-row (state) programs' pool
         argument indices (args 5/6): non-empty means the jit update is
         in-place on device. CPU ignores donation and warns, so skip
         it there. Paged programs derive their own indices from this

@@ -117,8 +117,8 @@ class StateCacheUnsupported(ServingError):
     def __init__(self, option: str, value=None):
         super().__init__(
             f"{option}={value!r} needs K and V by position; this model "
-            f"keeps a fixed-size recurrent state a slot "
-            f"(kv_layout='state'), on which it is not supported yet")
+            f"keeps a fixed-size recurrent state a slot, on which it "
+            f"is not supported yet")
         self.option = option
 
 

@@ -6,8 +6,8 @@ a raw ``jax.sharding.Mesh``) — into the concrete shardings every
 engine program is jitted under:
 
 - **KV pools** shard over the ``model`` axis on their ``kv_heads``
-  dimension (contiguous ``[slots, Tmax, KV, D]`` and paged
-  ``[pages, page, KV, D]`` pools alike; int8 per-page scales
+  dimension (the ``[pages, page, KV, D]`` pools, and the prefill
+  group's ``[1, Tmax, KV, D]`` chunk buffers; int8 per-page scales
   ``[pages, page, KV]`` follow on their last axis), so each chip holds
   ``1/tp`` of the KV bytes — the serving memory bottleneck.
 - **Model params** shard over the same axis via the model family's
@@ -140,7 +140,7 @@ class MeshContext:
         return NamedSharding(self._mesh(group), PartitionSpec())
 
     def kv_sharding(self, group: str = "decode") -> NamedSharding:
-        """Pool sharding, both layouts: [.., .., KV, D] over kv_heads."""
+        """Pool and chunk-buffer sharding: [.., .., KV, D] over kv_heads."""
         return NamedSharding(self._mesh(group),
                              PartitionSpec(None, None, self.AXIS, None))
 

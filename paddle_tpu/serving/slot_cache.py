@@ -1,17 +1,15 @@
-"""Cache pools for the serving engine: the contiguous KV slot pool, its
-block-paged successor, and the recurrent-state pool of models that keep
-no K/V (``SlotStateCache``, at the end of the module docstring).
+"""Cache pools for the serving engine: the block-paged K/V pool, and the
+recurrent-state pool of models that keep no K/V (``SlotStateCache``,
+at the end of the module docstring).
 
-``SlotKVCache`` is the original fixed ``(max_slots, max_len)`` pool:
-one full row reserved per slot, so concurrency is capped by the
-worst-case request length. ``PagedKVCache`` replaces the row with a
-pool of fixed-size PAGES (``[num_pages, page_size, kv_heads,
-head_dim]`` per layer) and a static per-slot page table
-(``[max_slots, pages_per_slot]`` int32 — the ONE compiled decode
-program gathers through it, see models/_decode_cache.paged_cache_attend),
-so a request only holds pages covering the tokens it has actually
-written and the pool oversubscribes: many more concurrent requests fit
-the same KV bytes.
+``PagedKVCache`` holds K and V in a pool of fixed-size PAGES
+(``[num_pages, page_size, kv_heads, head_dim]`` per layer) behind a
+static per-slot page table (``[max_slots, pages_per_slot]`` int32 — the
+ONE compiled decode program reads through it, see
+models/_decode_cache.paged_cache_attend), so a request only holds pages
+covering the tokens it has actually written and the pool
+oversubscribes: concurrency is not capped by the worst-case request
+length.
 
 On top of paging it adds:
 
@@ -58,7 +56,7 @@ from typing import Dict, List, Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["SlotKVCache", "SlotStateCache", "PagedKVCache"]
+__all__ = ["SlotStateCache", "PagedKVCache"]
 
 
 def _validate_geometry(num_layers: int, max_slots: int, max_len: int,
@@ -76,7 +74,7 @@ def _validate_geometry(num_layers: int, max_slots: int, max_len: int,
 
 
 class _SlotTable:
-    """Slot lease bookkeeping shared by both pool flavors: incremental
+    """Slot lease bookkeeping shared by both pools: incremental
     free/active sets instead of per-call O(max_slots) scans."""
 
     def __init__(self, max_slots: int):
@@ -109,15 +107,6 @@ class _SlotTable:
     def occupancy(self) -> float:
         return len(self._active) / self.max_slots
 
-    def kv_bytes(self) -> int:
-        """Total device bytes of the KV pools (+scales when paged) —
-        ONE accounting used by the kv_bytes gauge and the benchmark's
-        byte-budget comparison."""
-        pools = list(self.ks) + list(self.vs) \
-            + list(getattr(self, "kss", [])) \
-            + list(getattr(self, "vss", []))
-        return sum(p.size * p.dtype.itemsize for p in pools)
-
 
 def _place_pools(pools, sharding):
     """Commit freshly allocated pool buffers to a device sharding (the
@@ -127,37 +116,6 @@ def _place_pools(pools, sharding):
         return pools
     import jax
     return [jax.device_put(p, sharding) for p in pools]
-
-
-class SlotKVCache(_SlotTable):
-    """Per-layer [max_slots, max_len, kv_heads, head_dim] k/v buffers
-    plus the slot lease table (the contiguous pool). ``kv_sharding``
-    commits the pools to a tensor-parallel mesh (split on kv_heads)."""
-
-    def __init__(self, num_layers: int, max_slots: int, max_len: int,
-                 kv_heads: int, head_dim: int, dtype,
-                 kv_sharding=None):
-        _validate_geometry(num_layers, max_slots, max_len, kv_heads,
-                           head_dim)
-        super().__init__(max_slots)
-        self.max_len = max_len
-        shape = (max_slots, max_len, kv_heads, head_dim)
-        self.ks = _place_pools(
-            [jnp.zeros(shape, dtype) for _ in range(num_layers)],
-            kv_sharding)
-        self.vs = _place_pools(
-            [jnp.zeros(shape, dtype) for _ in range(num_layers)],
-            kv_sharding)
-
-    @property
-    def pools(self):
-        """The per-layer device arrays a slot-row program reads and
-        returns, in argument order."""
-        return self.ks, self.vs
-
-    @pools.setter
-    def pools(self, new) -> None:
-        self.ks, self.vs = (list(p) for p in new)
 
 
 class SlotStateCache(_SlotTable):
@@ -182,6 +140,8 @@ class SlotStateCache(_SlotTable):
 
     @property
     def pools(self):
+        """The per-layer device arrays a slot-row program reads and
+        returns, in argument order."""
         return self._pools
 
     @pools.setter
@@ -254,8 +214,8 @@ class PagedKVCache(_SlotTable):
         self.page_size = page_size
         self.pages_per_slot = max_len // page_size
         if num_pages is None:
-            # capacity parity with the contiguous pool by default;
-            # benchmarks pass a smaller pool to oversubscribe
+            # a full-length row a slot by default; benchmarks pass a
+            # smaller pool to oversubscribe
             num_pages = max_slots * self.pages_per_slot + 1
         if num_pages < self.pages_per_slot + 1:
             raise ValueError(
@@ -1032,6 +992,13 @@ class PagedKVCache(_SlotTable):
             self.prefix_lookup_tokens -= plan["lookup_counted"]
 
     # -- introspection --------------------------------------------------
+    def kv_bytes(self) -> int:
+        """Total device bytes of the K/V pools and their scales — ONE
+        accounting used by the kv_bytes gauge and the benchmark's
+        byte-budget comparison."""
+        return sum(p.size * p.dtype.itemsize
+                   for p in self.ks + self.vs + self.kss + self.vss)
+
     def stats(self) -> Dict[str, float]:
         return {
             "num_pages": self.num_pages - 1,     # usable (sans trash)

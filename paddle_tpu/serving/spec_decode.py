@@ -138,8 +138,9 @@ class DraftModelProposer:
     """Small-draft-model proposer: a tiny GPT-family causal LM drafts
     the next ``max_draft`` tokens autoregressively, sharing the
     serving stack's cache/program machinery — ONE compiled draft
-    program (a window-``W`` write-masked forward, the contiguous
-    verify program's shape) over a slot-mirrored per-layer
+    program (a window-``W`` write-masked forward over fixed buffers,
+    the 4-tuple cache of ``models/_decode_cache``) over a
+    slot-mirrored per-layer
     ``[max_slots, max_len, H, D]`` KV pool. The engine admits, evicts
     and recovers proposer state in lockstep with its own slots
     (release/retain below), so the no-leak law that audits the n-gram
@@ -244,8 +245,9 @@ class DraftModelProposer:
 
     def _draft_fn(self):
         """THE draft program (compiled once): a [max_slots, window]
-        write-masked forward at per-slot positions — the contiguous
-        verify program's body without the acceptance rule. wlen=1
+        write-masked forward at per-slot positions — the engine's
+        verify program over the proposer's own row-a-slot buffers,
+        without the acceptance rule. wlen=1
         calls chain draft tokens; wlen=w calls batch catch-up
         ingestion of confirmed history. Same program either way —
         compile count 1, trace-count asserted."""

@@ -95,6 +95,32 @@ def serving_model_mesh(tp: int = 2, prefill: int = 0):
     return ProcessMesh(_np.arange(tp + prefill), ["model"])
 
 
+def model_greedy(model, prompt, max_new: int):
+    """The model's own greedy decode of one prompt, the serving tests'
+    reference that is independent of the engine: the public
+    ``generate()`` where the family has one (llama), else (GPT) a
+    cache-free loop that re-runs the FULL sequence every step and
+    takes the argmax at its last position (the sequence sits in one
+    buffer of the model's position range, so every step is one shape:
+    what lies behind a position cannot reach it through a causal
+    mask)."""
+    import numpy as _np
+
+    import paddle_tpu as paddle
+    prompt = _np.asarray(prompt, _np.int64)
+    n = len(prompt)
+    if hasattr(model, "generate"):
+        return model.generate(
+            paddle.to_tensor(prompt[None]),
+            max_new_tokens=max_new).numpy()[0, n:].tolist()
+    ids = _np.zeros((1, model.cache_spec().max_positions), _np.int64)
+    ids[0, :n] = prompt
+    for i in range(n, n + max_new):
+        logits = model(paddle.to_tensor(ids)).numpy()
+        ids[0, i] = int(_np.argmax(logits[0, i - 1]))
+    return ids[0, n:n + max_new].tolist()
+
+
 @pytest.fixture(scope="module")
 def worker_compile_cache(tmp_path_factory):
     """serving/worker.py turns the compile cache on: modules that spawn

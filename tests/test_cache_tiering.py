@@ -64,7 +64,7 @@ def test_tier_knob_validation():
     with pytest.raises(ValueError, match="host_tier_pages"):
         ServingEngine(model, max_slots=2, max_len=64, min_bucket=8,
                       page_size=8, host_tier_pages=4)
-    with pytest.raises(ValueError, match="paged"):
+    with pytest.raises(ValueError, match="contiguous slot pool is gone"):
         ServingEngine(model, max_slots=2, max_len=64, min_bucket=8,
                       kv_layout="contiguous", kv_host_tier=True)
     with pytest.raises(ValueError, match="prefix_sharing"):

@@ -229,7 +229,7 @@ def test_engine_with_the_kernel_is_token_identical(monkeypatch, family):
             monkeypatch.setattr(pallas_ops, "single_device_tpu",
                                 lambda: True)
         eng = ServingEngine(model, max_slots=3, max_len=64, min_bucket=8,
-                            kv_layout="paged", page_size=8)
+                            page_size=8)
         reqs = [eng.submit(p, max_new_tokens=12) for p in prompts]
         eng.run()
         outs.append([r.output_ids for r in reqs])
@@ -248,7 +248,7 @@ def test_engine_programs_write_without_a_pass_over_the_pool():
     attention they take, and no pool-sized select is left in them."""
     from paddle_tpu.serving import ServingEngine
     eng = ServingEngine(_tiny("llama"), max_slots=3, max_len=64,
-                        min_bucket=8, kv_layout="paged", page_size=8)
+                        min_bucket=8, page_size=8)
     seen = {}
 
     def spy(kind, prog):

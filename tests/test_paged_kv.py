@@ -1,5 +1,5 @@
 """Paged KV cache (paddle_tpu/serving/slot_cache.PagedKVCache +
-engine paged path): token identity paged-vs-contiguous over ragged
+engine paged path): token identity with generate() over ragged
 request mixes, copy-on-write prefix sharing (page-boundary and
 mid-page divergence), refcount conservation across eviction, deadline
 cancel and drain, int8-KV measured-parity gate, page-gated admission
@@ -9,9 +9,11 @@ import numpy as np
 import pytest
 
 import paddle_tpu as paddle
+from conftest import model_greedy
 from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny_config
 from paddle_tpu.resilience.invariants import page_leak_violations
-from paddle_tpu.serving import PagedKVCache, ServingEngine, SlotKVCache
+from paddle_tpu.serving import (PagedKVCache, ServingEngine,
+                                SlotStateCache)
 
 
 def _tiny_llama(**kw):
@@ -57,10 +59,6 @@ def test_cache_geometry_validation():
                   head_dim=4)
         kw.update(bad)
         with pytest.raises(ValueError):
-            SlotKVCache(kw["num_layers"], kw["max_slots"],
-                        kw["max_len"], kw["kv_heads"], kw["head_dim"],
-                        jnp.float32)
-        with pytest.raises(ValueError):       # paged inherits checks
             PagedKVCache(kw["num_layers"], kw["max_slots"],
                          kw["max_len"], kw["kv_heads"],
                          kw["head_dim"], jnp.float32, page_size=8)
@@ -71,6 +69,10 @@ def test_cache_geometry_validation():
     with pytest.raises(ValueError, match="num_pages"):
         PagedKVCache(1, 2, 16, 2, 4, jnp.float32, page_size=8,
                      num_pages=2)
+    state = (("S", (2, 4), jnp.float32),)
+    for bad in [(0, 2, state), (2, 0, state), (2, 2, ())]:
+        with pytest.raises(ValueError):
+            SlotStateCache(*bad)
 
 
 def test_slot_bookkeeping_is_maintained_not_scanned():
@@ -78,7 +80,7 @@ def test_slot_bookkeeping_is_maintained_not_scanned():
     arbitrary assign/release interleaving, and release returns slots
     in O(1) (no O(max_slots) list scans on the per-step path)."""
     import jax.numpy as jnp
-    c = SlotKVCache(1, 5, 16, 2, 4, jnp.float32)
+    c = SlotStateCache(1, 5, (("S", (2, 4), jnp.float32),))
     rng = np.random.RandomState(0)
     held = set()
     for _ in range(200):
@@ -133,29 +135,24 @@ def test_page_span_and_reservation_accounting():
     assert (c.page_table[0] == 0).all()
 
 
-# -- token identity paged vs contiguous --------------------------------
+# -- token identity of the paged path with generate() ------------------
 
-def test_paged_matches_contiguous_ragged_llama():
-    """Acceptance bar: greedy outputs on the bf16/f32 non-shared paged
-    path are token-identical to the contiguous slot pool (and thus to
-    generate()) over a ragged mix, for MHA and GQA."""
+def test_paged_matches_generate_ragged_llama():
+    """Acceptance bar: greedy outputs on the f32 paged path are
+    token-identical to generate() over a ragged mix, for MHA and GQA,
+    with and without the prefix index."""
     for kv_kw in ({}, {"num_key_value_heads": 1}):
         model = _tiny_llama(**kv_kw)
         rng = np.random.RandomState(1)
         prompts = _prompts(rng, [3, 9, 5, 12, 7, 17])
-        outs = []
-        for layout in ("contiguous", "paged"):
-            kw = {} if layout == "contiguous" else {"page_size": 8}
+        ref = [model_greedy(model, p, 6) for p in prompts]
+        for share in (True, False):
             eng = ServingEngine(model, max_slots=2, max_len=64,
-                                min_bucket=4, kv_layout=layout, **kw)
+                                min_bucket=4, page_size=8,
+                                prefix_sharing=share)
             reqs = [eng.submit(p, max_new_tokens=6) for p in prompts]
             eng.run()
-            outs.append([r.output_ids for r in reqs])
-        assert outs[0] == outs[1]
-        ref = model.generate(
-            paddle.to_tensor(prompts[1][None]),
-            max_new_tokens=6).numpy()[0, len(prompts[1]):]
-        np.testing.assert_array_equal(ref, outs[1][1])
+            assert [r.output_ids for r in reqs] == ref, (kv_kw, share)
 
 
 def test_paged_serves_gpt_family():
@@ -167,15 +164,12 @@ def test_paged_serves_gpt_family():
     model.eval()
     rng = np.random.RandomState(2)
     prompts = _prompts(rng, [4, 7, 11])
-    outs = []
-    for layout in ("contiguous", "paged"):
-        kw = {} if layout == "contiguous" else {"page_size": 8}
-        eng = ServingEngine(model, max_slots=2, max_len=64,
-                            min_bucket=8, kv_layout=layout, **kw)
-        reqs = [eng.submit(p, max_new_tokens=5) for p in prompts]
-        eng.run()
-        outs.append([r.output_ids for r in reqs])
-    assert outs[0] == outs[1]
+    eng = ServingEngine(model, max_slots=2, max_len=64, min_bucket=8,
+                        page_size=8)
+    reqs = [eng.submit(p, max_new_tokens=5) for p in prompts]
+    eng.run()
+    assert [r.output_ids for r in reqs] == [
+        model_greedy(model, p, 5) for p in prompts]
 
 
 # -- copy-on-write prefix sharing --------------------------------------
@@ -472,11 +466,8 @@ def test_admission_gated_by_free_pages_not_slots():
         peak = max(peak, len(eng.cache.active_slots()))
     assert peak <= 2                    # page-bounded, not slot-bounded
     assert all(r.finish_reason == "length" for r in reqs)
-    ref = ServingEngine(model, max_slots=6, max_len=32, min_bucket=8,
-                        kv_layout="contiguous")
-    rr = [ref.submit(p, max_new_tokens=6) for p in prompts]
-    ref.run()
-    assert [r.output_ids for r in reqs] == [r.output_ids for r in rr]
+    assert [r.output_ids for r in reqs] == [
+        model_greedy(model, p, 6) for p in prompts]
     _quiesced_ok(eng)
 
 

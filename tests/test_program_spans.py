@@ -60,7 +60,7 @@ def _tiny_llama():
 def _paged_engine(**kw):
     from paddle_tpu.serving import ServingEngine
     return ServingEngine(_tiny_llama(), max_slots=4, max_len=64,
-                         kv_layout="paged", page_size=8,
+                         page_size=8,
                          registry=MetricRegistry(),
                          **kw)
 
@@ -193,6 +193,29 @@ def test_ring_overflow_bumps_dropped_total_and_the_query_says_so():
     assert buf.dropped_total == 2 and got["dropped_total"] == 2
     assert [r["attrs"]["i"] for r in got["spans"]] == [2, 3, 4]
     assert got["recorded_total"] == 5
+
+
+def test_default_ring_keeps_setup_through_a_window_of_short_steps(ring):
+    """A ``compile.*`` span of set-up is still in the default-sized
+    ring after 3,500 engine steps' worth of spans (a 50 s window at a
+    14 ms step, 13 spans a step): the benchmark's reader looks for it
+    only when the window has closed."""
+    tracing._record_span("compile.decode", tracing._now(), key=None)
+    family = ("serving.admit", "serving.decode.build",
+              "serving.decode.enqueue", "serving.decode.fetch",
+              "serving.sample", "serving.publish", "frontdoor.deliver",
+              "frontdoor.pump", "router.step", "serving.publish",
+              "serving.prefill.fetch")
+    for i in range(3500):
+        with span("serving.step", step=i):
+            with span("serving.decode"):
+                for name in family:
+                    with span(name):
+                        pass
+    got = tracing.query("compile.*")
+    assert [r["name"] for r in got["spans"]] == ["compile.decode"]
+    assert got["dropped_total"] == 0
+    assert got["recorded_total"] == 1 + 3500 * 13 <= ring.capacity // 2
 
 
 def test_default_ring_is_installed_on_perf_counter():

@@ -4,8 +4,8 @@ token.
 
 Acceptance band: the ``prefill_chunk`` engine is greedy
 TOKEN-IDENTICAL to the unchunked engine and to ``generate()`` across
-a >= 25-seed property band — llama (GQA) and GPT, contiguous and
-paged layouts including COW-shared prefixes, chunk sizes including
+a >= 25-seed property band — llama (GQA) and GPT, COW-shared
+prefixes included, chunk sizes including
 the chunk >= prompt degenerate case — with the compile contract
 intact: ONE decode program, chunk programs bounded by the prefill
 bucket set. Mid-prefill terminal paths (cancel / deadline /
@@ -19,7 +19,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as paddle
-from conftest import serving_model_mesh
+from conftest import model_greedy, serving_model_mesh
 from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny_config
 from paddle_tpu.resilience import faults
 from paddle_tpu.resilience.invariants import (engine_leak_violations,
@@ -88,12 +88,8 @@ def _drive(eng, prompts, max_new=6):
     return [list(r.out_tokens) for r in reqs]
 
 
-def _engine(family, layout, **kw):
-    eng_kw = dict(max_slots=3, max_len=64, min_bucket=8)
-    if layout == "paged":
-        eng_kw["page_size"] = 8
-    else:
-        eng_kw["kv_layout"] = "contiguous"
+def _engine(family, **kw):
+    eng_kw = dict(max_slots=3, max_len=64, min_bucket=8, page_size=8)
     eng_kw.update(kw)
     return ServingEngine(_model(family), **eng_kw)
 
@@ -102,38 +98,35 @@ def _engine(family, layout, **kw):
 # the >= 25-seed identity band (acceptance criterion)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("family,layout", [
-    ("llama", "contiguous"), ("llama", "paged"),
-    ("gpt", "contiguous"), ("gpt", "paged"),
-])
-def test_chunked_identity_band_25_seeds(family, layout):
+@pytest.mark.parametrize("family", ["llama", "gpt"],
+                         ids=["llama-paged", "gpt-paged"])
+def test_chunked_identity_band_25_seeds(family):
     """Chunked greedy outputs == unchunked engine outputs, bitwise,
-    for 25 seeded traffic waves per (family, layout) — paged waves
-    share a prompt prefix so COW/prefix-index admissions chunk too.
+    for 25 seeded traffic waves per family — the waves share a
+    prompt prefix so COW/prefix-index admissions chunk too.
     ONE engine per chunk size serves the whole band, so it also
     proves the compile contract: one decode program and chunk
     programs bounded by the prefill bucket set across all waves.
     chunk=64 == max_len is the degenerate case: every prompt fits one
     chunk and the engine must behave exactly like the unchunked one."""
     shared = np.arange(1, 11, dtype=np.int64)  # > 1 page of 8
-    ref_eng = _engine(family, layout)
-    chunk_engines = {c: _engine(family, layout, prefill_chunk=c)
+    ref_eng = _engine(family)
+    chunk_engines = {c: _engine(family, prefill_chunk=c)
                      for c in (8, 16, 64)}
     for seed in range(25):
         rng = np.random.RandomState(1400 + seed)
-        prompts = _wave(rng, shared=shared
-                        if layout == "paged" else None)
+        prompts = _wave(rng, shared=shared)
         ref = _drive(ref_eng, prompts)
         sizes = (8, 16, 64) if seed % 5 == 0 \
             else ((8, 16, 64)[seed % 3],)
         for c in sizes:
             got = _drive(chunk_engines[c], prompts)
-            assert got == ref, (family, layout, seed, c)
+            assert got == ref, (family, seed, c)
     budget = set(prefill_buckets(8, 64))
     for c, eng in chunk_engines.items():
-        assert eng.trace_counts["decode"] == 1, (family, layout, c)
+        assert eng.trace_counts["decode"] == 1, (family, c)
         assert set(eng.trace_counts["chunk"]) <= budget, \
-            (family, layout, c, eng.trace_counts["chunk"])
+            (family, c, eng.trace_counts["chunk"])
     assert ref_eng.trace_counts["decode"] == 1
     # the degenerate engine (chunk >= every prompt) prefills each
     # prompt as ONE whole-prompt chunk: its compiled chunk shapes are
@@ -143,40 +136,17 @@ def test_chunked_identity_band_25_seeds(family, layout):
         <= set(ref_eng.trace_counts["prefill"])
 
 
-def _greedy_full_forward(model, prompt, max_new):
-    """Cache-free greedy reference: re-run the FULL sequence every
-    step and argmax the last position (works for any family)."""
-    ids = list(prompt)
-    out = []
-    for _ in range(max_new):
-        logits = model(paddle.to_tensor(
-            np.asarray(ids, np.int64)[None])).numpy()
-        out.append(int(np.argmax(logits[0, -1])))
-        ids.append(out[-1])
-    return out
-
-
 @pytest.mark.parametrize("family", ["llama", "gpt"])
 def test_chunked_matches_generate(family):
     """Transitive anchor: chunked engine == the model's own greedy
     decode directly (not just == the unchunked engine). llama pins
     against its public generate(); GPT (no generate()) against a
     cache-free full-forward greedy loop."""
-    model = _model(family)
     rng = np.random.RandomState(3)
     prompts = [rng.randint(1, 100, (L,)).astype(np.int64)
                for L in (5, 23, 37)]
-    eng = _engine(family, "paged", prefill_chunk=8)
-    reqs = [eng.submit(p, 6) for p in prompts]
-    while eng.has_work():
-        eng.step()
-    for p, req in zip(prompts, reqs):
-        if family == "llama":
-            ref = model.generate(paddle.to_tensor(p[None]),
-                                 max_new_tokens=6).numpy()[0, len(p):]
-        else:
-            ref = _greedy_full_forward(model, p, 6)
-        np.testing.assert_array_equal(ref, np.asarray(req.output_ids))
+    got = _drive(_engine(family, prefill_chunk=8), prompts)
+    assert got == [model_greedy(_model(family), p, 6) for p in prompts]
 
 
 def test_chunk_trace_counts_pinned():
@@ -184,12 +154,11 @@ def test_chunk_trace_counts_pinned():
     chunk=8 produce 8-token chunks only (finals are 4 and 3 tokens,
     bucketed back to 8) — ONE chunk program, one decode program, and
     no monolithic prefill at all."""
-    eng = _engine("llama", "contiguous", prefill_chunk=8)
+    eng = _engine("llama", prefill_chunk=8)
     rng = np.random.RandomState(5)
     prompts = [rng.randint(1, 100, (L,)).astype(np.int64)
                for L in (20, 35)]
-    assert _drive(eng, prompts) == _drive(
-        _engine("llama", "contiguous"), prompts)
+    assert _drive(eng, prompts) == _drive(_engine("llama"), prompts)
     assert eng.trace_counts["chunk"] == {8: 1}
     assert eng.trace_counts["decode"] == 1
     assert eng.trace_counts["prefill"] == {}
@@ -200,7 +169,7 @@ def test_chunk_budget_caps_tokens_per_step():
     in flight, a step admits no monolithic prefill past the budget
     and advances at most ONE chunk — so no step ever carries more
     than ``chunk + max_slots`` tokens of work."""
-    eng = _engine("llama", "paged", max_slots=3, prefill_chunk=8)
+    eng = _engine("llama", max_slots=3, prefill_chunk=8)
     rng = np.random.RandomState(9)
     long1 = rng.randint(1, 100, (30,)).astype(np.int64)
     long2 = rng.randint(1, 100, (25,)).astype(np.int64)
@@ -237,7 +206,7 @@ def _start_chunked(eng, prompt, max_new=4, **submit_kw):
 
 
 def test_mid_chunk_cancel_frees_slot_and_pages():
-    eng = _engine("llama", "paged", prefill_chunk=8)
+    eng = _engine("llama", prefill_chunk=8)
     rng = np.random.RandomState(11)
     req = _start_chunked(eng, rng.randint(1, 100, (40,)).astype(np.int64))
     assert eng.cancel(req)
@@ -251,7 +220,7 @@ def test_mid_chunk_cancel_frees_slot_and_pages():
 
 def test_mid_chunk_deadline_frees_slot_and_pages():
     clock = {"t": 0.0}
-    eng = _engine("llama", "paged", prefill_chunk=8,
+    eng = _engine("llama", prefill_chunk=8,
                   time_fn=lambda: clock["t"])
     rng = np.random.RandomState(12)
     req = _start_chunked(eng, rng.randint(1, 100, (40,)).astype(np.int64),
@@ -266,7 +235,7 @@ def test_mid_chunk_deadline_frees_slot_and_pages():
 
 
 def test_mid_chunk_disconnect_frees_slot_and_pages():
-    eng = _engine("llama", "paged", prefill_chunk=8)
+    eng = _engine("llama", prefill_chunk=8)
     rng = np.random.RandomState(13)
     req = _start_chunked(eng, rng.randint(1, 100, (40,)).astype(np.int64))
     req.cancel_requested = True          # client went away
@@ -277,17 +246,16 @@ def test_mid_chunk_disconnect_frees_slot_and_pages():
     assert not page_leak_violations(eng)
 
 
-@pytest.mark.parametrize("layout", ["contiguous", "paged"])
-def test_chunk_fault_unwinds_requeues_and_replays_identically(layout):
+def test_chunk_fault_unwinds_requeues_and_replays_identically():
     """An injected ``serving.prefill.chunk`` fault between chunks
     unwinds the PREFILLING request (slot + pages freed), requeues it,
     and the re-chunked replay emits EXACTLY the unfaulted tokens."""
     rng = np.random.RandomState(21)
     prompts = [rng.randint(1, 100, (L,)).astype(np.int64)
                for L in (35, 20)]
-    ref = _drive(_engine("llama", layout, prefill_chunk=8), prompts)
+    ref = _drive(_engine("llama", prefill_chunk=8), prompts)
 
-    eng = _engine("llama", layout, prefill_chunk=8)
+    eng = _engine("llama", prefill_chunk=8)
     reqs = [eng.submit(p, 6) for p in prompts]
     faults.inject("serving.prefill.chunk", times=1, after=2)
     fired = 0
@@ -306,10 +274,9 @@ def test_chunk_fault_unwinds_requeues_and_replays_identically(layout):
             assert not (pending & fifo_rids)
             assert not eng._broken
     assert fired == 1
-    assert [list(r.out_tokens) for r in reqs] == ref, layout
+    assert [list(r.out_tokens) for r in reqs] == ref
     assert not engine_leak_violations(eng)
-    if layout == "paged":
-        assert not page_leak_violations(eng)
+    assert not page_leak_violations(eng)
 
 
 def test_chunked_recover_replays_token_identically():
@@ -319,9 +286,9 @@ def test_chunked_recover_replays_token_identically():
     rng = np.random.RandomState(23)
     prompts = [rng.randint(1, 100, (L,)).astype(np.int64)
                for L in (30, 12)]
-    ref = _drive(_engine("llama", "paged", prefill_chunk=8), prompts)
+    ref = _drive(_engine("llama", prefill_chunk=8), prompts)
 
-    eng = _engine("llama", "paged", prefill_chunk=8)
+    eng = _engine("llama", prefill_chunk=8)
     reqs = [eng.submit(p, 6) for p in prompts]
     eng.step()
     assert eng._chunk_fifo          # someone is mid-prefill
@@ -348,8 +315,8 @@ def test_chunked_composes_with_speculative():
     pat = rng.randint(1, 100, (3,)).astype(np.int64)
     prompts = [np.tile(pat, 12)[:30].astype(np.int64),
                rng.randint(1, 100, (20,)).astype(np.int64)]
-    ref = _drive(_engine("llama", "paged"), prompts, max_new=10)
-    eng = _engine("llama", "paged", prefill_chunk=8,
+    ref = _drive(_engine("llama"), prompts, max_new=10)
+    eng = _engine("llama", prefill_chunk=8,
                   speculative=True, spec_k=4)
     got = _drive(eng, prompts, max_new=10)
     assert got == ref
@@ -357,20 +324,22 @@ def test_chunked_composes_with_speculative():
     assert set(eng.trace_counts["chunk"]) <= set(prefill_buckets(8, 64))
 
 
-@pytest.mark.parametrize("layout", ["contiguous", "paged"])
-def test_chunked_disaggregated_identity(layout):
+@pytest.mark.parametrize("family", ["llama", "gpt"])
+def test_chunked_disaggregated_identity(family):
     """Disaggregated mesh engines chunk on the PREFILL group (local
     per-layer buffers, final-span handoff to the decode pool) and
-    stay token-identical to the single-chip unchunked engine."""
+    stay token-identical to the single-chip unchunked engine and to
+    the model's own greedy decode."""
     mesh = serving_model_mesh(tp=2, prefill=2)
     rng = np.random.RandomState(41)
     prompts = [rng.randint(1, 100, (L,)).astype(np.int64)
                for L in (35, 20, 9)]
-    ref = _drive(_engine("llama", layout), prompts)
-    eng = _engine("llama", layout, mesh=mesh, prefill_devices=2,
+    ref = _drive(_engine(family), prompts)
+    eng = _engine(family, mesh=mesh, prefill_devices=2,
                   prefill_chunk=8)
     got = _drive(eng, prompts)
-    assert got == ref, layout
+    assert got == ref, family
+    assert got == [model_greedy(_model(family), p, 6) for p in prompts]
     assert eng.trace_counts["decode"] == 1
     assert eng._chunk_local == {}        # every handoff completed
     assert not engine_leak_violations(eng)
@@ -382,11 +351,11 @@ def test_chunked_disaggregated_identity(layout):
 
 def test_prefill_chunk_validation():
     with pytest.raises(ValueError, match="power of 2"):
-        _engine("llama", "paged", prefill_chunk=12)
+        _engine("llama", prefill_chunk=12)
     with pytest.raises(ValueError, match="bucket"):
-        _engine("llama", "paged", prefill_chunk=4)   # < min_bucket
+        _engine("llama", prefill_chunk=4)   # < min_bucket
     with pytest.raises(ValueError, match="admission_lookahead"):
-        _engine("llama", "paged", admission_lookahead=-1)
+        _engine("llama", admission_lookahead=-1)
 
 
 def test_admission_lookahead_relieves_head_of_line():

@@ -7,8 +7,9 @@ import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny_config
-from paddle_tpu.serving import (FIFOScheduler, Request, SamplingParams,
-                                ServingEngine, SlotKVCache, bucket_for,
+from paddle_tpu.serving import (FIFOScheduler, PagedKVCache, Request,
+                                SamplingParams, ServingEngine,
+                                SlotStateCache, bucket_for,
                                 prefill_buckets, sample_token)
 
 
@@ -54,7 +55,8 @@ def test_bucket_policy():
 
 def test_slot_cache_lease_cycle():
     import jax.numpy as jnp
-    c = SlotKVCache(2, 3, 16, 2, 4, jnp.float32)
+    c = SlotStateCache(2, 3, (("S", (2, 4, 4), jnp.float32),
+                              ("z", (2, 4), jnp.float32)))
     assert c.free_slots() == [0, 1, 2] and c.occupancy == 0.0
     c.assign(1, "req")
     assert c.free_slots() == [0, 2] and c.active_slots() == [1]
@@ -64,7 +66,55 @@ def test_slot_cache_lease_cycle():
     with pytest.raises(RuntimeError):
         c.release(1)
     assert c.free_slots() == [0, 1, 2]
-    assert len(c.ks) == 2 and c.ks[0].shape == (3, 16, 2, 4)
+    S, z = c.pools                  # a list a layer, a row a slot
+    assert len(S) == len(z) == 2
+    assert S[0].shape == (3, 2, 4, 4) and z[0].shape == (3, 2, 4)
+
+
+def test_kv_layout_follows_from_the_model():
+    """The layout is no choice: a model that caches K and V is served
+    from pages. The contiguous pool's old value is refused by a
+    message that says so, any other foreign value by what this model's
+    kind gives; None and the matching value build the same engine."""
+    model = _tiny_llama()
+    with pytest.raises(ValueError, match="contiguous slot pool is gone, "
+                                         "pages serve K and V"):
+        ServingEngine(model, max_slots=2, max_len=32,
+                      kv_layout="contiguous")
+    with pytest.raises(ValueError, match="'paged' for LlamaForCausalLM"):
+        ServingEngine(model, max_slots=2, max_len=32, kv_layout="rows")
+    with pytest.raises(ValueError, match="recurrent state"):
+        ServingEngine(model, max_slots=2, max_len=32, kv_layout="state")
+    for layout in (None, "paged"):
+        eng = ServingEngine(model, max_slots=2, max_len=32,
+                            kv_layout=layout)
+        assert eng.paged and eng.page_size == 32
+        assert isinstance(eng.cache, PagedKVCache)
+
+
+@pytest.mark.parametrize("family", ["llama", "gpt"])
+def test_per_row_positions_need_pages_or_a_write_length(family):
+    """The fixed-buffer 3-tuple is ``generate()``'s: one position for
+    the whole batch. A per-row position without ``wlen`` (the tuple of
+    the contiguous pool's decode step) is refused by name, not
+    mis-sliced."""
+    import jax.numpy as jnp
+    if family == "llama":
+        model = _tiny_llama()
+    else:
+        from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
+        paddle.seed(0)
+        model = GPTForCausalLM(GPTConfig(
+            vocab_size=64, hidden_size=32, num_layers=1, num_heads=2,
+            max_seq_len=16, dropout=0.0))
+        model.eval()
+    spec = model.cache_spec()
+    buf = jnp.zeros((2, 16, spec.kv_heads, spec.head_dim), spec.dtype)
+    caches = [(buf, buf, jnp.asarray([3, 5], jnp.int32))] \
+        * spec.num_layers
+    ids = paddle.to_tensor(np.ones((2, 1), np.int64))
+    with pytest.raises(ValueError, match="4-tuple cache"):
+        model.cached_forward(ids, caches)
 
 
 def test_scheduler_fifo_admission():

@@ -2,14 +2,15 @@
 widened verify program): draft-proposer units (determinism, edge
 cases, hit-rate floor, state lifecycle) and the ISSUE-8 acceptance
 band — greedy speculative output TOKEN-IDENTICAL to the
-non-speculative engine and to generate(), for llama and GPT, on both
-KV layouts (incl. COW-shared prefixes), across a >= 25-seed property
+non-speculative engine and to generate(), for llama and GPT, with
+and without COW-shared prefixes, across a >= 25-seed property
 band — with the compile-once contract held (exactly ONE verify
 program per engine, k=1 fallback inside it, trace-count asserted)."""
 import numpy as np
 import pytest
 
 import paddle_tpu as paddle
+from conftest import model_greedy
 from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny_config
 from paddle_tpu.serving import (NgramProposer, SamplingParams,
                                 ServingEngine)
@@ -155,19 +156,16 @@ def test_proposer_rebuilds_on_shrunk_history():
 
 # -- engine verify: the >= 25-seed token-identity property band --------
 
-def _run_band(model, layout, seeds, *, max_len=64, shared=False,
+def _run_band(model, seeds, *, max_len=64, shared=False,
               spec_k=4, max_new=8):
     """One spec + one base engine (programs compile once), driven over
     ``seeds`` request mixes; every request's greedy output must be
     token-identical across the two."""
-    kw = dict(kv_layout=layout)
-    if layout == "paged":
-        kw["page_size"] = 8
     spec = ServingEngine(model, max_slots=3, max_len=max_len,
-                         min_bucket=8, speculative=True,
-                         spec_k=spec_k, **kw)
+                         min_bucket=8, page_size=8, speculative=True,
+                         spec_k=spec_k)
     base = ServingEngine(model, max_slots=3, max_len=max_len,
-                         min_bucket=8, **kw)
+                         min_bucket=8, page_size=8)
     accepted = 0
     for seed in seeds:
         rng = np.random.RandomState(seed)
@@ -193,37 +191,33 @@ def _run_band(model, layout, seeds, *, max_len=64, shared=False,
     return spec, accepted
 
 
-def test_llama_contiguous_identity_band_25_seeds():
+def test_llama_paged_identity_band_25_seeds_with_shared_prefixes():
+    """Prefix sharing: every seed's prompts share a 9-token prefix
+    (full page + mid-page partial -> COW on first write), so
+    accepted/rejected speculative writes land in pages that started
+    life shared."""
     model = _tiny_llama()
-    spec, accepted = _run_band(model, "contiguous", range(25))
+    spec, accepted = _run_band(model, range(25), shared=True)
     assert accepted >= 20       # the band really speculated
     assert spec.proposer.tracked() == []      # state all released
-
-
-def test_llama_paged_identity_band_25_seeds_with_shared_prefixes():
-    """Paged layout with prefix sharing: every seed's prompts share a
-    9-token prefix (full page + mid-page partial -> COW on first
-    write), so accepted/rejected speculative writes land in pages that
-    started life shared."""
-    model = _tiny_llama()
-    spec, accepted = _run_band(model, "paged", range(25), shared=True)
-    assert accepted >= 20
     assert spec.cache.prefix_hit_tokens > 0   # sharing really engaged
     assert spec.cache.cow_copies >= 1
     from paddle_tpu.resilience.invariants import page_leak_violations
     assert page_leak_violations(spec) == []   # spec rollback leak-free
 
 
-def test_gpt_identity_band_both_layouts():
+def test_gpt_identity_band_unshared_and_shared():
     model = _tiny_gpt()
-    _run_band(model, "contiguous", range(8))
-    _run_band(model, "paged", range(8, 16))
+    _run_band(model, range(8))
+    _run_band(model, range(8, 16), shared=True)
 
 
-def test_speculative_matches_generate():
-    """End to end vs the model's own generate(): the spec engine's
-    greedy output equals the fused static-cache decode."""
-    model = _tiny_llama()
+@pytest.mark.parametrize("family", ["llama", "gpt"])
+def test_speculative_matches_generate(family):
+    """End to end vs the model's own greedy decode (llama: the fused
+    static-cache generate(); GPT: a cache-free loop): the spec
+    engine's greedy output equals it."""
+    model = _tiny_llama() if family == "llama" else _tiny_gpt()
     rng = np.random.RandomState(3)
     prompts = _mixed_prompts(rng, 4, lo=5, hi=10)
     eng = ServingEngine(model, max_slots=2, max_len=64, min_bucket=8,
@@ -231,9 +225,8 @@ def test_speculative_matches_generate():
     reqs = [eng.submit(p, max_new_tokens=10) for p in prompts]
     eng.run()
     for p, req in zip(prompts, reqs):
-        ref = model.generate(paddle.to_tensor(p[None]),
-                             max_new_tokens=10).numpy()[0, len(p):]
-        np.testing.assert_array_equal(ref, np.asarray(req.output_ids))
+        assert req.output_ids == model_greedy(model, p, 10)
+    assert eng._spec["accepted_draft_tokens"] >= 1    # it speculated
 
 
 def test_speculative_eos_stops_inside_accepted_run():
@@ -402,8 +395,7 @@ def test_faulted_verify_returns_overclaimed_pages():
     from paddle_tpu.resilience import faults
     from paddle_tpu.resilience.invariants import page_leak_violations
     model = _tiny_llama()
-    kw = dict(max_slots=1, max_len=64, min_bucket=8,
-              kv_layout="paged", page_size=8)
+    kw = dict(max_slots=1, max_len=64, min_bucket=8, page_size=8)
     # own registries: spec_k=8 buckets must not collide with the
     # default registry's spec_k=4 histograms from earlier tests
     eng = ServingEngine(model, speculative=True, spec_k=8,

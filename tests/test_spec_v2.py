@@ -7,8 +7,8 @@ sampled rejection-sampling acceptance + serving/spec_tune.SpecTuner):
 - The greedy token-identity property band with a draft MODEL behind
   the verify program — an INDEPENDENT draft (disagrees with the
   target constantly) and a self-draft oracle (agrees constantly, the
-  acceptance-floor regime) — across llama + GPT, contiguous + paged
-  with COW-shared prefixes, >= 25 seeds total.
+  acceptance-floor regime) — across llama + GPT, with and without
+  COW-shared prefixes, >= 25 seeds total.
 - Sampled acceptance: distribution parity vs the k=1 engine
   (aggregate histograms under fixed sampling seeds), bitwise parity
   for sampled rows when spec_sampled is OFF, and the residual
@@ -170,15 +170,13 @@ def test_draft_proposer_short_and_full_histories():
 
 # -- greedy identity band with a draft model ---------------------------
 
-def _run_band(model, draft, layout, seeds, *, max_len=64, shared=False,
+def _run_band(model, draft, seeds, *, max_len=64, shared=False,
               spec_k=4, max_new=8, **extra):
     """One draft-spec + one base engine over ``seeds`` request mixes;
     every greedy output must be token-identical, under the compile-
     once contract: ONE verify program, ONE draft program, at most one
     k=1 decode program (the gate serves draft-less steps)."""
-    kw = dict(kv_layout=layout, **extra)
-    if layout == "paged":
-        kw["page_size"] = 8
+    kw = dict(page_size=8, **extra)
     spec = ServingEngine(model, max_slots=3, max_len=max_len,
                          min_bucket=8, speculative=True, spec_k=spec_k,
                          spec_proposer="draft", draft_model=draft,
@@ -211,8 +209,8 @@ def test_independent_draft_identity_band_25_seeds():
     must still emit exactly the target's greedy chain, every seed."""
     model = _tiny_llama()
     draft = _tiny_draft()
-    spec = _run_band(model, draft, "contiguous", range(13))
-    _run_band(model, draft, "paged", range(13, 25), shared=True)
+    spec = _run_band(model, draft, range(13))
+    _run_band(model, draft, range(13, 25), shared=True)
     st = spec.spec_stats()
     assert st["proposer"] == "draft"
     assert st["draft_tokens"] > 0       # it really drafted
@@ -228,7 +226,7 @@ def test_self_draft_acceptance_floor_band():
     proves the k-wide program actually consumes drafts instead of
     silently running k=1."""
     model = _tiny_llama()
-    spec = _run_band(model, model, "contiguous", range(8))
+    spec = _run_band(model, model, range(8))
     st = spec.spec_stats()
     assert st["draft_hit_rate"] >= 0.95, st
     assert st["accepted_per_step"] >= 2.0, st
@@ -238,16 +236,16 @@ def test_self_draft_acceptance_floor_band():
 
 def test_gpt_draft_identity_band():
     """Draft speculation is model-family-agnostic: a GPT target behind
-    a GPT self-draft holds the same identity law on both layouts."""
+    a GPT self-draft holds the same identity law, with and without
+    a shared prefix."""
     model = _tiny_gpt()
-    _run_band(model, model, "contiguous", range(4))
-    _run_band(model, model, "paged", range(4, 8))
+    _run_band(model, model, range(4))
+    _run_band(model, model, range(4, 8), shared=True)
 
 
 def test_paged_shared_prefix_draft_band_leak_free():
     model = _tiny_llama()
-    spec = _run_band(model, _tiny_draft(), "paged", range(6),
-                     shared=True)
+    spec = _run_band(model, _tiny_draft(), range(6), shared=True)
     assert spec.cache.prefix_hit_tokens > 0
     from paddle_tpu.resilience.invariants import page_leak_violations
     assert page_leak_violations(spec) == []
@@ -260,8 +258,7 @@ def test_int8_kv_draft_identity_band():
     spec engine's quantized pool stays write-identical to the base
     engine's — output token-identical between the two int8 engines."""
     model = _tiny_llama()
-    _run_band(model, _tiny_draft(), "paged", range(5),
-              kv_dtype="int8")
+    _run_band(model, _tiny_draft(), range(5), kv_dtype="int8")
 
 
 # -- sampled acceptance ------------------------------------------------

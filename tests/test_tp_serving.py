@@ -3,8 +3,8 @@ under a `model`-axis mesh on the emulated 8-device CPU mesh.
 
 Acceptance band: sharded decode (TP=2) is greedy TOKEN-IDENTICAL to
 the single-chip engine and to ``generate()`` across a >= 25-seed
-property band — llama (GQA) and GPT, contiguous and paged layouts
-including COW-shared prefixes — with decode/verify trace counts == 1
+property band — llama (GQA) and GPT, COW-shared prefixes
+included — with decode/verify trace counts == 1
 per mesh shape (the compile-once contract survives sharding).
 
 Disaggregated prefill/decode: full prefills run on the prefill chip
@@ -19,7 +19,8 @@ import numpy as np
 import pytest
 
 import paddle_tpu as paddle
-from conftest import require_devices, serving_model_mesh
+from conftest import (model_greedy, require_devices,
+                      serving_model_mesh)
 from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny_config
 from paddle_tpu.serving import ServingEngine
 
@@ -85,12 +86,8 @@ def _drive(eng, prompts, max_new=8):
     return [list(r.out_tokens) for r in reqs]
 
 
-def _engine(family, layout, mesh=None, prefill=0, **kw):
-    eng_kw = dict(max_slots=4, max_len=64, min_bucket=8)
-    if layout == "paged":
-        eng_kw["page_size"] = 8
-    else:
-        eng_kw["kv_layout"] = "contiguous"
+def _engine(family, mesh=None, prefill=0, **kw):
+    eng_kw = dict(max_slots=4, max_len=64, min_bucket=8, page_size=8)
     if mesh is not None:
         eng_kw["mesh"] = mesh
         if prefill:
@@ -103,47 +100,40 @@ def _engine(family, layout, mesh=None, prefill=0, **kw):
 # the >= 25-seed identity band (acceptance criterion)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("family,layout", [
-    ("llama", "contiguous"), ("llama", "paged"),
-    ("gpt", "contiguous"), ("gpt", "paged"),
-])
-def test_tp2_token_identity_band_25_seeds(family, layout):
+@pytest.mark.parametrize("family", ["llama", "gpt"],
+                         ids=["llama-paged", "gpt-paged"])
+def test_tp2_token_identity_band_25_seeds(family):
     """TP=2 greedy outputs == single-chip engine outputs, bitwise, for
-    25 seeded traffic waves per (family, layout) — paged waves share a
-    prompt prefix so COW/prefix-index paths run sharded too. ONE
+    25 seeded traffic waves per family — the waves share a prompt
+    prefix so COW/prefix-index paths run sharded too. ONE
     engine pair serves all 25 waves, so the band also proves the
     compile-once contract: exactly one decode program per mesh shape
     across the whole band."""
     mesh = serving_model_mesh(tp=2)
     shared = np.arange(1, 11, dtype=np.int64)  # > 1 page of 8
-    ref_eng = _engine(family, layout)
-    tp_eng = _engine(family, layout, mesh=mesh)
+    ref_eng = _engine(family)
+    tp_eng = _engine(family, mesh=mesh)
     for seed in range(25):
         rng = np.random.RandomState(1000 + seed)
-        prompts = _wave(rng, shared=shared
-                        if layout == "paged" else None)
+        prompts = _wave(rng, shared=shared)
         ref = _drive(ref_eng, prompts)
         got = _drive(tp_eng, prompts)
-        assert got == ref, (family, layout, seed)
+        assert got == ref, (family, seed)
     assert tp_eng.trace_counts["decode"] == 1
     assert tp_eng.trace_counts["verify"] == 0
     assert ref_eng.trace_counts["decode"] == 1
 
 
-def test_tp2_matches_generate():
+@pytest.mark.parametrize("family", ["llama", "gpt"])
+def test_tp2_matches_generate(family):
     """The sharded engine's greedy output equals the model's own
-    generate() (transitively pinned through the single-chip engine in
-    the band above; direct here for one wave)."""
+    greedy decode (transitively pinned through the single-chip engine
+    in the band above; direct here for one wave)."""
     mesh = serving_model_mesh(tp=2)
-    model = _model("llama")
     rng = np.random.RandomState(0)
     prompts = _wave(rng)
-    eng = _engine("llama", "paged", mesh=mesh)
-    got = _drive(eng, prompts, max_new=8)
-    for p, out in zip(prompts, got):
-        gen = model.generate(paddle.to_tensor(p[None, :]),
-                             max_new_tokens=8)
-        assert out == list(np.asarray(gen.numpy())[0, len(p):])
+    got = _drive(_engine(family, mesh=mesh), prompts, max_new=8)
+    assert got == [model_greedy(_model(family), p, 8) for p in prompts]
 
 
 def test_tp2_speculative_identity_and_one_verify_program():
@@ -155,8 +145,8 @@ def test_tp2_speculative_identity_and_one_verify_program():
     pat = rng.randint(1, 100, (3,))
     prompts = [np.tile(pat, 5)[:int(n)].astype(np.int64)
                for n in (9, 12, 14)]
-    ref = _drive(_engine("llama", "paged"), prompts, max_new=10)
-    spec = _engine("llama", "paged", mesh=mesh, speculative=True,
+    ref = _drive(_engine("llama"), prompts, max_new=10)
+    spec = _engine("llama", mesh=mesh, speculative=True,
                    spec_k=4)
     got = _drive(spec, prompts, max_new=10)
     assert got == ref
@@ -175,8 +165,8 @@ def test_tp2_int8_kv_matches_single_chip_int8():
     mesh = serving_model_mesh(tp=2)
     rng = np.random.RandomState(5)
     prompts = _wave(rng, shared=np.arange(1, 11, dtype=np.int64))
-    ref = _drive(_engine("llama", "paged", kv_dtype="int8"), prompts)
-    got = _drive(_engine("llama", "paged", kv_dtype="int8",
+    ref = _drive(_engine("llama", kv_dtype="int8"), prompts)
+    got = _drive(_engine("llama", kv_dtype="int8",
                          mesh=mesh), prompts)
     assert got == ref
 
@@ -188,8 +178,8 @@ def test_tp2_recover_replays_token_identically():
     mesh = serving_model_mesh(tp=2)
     rng = np.random.RandomState(11)
     prompts = _wave(rng)
-    ref = _drive(_engine("llama", "paged"), prompts)
-    eng = _engine("llama", "paged", mesh=mesh)
+    ref = _drive(_engine("llama"), prompts)
+    eng = _engine("llama", mesh=mesh)
     eng._donate = lambda: (5, 6)          # TPU-like donated pools
     reqs = [eng.submit(p, 8) for p in prompts]
     faults.inject("serving.decode.sharded", times=1, after=2)
@@ -214,25 +204,32 @@ def _quiesced_pool_clean(eng):
     return engine_leak_violations(eng) + page_leak_violations(eng)
 
 
-@pytest.mark.parametrize("family,layout,split", [
-    ("llama", "paged", 2), ("llama", "contiguous", 1),
-    ("gpt", "paged", 2),
+@pytest.mark.parametrize("family,split,against", [
+    ("llama", 2, "engine"), ("llama", 1, "engine"),
+    ("gpt", 2, "engine"),
+    ("llama", 2, "generate"), ("gpt", 2, "generate"),
 ])
-def test_disaggregated_token_identity(family, layout, split):
+def test_disaggregated_token_identity(family, split, against):
     """Disaggregated prefill/decode (prefill group = ``split``
     devices, decode group TP=2 or 1): outputs identical to the
-    single-chip engine, installs bounded by the prefill bucket set,
-    no staged handoff survives quiesce."""
+    single-chip engine over five waves, or (``against="generate"``)
+    to the model's own greedy decode over one; installs bounded by
+    the prefill bucket set, no staged handoff survives quiesce."""
     mesh = serving_model_mesh(tp=2 if split == 2 else 1,
                               prefill=split)
     shared = np.arange(1, 11, dtype=np.int64)
-    ref_eng = _engine(family, layout)
-    dis = _engine(family, layout, mesh=mesh, prefill=split)
-    for seed in range(5):
-        rng = np.random.RandomState(2000 + seed)
-        prompts = _wave(rng, shared=shared
-                        if layout == "paged" else None)
-        assert _drive(dis, prompts) == _drive(ref_eng, prompts), seed
+    dis = _engine(family, mesh=mesh, prefill=split)
+    if against == "generate":
+        prompts = _wave(np.random.RandomState(2000), shared=shared)
+        assert _drive(dis, prompts) == [
+            model_greedy(_model(family), p, 8) for p in prompts]
+    else:
+        ref_eng = _engine(family)
+        for seed in range(5):
+            rng = np.random.RandomState(2000 + seed)
+            prompts = _wave(rng, shared=shared)
+            assert _drive(dis, prompts) == _drive(ref_eng, prompts), \
+                seed
     assert dis.trace_counts["decode"] == 1
     # one install compile per distinct prefill block shape — the same
     # O(log max_len) budget as the prefill buckets themselves
@@ -250,8 +247,8 @@ def test_handoff_fault_requeues_and_stays_identical():
     mesh = serving_model_mesh(tp=2, prefill=2)
     rng = np.random.RandomState(21)
     prompts = _wave(rng)
-    ref = _drive(_engine("llama", "paged"), prompts)
-    eng = _engine("llama", "paged", mesh=mesh, prefill=2)
+    ref = _drive(_engine("llama"), prompts)
+    eng = _engine("llama", mesh=mesh, prefill=2)
     reqs = [eng.submit(p, 8) for p in prompts]
     faults.inject("serving.kv.handoff", times=2)
     while eng.has_work():
@@ -317,7 +314,7 @@ def test_stranded_staged_handoff_is_reported_by_leak_audit():
     engine_leak_violations rather than passing vacuously."""
     from paddle_tpu.resilience.invariants import engine_leak_violations
     mesh = serving_model_mesh(tp=2, prefill=2)
-    eng = _engine("llama", "paged", mesh=mesh, prefill=2)
+    eng = _engine("llama", mesh=mesh, prefill=2)
     assert engine_leak_violations(eng) == []
     eng._staged_handoffs[7] = 0           # simulate a forgotten unwind
     v = engine_leak_violations(eng)
@@ -335,8 +332,8 @@ def test_dropped_handoff_is_detected_by_token_identity():
     mesh = serving_model_mesh(tp=2, prefill=2)
     rng = np.random.RandomState(44)
     prompts = _wave(rng)
-    ref = _drive(_engine("llama", "paged"), prompts)
-    eng = _engine("llama", "paged", mesh=mesh, prefill=2)
+    ref = _drive(_engine("llama"), prompts)
+    eng = _engine("llama", mesh=mesh, prefill=2)
     real_install = eng._install_fn
 
     def skip_install(key):
@@ -444,7 +441,7 @@ def test_pools_and_params_actually_sharded():
     pinned so a sharding-spec regression cannot hide behind the
     identity tests."""
     mesh = serving_model_mesh(tp=2)
-    eng = _engine("llama", "paged", mesh=mesh)
+    eng = _engine("llama", mesh=mesh)
     prompts = _wave(np.random.RandomState(1))
     _drive(eng, prompts)
     import jax
