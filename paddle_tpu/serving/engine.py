@@ -71,7 +71,8 @@ from .errors import (DeadlineExceeded, EngineBroken, EngineClosed,
 from .kv_tier import HostPageTier, PersistentPrefixStore
 from .mesh import MeshContext
 from .metrics import EngineMetrics
-from .sampling import SamplingParams, sample_token, sampling_dist
+from .sampling import (ArgmaxRow, SamplingParams, sample_token,
+                       sampling_dist)
 from .scheduler import FIFOScheduler, Request, bucket_for
 from .slot_cache import PagedKVCache, SlotStateCache
 from .spec_decode import DraftModelProposer, NgramProposer
@@ -524,6 +525,11 @@ class ServingEngine:
             "ptpu_serving_recover_replay_mismatch_total",
             "recovery re-prefills whose greedy replay token diverged "
             "from the already-delivered token")
+        self._m_sampled = reg.counter(
+            "ptpu_serving_sampled_tokens_total",
+            "tokens of plain decode steps, by where each was chosen: "
+            "the decode program's argmax (device) or the fetched "
+            "logits (host)", labels=("where",))
         if self.prefill_chunk is not None:
             self._m_chunk_steps = reg.counter(
                 "ptpu_serving_chunk_steps_total",
@@ -1166,7 +1172,7 @@ class ServingEngine:
                            step=self._step_idx - 1, tp=self.meshctx.tp)
             with span("serving.decode.enqueue", batch=len(active)):
                 if self.paged:
-                    logits, ks, vs, kss, vss = self._decode_fn()(
+                    logits, best, ks, vs, kss, vss = self._decode_fn()(
                         self._params, self._buffers, toks, pos, mask,
                         self.cache.page_table.copy(),
                         self.cache.ks, self.cache.vs,
@@ -1175,16 +1181,30 @@ class ServingEngine:
                     self.cache.kss, self.cache.vss = \
                         list(kss), list(vss)
                 else:
-                    logits, *pools = self._decode_fn()(
+                    logits, best, *pools = self._decode_fn()(
                         self._params, self._buffers, toks, pos, mask,
                         *self.cache.pools)
                     self.cache.pools = pools
-            logits = self._fetch("serving.decode.fetch", logits)
-        with span("serving.sample", rows=len(active)) as sp:
+            # the batch decides what the step fetches: all rows greedy,
+            # the program's argmax ([slots] int32); one row that
+            # samples, the logits, which its seeded host stream draws
+            # from (a mixed batch takes the host path whole)
+            on_device = all(self.cache.slots[s].sampling.temperature <= 0
+                            for s in active)
+            fetched = self._fetch("serving.decode.fetch",
+                                  best if on_device else logits)
+        n = len(active)
+        with span("serving.sample", rows=n,
+                  device_rows=n if on_device else 0,
+                  host_rows=0 if on_device else n) as sp:
+            self._m_sampled.labels(
+                where="device" if on_device else "host").inc(n)
             n0 = len(finished)
             for s in active:
                 req = self.cache.slots[s]
-                tok = sample_token(logits[s], req.sampling, req._rng)
+                row = ArgmaxRow(logits, s, int(fetched[s])) \
+                    if on_device else fetched[s]
+                tok = sample_token(row, req.sampling, req._rng)
                 req.out_tokens.append(tok)
                 self.metrics.on_token(req.rid)
                 if self._is_finished(req, tok):
@@ -2843,6 +2863,9 @@ class ServingEngine:
         advances one token at its own position; the active-slot mask
         pins inactive lanes to position 0 and zeroes their logits so
         they stay numerically inert whatever garbage their row holds.
+        Beside the logits it returns their argmax a slot, ``[slots]``
+        int32: a greedy step fetches that and leaves the logits on the
+        device (``_decode_plain``).
         K and V flow through the page tables (inactive rows pinned to
         the trash page); a stateful model's step reads and rewrites
         its slot rows, active slots only.
@@ -2856,6 +2879,7 @@ class ServingEngine:
         if self._decode_jit is not None:
             return self._decode_jit
         ad = self.adapter
+        greedy = lambda logits: jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
         if self.paged:
             jit_kw = {}
@@ -2863,7 +2887,7 @@ class ServingEngine:
                 psh, bsh, R, kv, sc = self._prog_shardings()
                 jit_kw = dict(
                     in_shardings=(psh, bsh, R, R, R, R, kv, kv, sc, sc),
-                    out_shardings=(R, kv, kv, sc, sc))
+                    out_shardings=(R, R, kv, kv, sc, sc))
 
             def ptpu_decode(params, buffers, toks, pos, active, tables, ks,
                      vs, kss, vss):
@@ -2877,7 +2901,8 @@ class ServingEngine:
                     logits = ad.head(h[:, -1:])._data[:, -1]
                 self.decode_attend = noted_fact("attend")
                 logits = jnp.where(active[:, None], logits, 0.0)
-                return (logits,) + self._unpack_paged(new_caches)
+                return (logits, greedy(logits)) \
+                    + self._unpack_paged(new_caches)
 
             self._decode_jit = self._jit(
                 ptpu_decode, donate_argnums=self._donate_idx(6, 7, 8, 9),
@@ -2895,7 +2920,7 @@ class ServingEngine:
             logits = jnp.where(active[:, None], logits, 0.0)
             ks2 = [getattr(c[0], "_data", c[0]) for c in new_caches]
             vs2 = [getattr(c[1], "_data", c[1]) for c in new_caches]
-            return logits, ks2, vs2
+            return logits, greedy(logits), ks2, vs2
 
         self._decode_jit = self._jit(
             ptpu_decode, donate_argnums=self._donate())

@@ -1,9 +1,22 @@
 """Token sampling for the serving engine.
 
-Host-side by design: continuous batching already requires a host
-round-trip every step (EOS detection + admission/eviction decisions),
-so sampling rides the same fetched ``[slots, vocab]`` logits instead
-of adding a second compiled program per sampling configuration.
+A greedy token is chosen where its logits are; a sampled one on the
+host. Continuous batching needs a host round-trip every step (EOS
+detection, admission and eviction), and what the trip carries follows
+from the batch: the decode program returns, beside its ``[slots,
+vocab]`` logits (the model's dtype), their argmax a slot (``[slots]``
+int32), and a decode step whose active rows are ALL greedy
+(``temperature <= 0``) fetches that vector alone. A step with one row
+that samples (``temperature > 0``) fetches the logits and every row of
+it is chosen here, as the first token of a prefill, extend or chunk
+always is: a sampled token is drawn from the request's own seeded
+NumPy stream, which no device sampler could replay token for token,
+and one compiled decode program serves every sampling configuration.
+
+Either way every token passes through ``sample_token``, one call a row
+(the seam where tests and the benchmark's planted fault see, and alter,
+a token as it is produced): a greedy step hands it an ``ArgmaxRow``,
+the row as it stayed on the device.
 """
 from __future__ import annotations
 
@@ -12,7 +25,8 @@ from typing import Optional
 
 import numpy as np
 
-__all__ = ["SamplingParams", "sample_token", "sampling_dist"]
+__all__ = ["ArgmaxRow", "SamplingParams", "sample_token",
+           "sampling_dist"]
 
 
 @dataclasses.dataclass
@@ -30,6 +44,23 @@ class SamplingParams:
     def validate(self):
         if self.top_k < 0:
             raise ValueError(f"top_k must be >= 0, got {self.top_k}")
+
+
+class ArgmaxRow:
+    """One logits row that stayed on the device: its length and its
+    argmax (lowest index on a tie, a NaN counts as the maximum, as
+    ``np.argmax``) are here; its values cross only for who asks
+    (``np.asarray(row)``: tests do, the engine never)."""
+    __slots__ = ("_logits", "_slot", "argmax")
+
+    def __init__(self, logits, slot: int, argmax: int):
+        self._logits, self._slot, self.argmax = logits, slot, argmax
+
+    def __len__(self) -> int:
+        return self._logits.shape[-1]
+
+    def __array__(self, dtype=None, copy=None):
+        return np.asarray(self._logits[self._slot], dtype=dtype)
 
 
 def sampling_dist(logits: np.ndarray,
@@ -55,7 +86,15 @@ def sampling_dist(logits: np.ndarray,
 
 def sample_token(logits: np.ndarray, params: SamplingParams,
                  rng: np.random.RandomState) -> int:
-    """Pick one token id from a [vocab] logits row."""
+    """Pick one token id from a [vocab] logits row. An ``ArgmaxRow``
+    serves greedy requests only: the engine fetches the logits for a
+    step with any row that samples."""
+    if isinstance(logits, ArgmaxRow):
+        if params.temperature > 0:
+            raise ValueError(
+                "an ArgmaxRow holds a row's argmax alone: a request "
+                f"with temperature {params.temperature} needs its logits")
+        return logits.argmax
     if params.temperature <= 0:
         return int(np.argmax(logits))
     p = sampling_dist(logits, params)

@@ -318,7 +318,9 @@ def test_step_span_carries_the_counts_at_its_boundary(engine_run):
     assert sum(s["attrs"]["admitted"] for s in admits) == 3
     assert all("refused_for_pages" in s["attrs"] for s in admits)
     fetch = next(s for s in spans if s["name"] == "serving.decode.fetch")
-    assert fetch["attrs"]["bytes"] == 4 * 256 * 4   # [slots, vocab] f32
+    # greedy requests: the step fetches the program's argmax, [slots]
+    # int32, not the [slots, vocab] float32 logits (4 * 256 * 4)
+    assert fetch["attrs"]["bytes"] == 4 * 4
     assert all("request_ids" not in s["attrs"] for s in spans
                if s["name"] == "serving.decode")
 
