@@ -11,6 +11,21 @@ movement is two einsums — when the expert dim is sharded over the mesh's
 expert axis, XLA lowers those einsums to exactly the all-to-all pair the
 reference implements by hand, and they overlap with expert compute.
 Static shapes (capacity) keep everything jit-compatible.
+
+Two routing cores, each for what it serves. ``_topk_gating`` is the
+trainer's (``GPTSpmdTrainer._block_moe``) and ``MoELayer``'s: top-1 or
+top-2 with a capacity, a dense ``[tokens, experts, capacity]`` dispatch
+whose einsums XLA partitions over an expert mesh axis, tokens over the
+capacity dropped, an auxiliary loss. ``route_topk`` + ``expert_share``
+is a served model's (``models/solar.py``): top-k of any k over ALL the
+published experts, no capacity and no dropped token, the assignments
+whose expert this chip holds sorted by expert and run through a grouped
+matrix product (``ops/grouped_matmul.py``), so that work follows the
+assignments held and not experts x capacity. It is told which experts
+it holds (``first_expert``, and as many as its weights have) and gives
+this chip's part of the layer's result: on one chip without the
+exchange; the all-to-all over an ``expert`` mesh axis is what is left
+before ``_block_moe`` can move onto it.
 """
 from __future__ import annotations
 
@@ -25,8 +40,69 @@ from ..nn import functional as F
 from ..nn import initializer as I
 from ..nn.layer_base import Layer
 
+from ..ops import grouped_matmul as gm
+
 __all__ = ["MoELayer", "GShardGate", "SwitchGate", "NaiveGate",
-           "moe_dispatch_combine"]
+           "moe_dispatch_combine", "route_topk", "expert_share"]
+
+_F32 = jnp.float32
+
+
+def route_topk(x, router, k: int):
+    """Softmax scores of ``x [T, D]`` over all the experts of ``router
+    [D, E]`` and each token's ``k`` largest, their weights normalised
+    over the ``k``: ``(weights [T, k] float32, experts [T, k] int32)``.
+    Float32 at ``Precision.HIGHEST`` throughout: a router decides by
+    rank, and rounding can swap a token's k-th and (k+1)-th expert."""
+    scores = jax.nn.softmax(jnp.matmul(
+        x.astype(_F32), router.astype(_F32),
+        precision=jax.lax.Precision.HIGHEST), axis=-1)
+    w, idx = jax.lax.top_k(scores, k)
+    return w / jnp.sum(w, axis=-1, keepdims=True), idx.astype(jnp.int32)
+
+
+def expert_share(x, weights, experts, gate, up, down, first_expert: int,
+                 *, name: str = "expert_gmm", kernel=None):
+    """This chip's part of a routed SwiGLU layer: ``sum over a token's
+    chosen experts that are held here of weight * down_e(silu(gate_e x)
+    * up_e x)``.
+
+    ``x [T, D]`` float32; ``weights, experts [T, k]`` from
+    ``route_topk`` (normalised over all ``k``, held or not); ``gate, up
+    [H, D, F]`` and ``down [H, F, D]`` the ``H`` experts held, which are
+    experts ``first_expert .. first_expert + H - 1``. No capacity: every
+    assignment whose expert is held is computed. Returns ``(y [T, D]
+    float32, counts [H] int32)``, the assignments each held expert got.
+
+    The ``T * k`` assignments are sorted by expert, those of experts not
+    held last; the grouped product visits only the rows of a held
+    expert, so the matrix work follows the assignments held."""
+    T, D = x.shape
+    k = experts.shape[1]
+    H = gate.shape[0]
+    local = experts.reshape(-1) - first_expert
+    held = (local >= 0) & (local < H)
+    key = jnp.where(held, local, H)
+    order = jnp.argsort(key, stable=True)
+    counts = jnp.zeros(H + 1, jnp.int32).at[key].add(1)[:H]
+    x = x.astype(_F32)
+    if gm.use_kernel(kernel) and gate.dtype == jnp.bfloat16:
+        # the rows are cut into their two bfloat16 pieces before they
+        # are gathered: the pieces of T rows, not of T * k
+        rows = gm.pieces(x)[:, order // k]
+    else:
+        rows = x[order // k]
+    gmm = lambda a, w: gm.grouped_matmul(a, w, counts, name=name,
+                                         kernel=kernel)
+    y = gmm(jax.nn.silu(gmm(rows, gate)) * gmm(rows, up), down)
+    # back to the tokens' order by a gather, then the k parts of a token
+    # are weighted and summed; the rows of no held expert are whatever
+    # the kernel's buffers held, and are dropped here by a select, never
+    # multiplied by a zero
+    y = y[jnp.argsort(order)].reshape(T, k, D)
+    w = weights.reshape(T, k).astype(_F32)
+    part = jnp.where(held.reshape(T, k, 1), y * w[..., None], 0.0)
+    return jnp.sum(part, axis=1), counts
 
 
 def _topk_gating(logits, capacity, topk=2):

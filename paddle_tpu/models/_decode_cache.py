@@ -91,23 +91,41 @@ __all__ = ["CacheSpec", "cache_attend", "check_cache_pos",
 @dataclasses.dataclass(frozen=True)
 class CacheSpec:
     """What a causal LM asks the serving engine to hold for it between
-    steps (its ``cache_spec()``), a layer and a slot:
+    steps (its ``cache_spec()``), a slot: ``layers`` says it layer by
+    layer, and a model may mix the two kinds.
 
-    - ``kind="kv"``: K and V by position, ``[positions, kv_heads,
+    - ``"kv"``: K and V by position, ``[positions, kv_heads,
       head_dim]`` each, in ``dtype``; the engine holds them in pages
-      and the forward's cache tuples are ``models/_decode_cache``'s;
-    - ``kind="state"``: fixed-size arrays whatever the length, ``state``
-      naming each with its shape a slot and its dtype; a prefill builds
-      them (cache ``(None, None, true_len)``), a decode step reads and
-      rewrites them (``(*arrays, pos, active)``).
-    """
-    kind: str
-    num_layers: int
+      and the layer's cache tuples are ``models/_decode_cache``'s;
+    - ``"state"``: fixed-size arrays whatever the length, ``state``
+      naming each with its shape a slot and its dtype (the same for
+      every state layer); a prefill builds them (cache ``(None, None,
+      true_len)``), a decode step reads and rewrites them (``(*arrays,
+      pos, active)``).
+
+    A softmax decoder is ``("kv",) * n``, an attention-free one
+    ``("state",) * n``."""
+    layers: Tuple[str, ...]
     kv_heads: int
     head_dim: int
     dtype: Any                  # the model's
     max_positions: int
     state: Tuple[Tuple[str, Tuple[int, ...], Any], ...] = ()
+
+    def __post_init__(self):
+        odd = set(self.layers) - {"kv", "state"}
+        if odd or not self.layers:
+            raise ValueError(f"layers of kind {sorted(odd)}: a layer "
+                             f"keeps 'kv' or 'state'")
+        if ("state" in self.layers) != bool(self.state):
+            raise ValueError("state layers and their arrays go together")
+
+    @property
+    def num_layers(self) -> int:
+        return len(self.layers)
+
+    def count(self, kind: str) -> int:
+        return sum(1 for k in self.layers if k == kind)
 
 
 def check_cache_pos(pos, t: int, Tmax: int) -> bool:
@@ -297,7 +315,14 @@ def paged_cache_attend(qr, kr, v, kp, vp, ks, vs, table, p,
         # bf16 non-shared token-identity contract: same probs dtype
         # and same value einsum as cache_attend
         vc = gather(vp)
-        probs = jax.nn.softmax(scores, axis=-1).astype(vc.dtype)
+        probs = jax.nn.softmax(scores, axis=-1)
+        if jnp.dtype(out_dtype).itemsize > vc.dtype.itemsize:
+            # a float32 caller of a bfloat16 pool (a model whose
+            # arithmetic is float32 over bfloat16 storage): the
+            # probabilities stay whole, only what is stored is rounded
+            vc = vc.astype(probs.dtype)
+        else:
+            probs = probs.astype(vc.dtype)
     out = jnp.einsum("bgrqk,bkgd->bqgrd", probs, vc)
     return (out.reshape(b, t, h * D).astype(out_dtype),
             kp, vp, ks, vs)

@@ -59,6 +59,7 @@ from ..nn.layer.common import Embedding, Linear
 from ..nn.layer.container import LayerList
 from ..nn.layer.norm import RMSNorm
 from ..ops import power_retention as pr
+from ..ops.grouped_matmul import pieces
 from ._decode_cache import CacheSpec
 from .llama import LlamaConfig, LlamaMLP, _apply_rope, _rope_cache
 
@@ -75,7 +76,7 @@ STATE_CHUNK = 256
 SEQUENCE_CHUNK = 128
 
 
-def _split_matmul(x, w):
+def _split_matmul(x, w, whole: bool = False):
     """``x [..., C]`` float32 times ``w [C, N]`` in float32. A bfloat16
     ``w`` is exact in float32, and the high and the middle eight bits of
     ``x``'s significand are two bfloat16 numbers, so two bfloat16
@@ -83,16 +84,16 @@ def _split_matmul(x, w):
     ``x`` without ever holding ``w`` in float32 (a highest-precision
     float32 product would upcast it, and take six passes). The pieces
     are cut with a bit mask, not by rounding to bfloat16 and back: XLA
-    may elide such a round trip where excess precision is allowed."""
+    may elide such a round trip where excess precision is allowed.
+    ``whole``: three pieces, all of ``x``: for a product whose result is
+    rounded for storage, where 2^-16 would move it across a rounding
+    boundary."""
     if w.dtype != jnp.bfloat16:
         return jnp.matmul(x, w.astype(F32),
                           precision=jax.lax.Precision.HIGHEST)
-    bits = jax.lax.bitcast_convert_type
-    high = bits(bits(x, jnp.uint32) & jnp.uint32(0xFFFF0000), F32)
-    pieces = jnp.stack([high, x - high]).astype(jnp.bfloat16)
-    out = jnp.einsum("p...c,cn->p...n", pieces, w,
+    out = jnp.einsum("p...c,cn->p...n", pieces(x, whole=whole), w,
                      preferred_element_type=F32)
-    return out[0] + out[1]
+    return out[0] + out[1] + out[2] if whole else out[0] + out[1]
 
 
 def _head_norm(x, w, eps):
@@ -259,7 +260,7 @@ class BrumbyForCausalLM(Layer):
         cfg = self.config
         P = pr.phi_size(cfg.head_dim)
         return CacheSpec(
-            kind="state", num_layers=len(self.brumby.layers),
+            layers=("state",) * len(self.brumby.layers),
             kv_heads=cfg.kv_heads, head_dim=cfg.head_dim,
             dtype=self.brumby.embed_tokens.weight._data.dtype,
             max_positions=cfg.max_position_embeddings,

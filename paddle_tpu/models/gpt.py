@@ -304,7 +304,7 @@ class GPTForCausalLM(Layer):
         cfg = self.cfg
         qw = self.gpt.blocks[0].qkv.weight
         return CacheSpec(
-            kind="kv", num_layers=len(self.gpt.blocks),
+            layers=("kv",) * len(self.gpt.blocks),
             kv_heads=qw.shape[-1] // (3 * cfg.head_dim),
             head_dim=cfg.head_dim, dtype=self.gpt.wte.weight._data.dtype,
             max_positions=cfg.max_seq_len)

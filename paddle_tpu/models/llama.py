@@ -475,7 +475,7 @@ class LlamaForCausalLM(Layer):
         kp = self.llama.layers[0].self_attn.k_proj
         kw = kp.weight if hasattr(kp, "weight") else kp.wq
         return CacheSpec(
-            kind="kv", num_layers=len(self.llama.layers),
+            layers=("kv",) * len(self.llama.layers),
             kv_heads=kw.shape[-1] // cfg.head_dim, head_dim=cfg.head_dim,
             dtype=self.llama.embed_tokens.weight._data.dtype,
             max_positions=cfg.max_position_embeddings)

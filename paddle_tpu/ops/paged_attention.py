@@ -25,7 +25,11 @@ bfloat16 parts whose products with the cache are exact, so the MXU runs
 bfloat16 passes and nothing of the query is rounded away; any other
 cache dtype is upcast and multiplied at ``Precision.HIGHEST``. Softmax
 statistics are float32; probabilities enter the value product in the
-cache's dtype (as the einsum path's do) and accumulate in float32.
+cache's dtype (as the einsum path's do) and accumulate in float32,
+except for a caller that asks a float32 result of a bfloat16 cache (a
+model whose arithmetic is float32 over bfloat16 storage): its
+probabilities go in as two bfloat16 parts, so that only what is stored
+is rounded.
 """
 from __future__ import annotations
 
@@ -38,6 +42,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import pallas_ops
+from .grouped_matmul import pieces
 from .pallas_ops import NEG_INF
 
 __all__ = ["paged_decode_attention", "kernel_fits"]
@@ -125,8 +130,20 @@ def _kernel(table_ref, pos_ref, first_ref,          # scalar prefetch
         m_ref[...] = m_new
         cv.wait()
         v = vbuf[buf].reshape(rows, D)
-        pv = jnp.dot(p.astype(v.dtype), v, preferred_element_type=F32,
-                     precision=None if exact else _HI)
+        if exact and o_ref.dtype == F32:
+            # a float32 caller: the probabilities as two bfloat16 parts
+            # (2^-16 of each), both against the page as it lies; the
+            # high part is cut with a bit mask, which no compiler takes
+            # for a round trip it may drop
+            bits = jax.lax.bitcast_convert_type
+            hi = bits(bits(p, jnp.uint32) & jnp.uint32(0xFFFF0000), F32)
+            pv = jnp.dot(jnp.concatenate([hi, p - hi], axis=0).astype(BF16),
+                         v, preferred_element_type=F32)
+            pv = pv[:heads] + pv[heads:]
+        else:
+            pv = jnp.dot(p.astype(v.dtype), v,
+                         preferred_element_type=F32,
+                         precision=None if exact else _HI)
         acc_ref[...] = alpha * acc_ref[...] + pv
         return 0
 
@@ -165,7 +182,13 @@ def _attention(q, kp, vp, table, pos, out_dtype, interpret: bool):
     per_seq = table.shape[1]
     q = q.astype(F32) * (1.0 / math.sqrt(D))
     if kp.dtype == BF16:
-        q = jnp.concatenate(_split3(q), axis=1)            # [B, 3H, D]
+        # [B, 3H, D]; for a float32 caller the parts are cut with bit
+        # masks (grouped_matmul.pieces): inside a larger program the
+        # TPU compiler may drop ``_split3``'s round trips through
+        # bfloat16 as excess precision, and the query is then 2^-9 off
+        q = jnp.concatenate(
+            list(pieces(q, whole=True)) if out_dtype == F32
+            else _split3(q), axis=1)
     pos = pos.astype(jnp.int32)
     n = jnp.minimum(pos // page, per_seq - 1) + 1          # live pages
     first = jnp.concatenate([jnp.zeros(1, jnp.int32), jnp.cumsum(n)])

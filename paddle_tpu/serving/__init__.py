@@ -8,9 +8,11 @@ design): one compiled decode-step program serves ANY mix of in-flight
 requests, admission is gated by free PAGES (worst-case span reserved,
 so decode never preempts), and finished sequences (EOS / length cap)
 are evicted immediately, their shared prompt pages staying cached for
-later requests. A model that keeps a recurrent state and no K/V
-(``cache_spec().kind == "state"``) is served from a state row a slot
-instead; nothing chooses between the two, the model's kind does.
+later requests. A layer that keeps a recurrent state and no K/V
+(``"state"`` in ``cache_spec().layers``) is served from a state row a
+slot instead, in the same cache manager (``SlotCache``), beside the
+pages of the layers that keep K and V; nothing chooses between the two,
+what each layer of the model keeps does.
 
     engine = ServingEngine(model, max_slots=8, max_len=512, eos_id=2)
     req = engine.submit(prompt_ids, max_new_tokens=64)
@@ -64,7 +66,7 @@ from .router import Replica, ReplicaRouter  # noqa: F401
 from .sampling import SamplingParams, sample_token  # noqa: F401
 from .scheduler import (FIFOScheduler, Request, bucket_for,  # noqa: F401
                         prefill_buckets)
-from .slot_cache import PagedKVCache, SlotStateCache  # noqa: F401
+from .slot_cache import PagedKVCache, SlotCache  # noqa: F401
 from .spec_decode import (DraftModelProposer,  # noqa: F401
                           NgramProposer)
 from .spec_tune import SpecTuner  # noqa: F401
@@ -73,7 +75,7 @@ __all__ = ["ServingEngine", "EngineMetrics", "MeshContext",
            "SamplingParams",
            "sample_token", "FIFOScheduler", "Request", "bucket_for",
            "prefill_buckets", "PagedKVCache",
-           "SlotStateCache", "StateCacheUnsupported",
+           "SlotCache", "StateCacheUnsupported",
            "NgramProposer", "DraftModelProposer", "SpecTuner",
            "ServingError",
            "QueueFull", "DeadlineExceeded", "EngineBroken",

@@ -53,6 +53,7 @@ deadlines (cancelled at step boundaries with ``finish_reason ==
 """
 from __future__ import annotations
 
+import contextlib
 import time
 from typing import Callable, List, Optional, Sequence
 
@@ -74,7 +75,7 @@ from .metrics import EngineMetrics
 from .sampling import (ArgmaxRow, SamplingParams, sample_token,
                        sampling_dist)
 from .scheduler import FIFOScheduler, Request, bucket_for
-from .slot_cache import PagedKVCache, SlotStateCache
+from .slot_cache import SlotCache
 from .spec_decode import DraftModelProposer, NgramProposer
 from .spec_tune import SpecTuner
 
@@ -83,12 +84,13 @@ __all__ = ["ServingEngine"]
 
 class _ModelAdapter:
     """Uniform view over the causal LMs the engine can serve. The model
-    states what it needs held between steps (``cache_spec()``: a
-    ``models/_decode_cache.CacheSpec``, K and V by position or a
-    fixed-size recurrent state), runs its backbone over the matching
-    cache tuples (``cached_forward(ids, caches)``) and has a logits
-    head (``_head``); this class turns the engine's pools into those
-    tuples and knows nothing else of a model family."""
+    states what each of its layers needs held between steps
+    (``cache_spec()``: a ``models/_decode_cache.CacheSpec``, K and V by
+    position or a fixed-size recurrent state, layer by layer), runs its
+    backbone over the matching cache tuples (``cached_forward(ids,
+    caches)``) and has a logits head (``_head``); this class turns the
+    engine's pools into those tuples and knows nothing else of a model
+    family."""
 
     def __init__(self, model):
         self.model = model
@@ -104,30 +106,34 @@ class _ModelAdapter:
         self.tp_param_spec = getattr(model, "tp_param_spec", None)
         self.call = model.cached_forward
         self.head = model._head
-        self.num_layers = spec.num_layers
+        # what a step counted beside its logits: small integer arrays
+        # by name, published under those names (none for most models)
+        self.counters = getattr(model, "step_counters", dict)
+        self.num_layers = spec.count("kv")      # layers with K/V pools
         self.kv_heads = spec.kv_heads
         self.head_dim = spec.head_dim
         self.max_positions = spec.max_positions
         self.dtype = spec.dtype
 
-    @property
-    def stateful(self) -> bool:
-        """The model keeps a fixed-size state a slot, no K/V."""
-        return self.spec.kind == "state"
-
     def prefill_caches(self, bucket: int, true_len):
         """Per-layer cache tuples for one prompt run from scratch."""
-        if self.stateful:
-            return [(None, None, true_len)] * self.num_layers
         shape = (1, bucket, self.kv_heads, self.head_dim)
         return [(jnp.zeros(shape, self.dtype),
-                 jnp.zeros(shape, self.dtype), 0)
-                for _ in range(self.num_layers)]
+                 jnp.zeros(shape, self.dtype), 0) if kind == "kv"
+                else (None, None, true_len) for kind in self.spec.layers]
 
-    def decode_caches(self, pools, pos, active):
-        """Per-layer cache tuples of a stateful model's slot-row pools
-        for one decode step at per-slot positions."""
-        return [layer + (pos, active) for layer in zip(*pools)]
+    def by_layer(self, kv, state) -> list:
+        """One entry a layer, in the model's order, from one list over
+        the K/V layers and one over the state layers."""
+        kv, state = iter(kv), iter(state)
+        return [next(kv if kind == "kv" else state)
+                for kind in self.spec.layers]
+
+    def by_kind(self, per_layer):
+        """The inverse of ``by_layer``: ``(kv entries, state entries)``."""
+        pick = lambda kind: [c for c, k in zip(per_layer, self.spec.layers)
+                             if k == kind]
+        return pick("kv"), pick("state")
 
 
 class ServingEngine:
@@ -214,15 +220,24 @@ class ServingEngine:
                 f"admission_lookahead must be >= 0, got "
                 f"{admission_lookahead}")
         self.admission_lookahead = int(admission_lookahead)
-        if self.adapter.stateful:
-            # the model keeps a fixed-size state a slot and no K/V: what
-            # reads, shares or moves K and V by position cannot work on
-            # it yet and is refused by name, never silently ignored
+        # what the model's layers keep decides everything below: the
+        # engine asks its cache manager, never the model's family
+        layers = self.adapter.spec.layers
+        self.paged = "kv" in layers           # has K/V layers: pages
+        self.stateful = "state" in layers     # has state layers: rows
+        if self.stateful:
+            # a layer keeps a fixed-size state a slot: what shares,
+            # rewinds, quantizes or moves a cache by position cannot
+            # restore or carry a state yet and is refused by name,
+            # never silently ignored; with no K/V layer at all there
+            # are no pages to size either
             for option, value, asked in (
-                    ("kv_layout", kv_layout,
-                     kv_layout not in (None, "state")),
-                    ("page_size", page_size, page_size is not None),
-                    ("num_pages", num_pages, num_pages is not None),
+                    ("kv_layout", kv_layout, kv_layout not in (
+                        None, "paged" if self.paged else "state")),
+                    ("page_size", page_size,
+                     page_size is not None and not self.paged),
+                    ("num_pages", num_pages,
+                     num_pages is not None and not self.paged),
                     ("kv_dtype", kv_dtype, kv_dtype is not None),
                     ("prefix_sharing", prefix_sharing,
                      bool(prefix_sharing)),
@@ -260,9 +275,6 @@ class ServingEngine:
             raise ValueError(
                 f"kv_dtype must be None (model dtype) or 'int8', got "
                 f"{kv_dtype!r}")
-        # the layout follows from what the model caches: K and V by
-        # position live in pages, a recurrent state in a row a slot
-        self.paged = not self.adapter.stateful
         if self.paged:
             if page_size is None:
                 # largest power-of-2 divisor of max_len, capped at 128
@@ -273,8 +285,10 @@ class ServingEngine:
             self.page_size = int(page_size)
             self.num_pages = num_pages        # None = capacity parity
             self.kv_quant = kv_dtype == "int8"
-            self.prefix_sharing = True if prefix_sharing is None \
-                else bool(prefix_sharing)
+            # a page alone does not restore a state: no sharing beside
+            # state layers
+            self.prefix_sharing = not self.stateful \
+                if prefix_sharing is None else bool(prefix_sharing)
         else:
             # a slot's state is its one page: nothing is shared and
             # nothing quantized
@@ -534,7 +548,7 @@ class ServingEngine:
             self._m_chunk_steps = reg.counter(
                 "ptpu_serving_chunk_steps_total",
                 "chunked-prefill chunk program runs")
-        if self.adapter.stateful:
+        if self.stateful:
             self._m_state_bytes = reg.gauge(
                 "ptpu_serving_state_bytes",
                 "total device bytes of the recurrent-state pool")
@@ -542,6 +556,9 @@ class ServingEngine:
             self._m_state_slots = reg.gauge(
                 "ptpu_serving_state_slots_in_use",
                 "slots whose recurrent state belongs to a request")
+        # ptpu_serving_<name>_total for each name of the model's
+        # step_counters(), made when the decode program first reports it
+        self._m_counted: dict = {}
         if self.paged:
             self._m_pages_free = reg.gauge(
                 "ptpu_serving_pages_free", "KV pages on the free list")
@@ -555,6 +572,11 @@ class ServingEngine:
                 "ptpu_serving_kv_bytes",
                 "total device bytes of the paged KV pool (+scales)")
             self._m_kv_bytes.set(self.cache.kv_bytes())
+            # what one page holds over every K/V layer (K, V, scales):
+            # with live positions, the bytes a decode step's attention
+            # has to read, whatever dtype the pool is in
+            self._page_bytes = (self.cache.kv_bytes()
+                                // self.cache.num_pages)
             self._m_prefix_hit = reg.counter(
                 "ptpu_serving_prefix_hit_tokens_total",
                 "prompt tokens served from shared prefix pages")
@@ -598,26 +620,18 @@ class ServingEngine:
                           "acc_len_hist": [0] * (self.spec_k + 1)}
 
     def _new_cache(self):
-        """Fresh pool of what the model caches (init + recover): K/V
-        pages, or a state row a slot. On a mesh engine the pages are
-        committed SHARDED (kv_heads over the `model` axis) to the
-        DECODE group, which owns all pool state — disaggregated
-        prefills hand their KV over."""
+        """Fresh cache manager (init + recover): K/V pages for the
+        model's K/V layers, a state row a slot for its state layers. On
+        a mesh engine the pages are committed SHARDED (kv_heads over
+        the `model` axis) to the DECODE group, which owns all pool
+        state — disaggregated prefills hand their KV over."""
         ad = self.adapter
-        if ad.stateful:
-            if len(ad.spec.state) != 2:
-                raise NotImplementedError(
-                    "the slot-row programs carry two arrays a layer "
-                    f"(K and V, or a state and its normaliser), not "
-                    f"{len(ad.spec.state)}")
-            return SlotStateCache(ad.num_layers, self.max_slots,
-                                  ad.spec.state)
         kv_sh = sc_sh = None
         if self.meshctx is not None:
             kv_sh = self.meshctx.kv_sharding()
             sc_sh = self.meshctx.scale_sharding()
-        return PagedKVCache(
-            ad.num_layers, self.max_slots, self.max_len,
+        return SlotCache(
+            ad.spec.layers, ad.spec.state, self.max_slots, self.max_len,
             ad.kv_heads, ad.head_dim, ad.dtype,
             page_size=self.page_size, num_pages=self.num_pages,
             quant=self.kv_quant,
@@ -698,6 +712,7 @@ class ServingEngine:
             sp.set_attr("pages_in_use", in_use)
             sp.set_attr("pages_reserved", c.committed_pages)
             sp.set_attr("pages_total", c.num_pages - 1)
+            sp.set_attr("page_bytes", self._page_bytes)
             sp.set_attr("prefix_hit_tokens", c.prefix_hit_tokens
                         - last["prefix_hit_tokens"])
             sp.set_attr("prefix_lookup_tokens", c.prefix_lookup_tokens
@@ -1123,7 +1138,7 @@ class ServingEngine:
                 self.peak_active_slots = max(self.peak_active_slots,
                                              len(active))
                 self._publish_page_stats(sp)
-            else:
+            if self.stateful:
                 in_use = len(self.cache.active_slots())
                 self._m_state_slots.set(in_use)
                 if sp is not None:
@@ -1171,28 +1186,28 @@ class ServingEngine:
                 maybe_fail("serving.decode.sharded",
                            step=self._step_idx - 1, tp=self.meshctx.tp)
             with span("serving.decode.enqueue", batch=len(active)):
-                if self.paged:
-                    logits, best, ks, vs, kss, vss = self._decode_fn()(
+                c = self.cache
+                logits, best, counted, ks, vs, kss, vss, *pools = \
+                    self._decode_fn()(
                         self._params, self._buffers, toks, pos, mask,
-                        self.cache.page_table.copy(),
-                        self.cache.ks, self.cache.vs,
-                        self.cache.kss, self.cache.vss)
-                    self.cache.ks, self.cache.vs = list(ks), list(vs)
-                    self.cache.kss, self.cache.vss = \
-                        list(kss), list(vss)
-                else:
-                    logits, best, *pools = self._decode_fn()(
-                        self._params, self._buffers, toks, pos, mask,
-                        *self.cache.pools)
-                    self.cache.pools = pools
+                        c.page_table.copy(), c.ks, c.vs, c.kss, c.vss,
+                        *c.pools)
+                c.ks, c.vs = list(ks), list(vs)
+                c.kss, c.vss = list(kss), list(vss)
+                c.pools = pools
             # the batch decides what the step fetches: all rows greedy,
             # the program's argmax ([slots] int32); one row that
             # samples, the logits, which its seeded host stream draws
             # from (a mixed batch takes the host path whole)
             on_device = all(self.cache.slots[s].sampling.temperature <= 0
                             for s in active)
-            fetched = self._fetch("serving.decode.fetch",
-                                  best if on_device else logits)
+            # what the model counted crosses with the tokens, in one
+            # fetch: a fetch of its own costs its fixed ~0.4 ms a step
+            fetched, counted = self._fetch(
+                "serving.decode.fetch", best if on_device else logits,
+                beside=counted)
+            if counted:
+                self._publish_counted(dsp, counted)
         n = len(active)
         with span("serving.sample", rows=n,
                   device_rows=n if on_device else 0,
@@ -1210,6 +1225,21 @@ class ServingEngine:
                 if self._is_finished(req, tok):
                     self._evict(s, req, finished)
             sp.set_attr("finished", len(finished) - n0)
+
+    def _publish_counted(self, dsp, counted: dict) -> None:
+        """What the model's decode program counted beside its logits
+        (``step_counters()``: small integer arrays by name), each summed
+        onto the ``serving.decode`` span as the attribute ``name`` and
+        into the registry counter ``ptpu_serving_<name>_total``."""
+        for name, value in counted.items():
+            n = int(np.sum(value))
+            dsp.set_attr(name, n)
+            if name not in self._m_counted:
+                self._m_counted[name] = self.registry.counter(
+                    f"ptpu_serving_{name}_total",
+                    f"{name}: what the model's decode program counted "
+                    "(step_counters()), summed over the decode steps")
+            self._m_counted[name].inc(n)
 
     def _decode_span(self, name: str, active, **attrs):
         """The batch span of one decode or verify step. It carries the
@@ -1981,12 +2011,8 @@ class ServingEngine:
                                   and req.out_tokens)) as sp:
                 padded = np.zeros((1, bucket), np.int64)
                 padded[0, :n] = ids
-                with self._state_reset(slot, sp):
-                    logits, *pools = self._prefill_fn()(
-                        self._params, self._buffers, padded,
-                        np.int32(n), np.int32(slot),
-                        *self.cache.pools)
-                    self.cache.pools = pools
+                logits = self._run_prefill(padded, n, slot, sp,
+                                           np.zeros((0,), np.int32))
                 return self._fetch("serving.prefill.fetch", logits)
         cache = self.cache
         disagg = self.meshctx is not None \
@@ -2032,7 +2058,7 @@ class ServingEngine:
                       bucket=bucket, prompt_tokens=n,
                       program="extend" if start else "prefill",
                       shared_prefix=start,
-                      replay=bool(req.out_tokens)):
+                      replay=bool(req.out_tokens)) as sp:
                 padded = np.zeros((1, bucket), np.int64)
                 padded[0, :tail] = ids[start:]
                 row = cache.page_table[slot]
@@ -2051,12 +2077,8 @@ class ServingEngine:
                 elif start == 0:
                     npages = (bucket + cache.page_size - 1) \
                         // cache.page_size
-                    logits, ks, vs, kss, vss = self._prefill_fn()(
-                        self._params, self._buffers, padded,
-                        np.int32(n), row[:npages].copy(),
-                        cache.ks, cache.vs, cache.kss, cache.vss)
-                    cache.ks, cache.vs = list(ks), list(vs)
-                    cache.kss, cache.vss = list(kss), list(vss)
+                    logits = self._run_prefill(padded, n, slot, sp,
+                                               row[:npages].copy())
                 else:
                     # prefix-hit EXTEND: stays on the decode group —
                     # it attends over shared pages already resident
@@ -2079,8 +2101,27 @@ class ServingEngine:
             cache.abort_sequence(slot, req)
             raise
 
+    def _run_prefill(self, padded, n: int, slot: int, prefill_span,
+                     page_ids):
+        """Dispatch the from-scratch prefill program of ``padded``'s
+        bucket: the bucket's K and V into the pages ``page_ids``, the
+        new state over the slot's row (its reset on reuse, under
+        ``serving.state.reset``). Returns the device logits."""
+        c = self.cache
+        with self._state_reset(slot, prefill_span) if self.stateful \
+                else contextlib.nullcontext():
+            logits, ks, vs, kss, vss, *pools = self._prefill_fn()(
+                self._params, self._buffers, padded, np.int32(n),
+                page_ids, np.int32(slot), c.ks, c.vs, c.kss, c.vss,
+                *c.pools)
+        c.ks, c.vs = list(ks), list(vs)
+        c.kss, c.vss = list(kss), list(vss)
+        c.pools = pools
+        return logits
+
     def _state_reset(self, slot: int, prefill_span):
-        """Around a stateful model's prefill dispatch: the slot's reset
+        """Around the prefill dispatch of a model with state layers: the
+        slot's reset
         on reuse and its new state's installation (the program builds
         the state from nothing and overwrites the slot's whole row):
         span ``serving.state.reset``. K and V in pages need none: the
@@ -2338,13 +2379,11 @@ class ServingEngine:
     @staticmethod
     def _unpack_paged(new_caches):
         d = lambda x: getattr(x, "_data", x)
-        ks2 = [d(c[0]) for c in new_caches]
-        vs2 = [d(c[1]) for c in new_caches]
-        kss2 = [d(c[2]) for c in new_caches] \
-            if new_caches[0][2] is not None else []
-        vss2 = [d(c[3]) for c in new_caches] \
-            if new_caches[0][3] is not None else []
-        return ks2, vs2, kss2, vss2
+        quant = bool(new_caches) and new_caches[0][2] is not None
+        return ([d(c[0]) for c in new_caches],
+                [d(c[1]) for c in new_caches],
+                [d(c[2]) for c in new_caches] if quant else [],
+                [d(c[3]) for c in new_caches] if quant else [])
 
     def _count_trace(self, kind: str, key=None) -> None:
         """Called in the body of every engine program, so it runs only
@@ -2369,13 +2408,19 @@ class ServingEngine:
         return Watched(jax.jit(no_grad()(fn), **jit_kw), self.registry,
                        sink=self._compiles)
 
-    def _fetch(self, name: str, x) -> np.ndarray:
+    def _fetch(self, name: str, x, beside=None):
         """``device_get`` under its own span: the host WAITS for the
-        device here, so this time is not idle."""
+        device here, so this time is not idle. ``beside``: a pytree of
+        small arrays fetched in the same call (returned after ``x``);
+        the span's ``bytes`` are ``x``'s."""
         with span(name) as sp:
-            out = np.asarray(jax.device_get(x))
+            if beside is None:
+                out = np.asarray(jax.device_get(x))
+            else:
+                out, beside = jax.device_get((x, beside))
+                out = np.asarray(out)
             sp.set_attr("bytes", out.nbytes)
-        return out
+        return out if beside is None else (out, beside)
 
     def _prefill_fn(self):
         """Full-prompt prefill program, one compile per bucket length:
@@ -2409,25 +2454,8 @@ class ServingEngine:
                 logits = ad.head(Tensor(h_last))._data[0, -1]
             return logits, new_caches
 
-        if not self.paged:
-            def ptpu_prefill(params, buffers, ids, true_len, slot, ks, vs):
-                # ks/vs: the slot-row pools (a stateful model's two
-                # state arrays): the slot's whole row is overwritten,
-                # which is a state's reset on reuse
-                logits, new_caches = local_run(params, buffers, ids,
-                                               true_len)
-                splice = lambda pool, c: jax.lax.dynamic_update_slice(
-                    pool, getattr(c, "_data", c).astype(pool.dtype),
-                    (slot,) + (0,) * (pool.ndim - 1))
-                ks = [splice(p, c[0]) for p, c in zip(ks, new_caches)]
-                vs = [splice(p, c[1]) for p, c in zip(vs, new_caches)]
-                return logits, ks, vs
-
-            self._prefill_jit = self._jit(ptpu_prefill,
-                                        donate_argnums=self._donate())
-            return self._prefill_jit
-
         from ..models._decode_cache import quantize_kv_page
+        by_kind = ad.by_kind
         P = self.cache.page_size
         quant = self.kv_quant
         disagg = self.meshctx is not None \
@@ -2445,6 +2473,7 @@ class ServingEngine:
             def ptpu_prefill(params, buffers, ids, true_len):
                 logits, new_caches = local_run(params, buffers, ids,
                                                true_len)
+                new_caches, _ = by_kind(new_caches)
                 npg = (ids.shape[1] + P - 1) // P
                 paginate = paginate_fn(npg, npg * P - ids.shape[1])
                 kb, vb, ksb, vsb = [], [], [], []
@@ -2470,10 +2499,19 @@ class ServingEngine:
                 out_shardings=(R, kv, kv, sc, sc))
             return self._prefill_jit
 
-        def ptpu_prefill(params, buffers, ids, true_len, page_ids, ks, vs,
-                 kss, vss):
+        def ptpu_prefill(params, buffers, ids, true_len, page_ids, slot,
+                         ks, vs, kss, vss, *pools):
+            # pools: the state rows, a list a state array: the slot's
+            # whole row is overwritten, which is a state's reset on
+            # reuse
             logits, new_caches = local_run(params, buffers, ids,
                                            true_len)
+            new_caches, new_state = by_kind(new_caches)
+            splice = lambda pool, c: jax.lax.dynamic_update_slice(
+                pool, getattr(c, "_data", c).astype(pool.dtype),
+                (slot,) + (0,) * (pool.ndim - 1))
+            pools = tuple([splice(p, c[a]) for p, c in zip(pool, new_state)]
+                          for a, pool in enumerate(pools))
             npg = page_ids.shape[0]
             paginate = paginate_fn(npg, npg * P - ids.shape[1])
 
@@ -2491,17 +2529,17 @@ class ServingEngine:
                         kpg.astype(ks[i].dtype))
                     vs[i] = vs[i].at[page_ids].set(
                         vpg.astype(vs[i].dtype))
-            return logits, ks, vs, kss, vss
+            return (logits, ks, vs, kss, vss) + pools
 
         jit_kw = {}
         if self.meshctx is not None:
             psh, bsh, R, kv, sc = self._prog_shardings()
             jit_kw = dict(
-                in_shardings=(psh, bsh, R, R, R, kv, kv, sc, sc),
+                in_shardings=(psh, bsh, R, R, R, R, kv, kv, sc, sc),
                 out_shardings=(R, kv, kv, sc, sc))
         self._prefill_jit = self._jit(
-            ptpu_prefill, donate_argnums=self._donate_idx(5, 6, 7, 8),
-            **jit_kw)
+            ptpu_prefill, donate_argnums=self._donate_idx(
+                *range(6, 10 + len(self.cache.pools))), **jit_kw)
         return self._prefill_jit
 
     def _extend_fn(self):
@@ -2867,8 +2905,9 @@ class ServingEngine:
         int32: a greedy step fetches that and leaves the logits on the
         device (``_decode_plain``).
         K and V flow through the page tables (inactive rows pinned to
-        the trash page); a stateful model's step reads and rewrites
-        its slot rows, active slots only.
+        the trash page); state layers read and rewrite their slot rows,
+        active slots only; what the model counted in the step
+        (``step_counters()``: a dict, empty for most) rides along.
 
         Mesh flavor: the SAME program jitted under the decode group's
         mesh with explicit in/out shardings — params by the family's
@@ -2880,50 +2919,38 @@ class ServingEngine:
             return self._decode_jit
         ad = self.adapter
         greedy = lambda logits: jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        by_layer, by_kind = ad.by_layer, ad.by_kind
+        jit_kw = {}
+        if self.meshctx is not None:
+            psh, bsh, R, kv, sc = self._prog_shardings()
+            jit_kw = dict(
+                in_shardings=(psh, bsh, R, R, R, R, kv, kv, sc, sc),
+                out_shardings=(R, R, {}, kv, kv, sc, sc))
 
-        if self.paged:
-            jit_kw = {}
-            if self.meshctx is not None:
-                psh, bsh, R, kv, sc = self._prog_shardings()
-                jit_kw = dict(
-                    in_shardings=(psh, bsh, R, R, R, R, kv, kv, sc, sc),
-                    out_shardings=(R, R, kv, kv, sc, sc))
-
-            def ptpu_decode(params, buffers, toks, pos, active, tables, ks,
-                     vs, kss, vss):
-                self._count_trace("decode")
-                pos_eff = jnp.where(active, pos, 0).astype(jnp.int32)
-                tab_eff = jnp.where(active[:, None], tables, 0)
-                caches = self._paged_caches(ks, vs, kss, vss,
-                                            tab_eff, pos_eff)
-                with ad.model.bind_state(params, buffers):
-                    h, new_caches = ad.call(Tensor(toks), caches)
-                    logits = ad.head(h[:, -1:])._data[:, -1]
-                self.decode_attend = noted_fact("attend")
-                logits = jnp.where(active[:, None], logits, 0.0)
-                return (logits, greedy(logits)) \
-                    + self._unpack_paged(new_caches)
-
-            self._decode_jit = self._jit(
-                ptpu_decode, donate_argnums=self._donate_idx(6, 7, 8, 9),
-                **jit_kw)
-            return self._decode_jit
-
-        def ptpu_decode(params, buffers, toks, pos, active, ks, vs):
+        def ptpu_decode(params, buffers, toks, pos, active, tables, ks,
+                        vs, kss, vss, *pools):
             self._count_trace("decode")
             pos_eff = jnp.where(active, pos, 0).astype(jnp.int32)
-            caches = ad.decode_caches((ks, vs), pos_eff, active)
+            tab_eff = jnp.where(active[:, None], tables, 0)
+            caches = by_layer(
+                self._paged_caches(ks, vs, kss, vss, tab_eff, pos_eff),
+                [row + (pos_eff, active) for row in zip(*pools)])
             with ad.model.bind_state(params, buffers):
                 h, new_caches = ad.call(Tensor(toks), caches)
                 logits = ad.head(h[:, -1:])._data[:, -1]
+                counted = ad.counters()
             self.decode_attend = noted_fact("attend")
             logits = jnp.where(active[:, None], logits, 0.0)
-            ks2 = [getattr(c[0], "_data", c[0]) for c in new_caches]
-            vs2 = [getattr(c[1], "_data", c[1]) for c in new_caches]
-            return logits, greedy(logits), ks2, vs2
+            new_caches, new_state = by_kind(new_caches)
+            d = lambda x: getattr(x, "_data", x)
+            return (logits, greedy(logits), counted) \
+                + self._unpack_paged(new_caches) \
+                + tuple([d(c[a]) for c in new_state]
+                        for a in range(len(pools)))
 
         self._decode_jit = self._jit(
-            ptpu_decode, donate_argnums=self._donate())
+            ptpu_decode, donate_argnums=self._donate_idx(
+                *range(6, 10 + len(self.cache.pools))), **jit_kw)
         return self._decode_jit
 
     def _verify_fn(self):
@@ -2994,12 +3021,11 @@ class ServingEngine:
 
     @staticmethod
     def _donate():
-        """Donation enable flag + the slot-row (state) programs' pool
-        argument indices (args 5/6): non-empty means the jit update is
-        in-place on device. CPU ignores donation and warns, so skip
-        it there. Paged programs derive their own indices from this
-        flag via ``_donate_idx`` (tests monkeypatch ``_donate`` to
-        simulate the TPU donated-pool failure mode)."""
+        """Donation enable flag: non-empty means the jit update of the
+        pools is in-place on device. CPU ignores donation and warns, so
+        skip it there. The programs derive their own argument indices
+        from this flag via ``_donate_idx`` (tests monkeypatch
+        ``_donate`` to simulate the TPU donated-pool failure mode)."""
         return () if jax.default_backend() == "cpu" else (5, 6)
 
     def _donate_idx(self, *idx):

@@ -1,6 +1,10 @@
-"""Cache pools for the serving engine: the block-paged K/V pool, and the
-recurrent-state pool of models that keep no K/V (``SlotStateCache``,
-at the end of the module docstring).
+"""The serving engine's cache manager: ``SlotCache`` holds, for the slots
+of one engine, what each LAYER of the model keeps (its ``cache_spec()``:
+``models/_decode_cache.CacheSpec.layers``): K and V by position in a
+block-paged pool (``PagedKVCache``, below) for the ``"kv"`` layers, and
+fixed-size state arrays, a row a slot, for the ``"state"`` layers, both
+behind one slot table. A softmax decoder has only the first, an
+attention-free model only the second, a hybrid both.
 
 ``PagedKVCache`` holds K and V in a pool of fixed-size PAGES
 (``[num_pages, page_size, kv_heads, head_dim]`` per layer) behind a
@@ -39,14 +43,17 @@ never corrupt live data. Rows are never cleared on the device — the
 per-slot causal mask (``kpos <= qpos``) keeps any stale tail beyond
 the current length invisible, so recycling costs zero device work.
 
-``SlotStateCache`` holds, a layer, the fixed-size arrays a model's
-``cache_spec()`` names (power retention: ``S [max_slots, kv_heads, P,
-D]`` and ``z [max_slots, kv_heads, P]``, float32), a row a slot whatever
-the request's length: admission is by free slots alone. A state has no
-mask to hide a stale tail behind, so a slot is RESET on reuse: the
-prefill program builds the new request's state from nothing and
-overwrites the slot's whole row (``reset`` is the host's side of it), and
-the decode program rewrites active slots only.
+The state rows (``SlotCache.pools``): a state layer's arrays as the
+spec names them (power retention: ``S [max_slots, kv_heads, P, D]`` and
+``z [max_slots, kv_heads, P]``; KDA: ``S [max_slots, heads, D, D]`` and
+the convolution's last inputs), a row a slot whatever the request's
+length. A state has no mask to hide a stale tail behind, so a slot is
+RESET on reuse: the prefill program builds the new request's state from
+nothing and overwrites the slot's whole row (``reset`` is the host's
+side of it), and the decode program rewrites active slots only. A slot
+released gives its pages back at once; its state rows wait for the next
+prefill. With no K/V layer the page pool is empty and admission is by
+free slots alone.
 """
 from __future__ import annotations
 
@@ -56,13 +63,13 @@ from typing import Dict, List, Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["SlotStateCache", "PagedKVCache"]
+__all__ = ["SlotCache", "PagedKVCache"]
 
 
 def _validate_geometry(num_layers: int, max_slots: int, max_len: int,
                        kv_heads: int, head_dim: int) -> None:
-    if num_layers < 1:
-        raise ValueError(f"num_layers must be >= 1, got {num_layers}")
+    if num_layers < 0:
+        raise ValueError(f"num_layers must be >= 0, got {num_layers}")
     if max_slots < 1:
         raise ValueError(f"max_slots must be >= 1, got {max_slots}")
     if max_len < 1:
@@ -74,8 +81,8 @@ def _validate_geometry(num_layers: int, max_slots: int, max_len: int,
 
 
 class _SlotTable:
-    """Slot lease bookkeeping shared by both pools: incremental
-    free/active sets instead of per-call O(max_slots) scans."""
+    """Slot lease bookkeeping: incremental free/active sets instead of
+    per-call O(max_slots) scans."""
 
     def __init__(self, max_slots: int):
         self.max_slots = max_slots
@@ -116,53 +123,6 @@ def _place_pools(pools, sharding):
         return pools
     import jax
     return [jax.device_put(p, sharding) for p in pools]
-
-
-class SlotStateCache(_SlotTable):
-    """Per-layer fixed-size state arrays, a row a slot, plus the slot
-    lease table. ``state`` is ``CacheSpec.state``: ``(name, shape a
-    slot, dtype)`` for each array of a layer."""
-
-    def __init__(self, num_layers: int, max_slots: int, state):
-        if num_layers < 1:
-            raise ValueError(f"num_layers must be >= 1, got {num_layers}")
-        if max_slots < 1:
-            raise ValueError(f"max_slots must be >= 1, got {max_slots}")
-        if not state:
-            raise ValueError("a state cache needs at least one array")
-        super().__init__(max_slots)
-        self.pools = [[jnp.zeros((max_slots,) + tuple(shape), dtype)
-                       for _ in range(num_layers)]
-                      for _, shape, dtype in state]
-        # bytes one slot holds over all layers; what a reset rebuilds
-        self.slot_bytes = self.state_bytes() // max_slots
-        self.resets = 0
-
-    @property
-    def pools(self):
-        """The per-layer device arrays a slot-row program reads and
-        returns, in argument order."""
-        return self._pools
-
-    @pools.setter
-    def pools(self, new) -> None:
-        self._pools = tuple(list(p) for p in new)
-
-    def reset(self, slot: int) -> None:
-        """The host's side of a slot's reset on reuse (admission, or
-        ``recover()``'s re-prefill of a slot it has leased again): the
-        prefill program about to run overwrites the slot's whole row
-        with a state built from nothing."""
-        if not 0 <= slot < self.max_slots:
-            raise IndexError(f"no slot {slot}")
-        self.resets += 1
-
-    def state_bytes(self) -> int:
-        """Total device bytes of the state pools."""
-        return sum(a.size * a.dtype.itemsize
-                   for p in self._pools for a in p)
-
-    kv_bytes = state_bytes
 
 
 class _PrefixNode:
@@ -1019,3 +979,57 @@ class PagedKVCache(_SlotTable):
             "prefix_hit_tokens_host": self.prefix_hit_tokens_host,
             "prefix_hit_tokens_disk": self.prefix_hit_tokens_disk,
         }
+
+
+class SlotCache(PagedKVCache):
+    """The engine's one cache manager (module docstring): K/V pages over
+    the model's ``"kv"`` layers and state rows over its ``"state"``
+    layers, one slot table. ``layers`` and ``state`` are
+    ``CacheSpec``'s; the other arguments ``PagedKVCache``'s, whose page
+    pool is empty where no layer keeps K and V."""
+
+    def __init__(self, layers, state, max_slots: int, max_len: int,
+                 kv_heads: int, head_dim: int, dtype, **paged):
+        self.layers = tuple(layers)
+        self.kv_layers = self.layers.count("kv")
+        self.state_layers = self.layers.count("state")
+        if self.kv_layers + self.state_layers != len(self.layers) \
+                or not self.layers:
+            raise ValueError(f"a layer keeps 'kv' or 'state', got "
+                             f"{self.layers}")
+        if bool(self.state_layers) != bool(state):
+            raise ValueError("state layers and their arrays go together")
+        super().__init__(self.kv_layers, max_slots, max_len, kv_heads,
+                         head_dim, dtype, **paged)
+        self.pools = [[jnp.zeros((max_slots,) + tuple(shape), dt)
+                       for _ in range(self.state_layers)]
+                      for _, shape, dt in state]
+        # bytes one slot's state holds over all layers; what a reset
+        # rebuilds
+        self.slot_bytes = self.state_bytes() // max_slots
+        self.resets = 0
+
+    @property
+    def pools(self):
+        """The state arrays, a list a name of one device array a state
+        layer: what a slot-row program reads and returns, in argument
+        order."""
+        return self._pools
+
+    @pools.setter
+    def pools(self, new) -> None:
+        self._pools = tuple(list(p) for p in new)
+
+    def reset(self, slot: int) -> None:
+        """The host's side of a slot's state reset on reuse (admission,
+        or ``recover()``'s re-prefill of a slot it has leased again):
+        the prefill program about to run overwrites the slot's whole
+        row with a state built from nothing."""
+        if not 0 <= slot < self.max_slots:
+            raise IndexError(f"no slot {slot}")
+        self.resets += 1
+
+    def state_bytes(self) -> int:
+        """Total device bytes of the state rows."""
+        return sum(a.size * a.dtype.itemsize
+                   for p in self._pools for a in p)

@@ -31,7 +31,7 @@ from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny_config
 from paddle_tpu.observability import (MetricRegistry, TraceBuffer,
                                       install_trace_buffer, tracing)
 from paddle_tpu.serving import (FrontDoor, ReplicaRouter, ServingEngine,
-                                SlotStateCache, StateCacheUnsupported)
+                                SlotCache, StateCacheUnsupported)
 
 SERVE_RTOL = 2e-5
 REF_RTOL = 1e-4
@@ -130,10 +130,12 @@ def test_forward_matches_the_plain_reference(model):
 
 def test_cache_spec_is_a_state_a_slot(model, eng):
     spec = model.cache_spec()
-    assert spec.kind == "state" and spec.num_layers == 2
+    assert spec.layers == ("state", "state") and spec.num_layers == 2
     assert spec.state == (("S", (2, 36, 8), jnp.float32),
                           ("z", (2, 36), jnp.float32))
-    assert isinstance(eng.cache, SlotStateCache)
+    assert isinstance(eng.cache, SlotCache)
+    assert (eng.cache.kv_layers, eng.cache.state_layers) == (0, 2)
+    assert eng.cache.kv_bytes() == 0
     assert not eng.paged and not eng.prefix_sharing
     assert [a.shape for a in eng.cache.pools[0]] == [(3, 2, 36, 8)] * 2
     assert [a.shape for a in eng.cache.pools[1]] == [(3, 2, 36)] * 2
@@ -146,14 +148,14 @@ def test_kv_models_state_their_cache_too():
     paddle.seed(0)
     llama = LlamaForCausalLM(llama_tiny_config(num_key_value_heads=2))
     spec = llama.cache_spec()
-    assert (spec.kind, spec.num_layers, spec.kv_heads, spec.head_dim,
-            spec.max_positions, spec.state) == ("kv", 2, 2, 16, 64, ())
+    assert (spec.layers, spec.num_layers, spec.kv_heads, spec.head_dim,
+            spec.max_positions, spec.state) == (("kv", "kv"), 2, 2, 16, 64, ())
     gpt = GPTForCausalLM(GPTConfig(vocab_size=64, hidden_size=32,
                                    num_layers=1, num_heads=4,
                                    max_seq_len=32))
     spec = gpt.cache_spec()
-    assert (spec.kind, spec.kv_heads, spec.head_dim,
-            spec.max_positions) == ("kv", 4, 8, 32)
+    assert (spec.layers, spec.kv_heads, spec.head_dim,
+            spec.max_positions) == (("kv",), 4, 8, 32)
     with pytest.raises(ValueError, match="recurrent state"):
         ServingEngine(llama, kv_layout="state")
     with pytest.raises(TypeError, match="cache_spec"):

@@ -255,6 +255,37 @@ def test_paged_decode_kernel_compiles_for_v5e(one_chip, monkeypatch):
     assert mem.temp_size_in_bytes < pool_bytes // 100
 
 
+# -- the live-pages decode kernel for a float32 caller, Solar-Open2's shapes --
+# 96 pages of 128 a slot, 8 KV heads of 128 under 64 query heads: 64 slots on
+# bfloat16 pages (the query in three parts, the probabilities in two), 48 on
+# float32 pages (a float32 model)
+
+@pytest.mark.parametrize("B,pages", [(64, BF16), (48, F32)])
+def test_paged_decode_kernel_compiles_for_v5e_for_a_float32_caller(
+        B, pages, one_chip, monkeypatch):
+    from paddle_tpu.models._decode_cache import paged_cache_attend
+    from paddle_tpu.ops import pallas_ops
+    monkeypatch.setattr(pallas_ops, "_interpret", lambda: False)
+    monkeypatch.setattr(pallas_ops, "single_device_tpu", lambda: True)
+    H, KV, D, page, per_seq = 64, 8, 128, 128, 96
+    pool = one_chip((B * per_seq + 1, page, KV, D), pages)
+
+    def step(q, k, v, kp, vp, table, pos):
+        out, kp, vp, _, _ = paged_cache_attend(
+            q, k, v, kp, vp, None, None, table, pos, F32)
+        return out, kp, vp
+
+    compiled = jax.jit(step, donate_argnums=(3, 4)).lower(
+        one_chip((B, 1, H, D), F32), one_chip((B, 1, KV, D), F32),
+        one_chip((B, 1, KV, D), F32), pool, pool,
+        one_chip((B, per_seq), I32), one_chip((B,), I32)).compile()
+    assert "paged_decode_attention" in compiled.as_text()
+    pool_bytes = int(np.prod(pool.shape)) * jnp.dtype(pages).itemsize
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes // 100
+
+
 # -- the retention decode kernel at Brumby-14B's shapes ---------------------
 # 16 slots x 8 KV heads of [8256, 128] float32 state: a whole state block
 # a grid step in VMEM (in and out, double-buffered), rewritten in place
@@ -277,6 +308,52 @@ def test_retention_decode_kernel_compiles_for_v5e(one_chip):
     state = B * KV * P * D * 4
     assert mem.alias_size_in_bytes >= state
     assert mem.temp_size_in_bytes < state // 100
+
+
+# -- the KDA decode kernel at Solar-Open2's shapes --------------------------
+# 64 slots x 64 heads of [128, 128] float32 state, 16 heads a grid step in
+# VMEM (in and out, double-buffered), rewritten in place
+
+def test_kda_decode_kernel_compiles_for_v5e(one_chip):
+    from paddle_tpu.ops import kda
+    B, H, D = 64, 64, 128
+    vec = one_chip((B, H, D), F32)
+    compiled = jax.jit(
+        lambda a, k, q, v, b, S, on: kda._decode_pallas(
+            a, k, q, v, b, S, on, False), donate_argnums=5).lower(
+            vec, vec, vec, vec, one_chip((B, H), F32),
+            one_chip((B, H, D, D), F32),
+            one_chip((B,), jnp.bool_)).compile()
+    txt = compiled.as_text()
+    assert txt.count('custom_call_target="tpu_custom_call"') == 1
+    assert "kda_decode" in txt
+    mem = compiled.memory_analysis()
+    state = B * H * D * D * 4
+    assert mem.alias_size_in_bytes >= state
+    assert mem.temp_size_in_bytes < state // 10
+
+
+# -- the grouped expert product at Solar-Open2's shapes ---------------------
+# 40 experts held of [4096, 1280] (gate, up) and [1280, 4096] (down) bf16;
+# a decode step's 64 x 8 assignments and a prefill's 2048 x 8, in row tiles
+# of 128, float32 rows as two bfloat16 pieces
+
+@pytest.mark.parametrize("M,K,N,name", [
+    (512, 4096, 1280, "expert_gmm_decode"),
+    (512, 1280, 4096, "expert_gmm_decode"),
+    (16384, 4096, 1280, "expert_gmm_prefill"),
+    (16384, 1280, 4096, "expert_gmm_prefill")])
+def test_grouped_matmul_compiles_for_v5e(M, K, N, name, one_chip,
+                                         monkeypatch):
+    from paddle_tpu.ops import grouped_matmul as gm
+    from paddle_tpu.ops import pallas_ops
+    monkeypatch.setattr(pallas_ops, "_interpret", lambda: False)
+    txt = jax.jit(lambda x, w, n: gm.grouped_matmul(
+        x, w, n, name=name, kernel=True)).lower(
+            one_chip((M, K), F32), one_chip((40, K, N)),
+            one_chip((40,), I32)).compile().as_text()
+    assert txt.count('custom_call_target="tpu_custom_call"') == 1
+    assert name in txt
 
 
 # -- P11: gradient collectives in the compiled DP / FSDP step -------------

@@ -171,3 +171,125 @@ def test_the_brumby_configuration_is_the_catalog_row_cut_in_depth():
         == {"serve_tokens_per_s", "itl_p95_ms", "setup_s"}
     assert "decode_hbm_roofline.serve" not in \
         {m["name"] for m in cell["per_layer"]}
+
+
+SOLAR_CATALOG = {
+    "model_type": "solar_open2", "partial_rotary_factor": 1,
+    "linear_attn_config": {"short_conv_kernel_size": 4, "head_dim": 128,
+                           "num_heads": 64, "num_kv_heads": None},
+    "hidden_size": 4096, "num_hidden_layers": 48,
+    "num_attention_heads": 64, "head_dim": 128, "num_key_value_heads": 8,
+    "vocab_size": 196608, "intermediate_size": 10240,
+    "moe_intermediate_size": 1280, "rms_norm_eps": 1e-05,
+    "rope_theta": 10000, "tie_word_embeddings": False,
+    "max_position_embeddings": 1048576, "first_k_dense_replace": 0,
+    "use_rope": False, "gqa_interval": 3,
+    "gqa_layers": [0, 4, 8, 12, 16, 20, 24, 28, 32, 36, 40, 44],
+    "use_gqa_gate": True, "kda_use_full_proj": False,
+    "kda_allow_neg_eigval": True, "n_routed_experts": 320,
+    "n_shared_experts": 1, "norm_topk_prob": True,
+    "routed_scaling_factor": 1, "num_experts_per_tok": 8}
+
+
+def _solar_spec():
+    return manifest.load_json(os.path.join(
+        manifest.HERE, "configs", "solar-open2-250b.json"))
+
+
+def test_the_solar_configuration_is_the_catalog_row_cut_to_a_share():
+    spec = _solar_spec()
+    cut = dict(num_hidden_layers=4, n_routed_experts=40, vocab_size=24576)
+    assert spec["published"] == SOLAR_CATALOG
+    assert {k: spec[k] for k in SOLAR_CATALOG} == dict(SOLAR_CATALOG,
+                                                       **cut)
+    assert spec["model"] == dict(
+        SOLAR_CATALOG, **cut, experts_published=320, expert_parallel=8,
+        first_expert=0, vocab_published=196608)
+    assert spec["reduced"] == list(cut)
+    assert spec["chips"] == 1 and spec["mesh"] == {}
+    assert spec["dtype"] == "bfloat16"
+    assert spec["engine"] == {"max_slots": 64, "max_len": 12288,
+                              "page_size": 128, "prefix_sharing": False,
+                              "min_bucket": 4096}
+
+
+@pytest.mark.parametrize("key", [
+    "hidden_size", "head_dim", "num_attention_heads",
+    "num_key_value_heads", "moe_intermediate_size", "intermediate_size",
+    "num_experts_per_tok", "n_shared_experts", "linear_attn_config",
+    "max_position_embeddings", "rms_norm_eps", "gqa_layers"])
+def test_the_solar_share_keeps_every_published_width(key):
+    spec = _solar_spec()
+    assert spec["model"][key] == SOLAR_CATALOG[key] == spec[key]
+
+
+@pytest.mark.parametrize("floor", ["period", "experts", "vocabulary",
+                                   "router"])
+def test_the_solar_share_keeps_to_the_floors(floor):
+    m = _solar_spec()["model"]
+    if floor == "period":       # a whole period: GQA, KDA, KDA, KDA
+        kinds = ["gqa" if i in m["gqa_layers"] else "kda"
+                 for i in range(m["num_hidden_layers"])]
+        assert kinds == ["gqa", "kda", "kda", "kda"]
+        assert m["num_hidden_layers"] >= 4
+    elif floor == "experts":
+        assert m["n_routed_experts"] >= 8
+        assert m["n_routed_experts"] * m["expert_parallel"] \
+            == m["experts_published"] == 320
+        assert m["first_expert"] + m["n_routed_experts"] <= 320
+    elif floor == "vocabulary":
+        assert m["vocab_size"] * 8 == m["vocab_published"] == 196608
+    else:                       # the router keeps its published width
+        from paddle_tpu.models.solar import SolarOpen2Config
+        cfg = SolarOpen2Config.from_dict(m)
+        assert cfg.router_width == 320 and cfg.num_experts_per_tok == 8
+        assert cfg.linear_num_heads == 64 and cfg.kda_rank == 128
+
+
+@pytest.mark.parametrize("name", [
+    "kda_mixer", "qk_norm", "decay", "low_rank", "beta", "head_norm",
+    "gqa_gate", "gqa_qk_norm", "router", "shared_expert",
+    "intermediate_size", "state_dtype", "arithmetic", "experts_load",
+    "engine"])
+def test_every_size_the_solar_catalog_row_lacks_is_assumed_by_name(name):
+    assumed = _solar_spec()["assumed"]
+    assert isinstance(assumed[name], str) and len(assumed[name]) > 20
+
+
+def test_the_solar_cell_and_its_traffic():
+    spec = _solar_spec()
+    traffic = manifest.load_json(os.path.join(
+        manifest.HERE, "traffic", "doc-gen-saturated.json"))
+    assert traffic["clients"] == 128 == 2 * spec["engine"]["max_slots"]
+    assert traffic["prompt_tokens"] == {"log_uniform": [2048, 8192]}
+    assert traffic["output_tokens"] == {"log_uniform": [1024, 4096]}
+    assert "bos_token_id" not in traffic and traffic["block"] == 16
+    assert (traffic["ramp_seconds"], traffic["drain_seconds"],
+            traffic["verify_requests"], traffic["trace_seconds"]) \
+        == (10, 0, 8, 3)
+    # prompt + output of the longest pair fit a slot
+    assert 8192 + 4096 - 1 <= spec["engine"]["max_len"]
+    cell = manifest.cell(BENCH, "solar-open2-250b.doc-gen-saturated")
+    # a closed loop above capacity: tokens a second is its end-to-end
+    # metric. itl_p95_ms is left out: 64 slots admit on 4-5% of the
+    # steps, so the 95th percentile of the gaps lies at the edge between
+    # a plain step (19 ms) and a step behind a prefill (250 ms), and five
+    # seeds read 18.7 to 23.2 (PERF.md, PR 35); with it go the lists of
+    # the metrics that move it
+    assert {m["name"] for m in cell["end_to_end"]} \
+        == {"serve_tokens_per_s", "setup_s"}
+    names = {m["name"] for m in cell["per_layer"]}
+    assert {"decode_hbm_roofline.solar", "kda_decode_roofline.solar",
+            "expert_gmm_roofline.solar", "experts_hit_pct.solar",
+            "prefill_ms_per_call.solar", "batch_occupancy_pct.saturated",
+            "device_idle_pct.saturated"} <= names
+    assert all(m["moves"] in ("serve_tokens_per_s", "setup_s")
+               for m in BENCH["per_layer"] if m["name"] in names)
+    assert not {"decode_hbm_roofline.serve", "decode_hbm_roofline.brumby",
+                "host_state_reset_ms.brumby", "engine_step_ms.serve"} \
+        & names
+    entry = next(c for c in BENCH["configs"]
+                 if c["name"] == "solar-open2-250b")
+    assert len(entry["why"]) == 191
+    assert len(next(w for w in BENCH["workloads"]
+                    if w["name"] == cell["workload"]["name"])["why"]) == 197

@@ -6,6 +6,7 @@ cancel and drain, int8-KV measured-parity gate, page-gated admission
 under an oversubscribed pool, and the compile-count contract (paging
 adds ZERO decode compiles)."""
 import numpy as np
+from types import SimpleNamespace
 import pytest
 
 import paddle_tpu as paddle
@@ -13,7 +14,7 @@ from conftest import model_greedy
 from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny_config
 from paddle_tpu.resilience.invariants import page_leak_violations
 from paddle_tpu.serving import (PagedKVCache, ServingEngine,
-                                SlotStateCache)
+                                SlotCache)
 
 
 def _tiny_llama(**kw):
@@ -53,7 +54,7 @@ def _quiesced_ok(eng):
 
 def test_cache_geometry_validation():
     import jax.numpy as jnp
-    for bad in [dict(num_layers=0), dict(max_slots=0),
+    for bad in [dict(num_layers=-1), dict(max_slots=0),
                 dict(max_len=0), dict(kv_heads=0), dict(head_dim=0)]:
         kw = dict(num_layers=2, max_slots=2, max_len=16, kv_heads=2,
                   head_dim=4)
@@ -70,9 +71,13 @@ def test_cache_geometry_validation():
         PagedKVCache(1, 2, 16, 2, 4, jnp.float32, page_size=8,
                      num_pages=2)
     state = (("S", (2, 4), jnp.float32),)
-    for bad in [(0, 2, state), (2, 0, state), (2, 2, ())]:
+    rows = lambda layers, slots, state: SlotCache(
+        layers, state, slots, 16, 2, 4, jnp.float32, page_size=8)
+    for bad in [((), 2, state), (("state",), 0, state),
+                (("state",), 2, ()), (("kv",), 2, state),
+                (("state", "window"), 2, state)]:
         with pytest.raises(ValueError):
-            SlotStateCache(*bad)
+            rows(*bad)
 
 
 def test_slot_bookkeeping_is_maintained_not_scanned():
@@ -80,7 +85,8 @@ def test_slot_bookkeeping_is_maintained_not_scanned():
     arbitrary assign/release interleaving, and release returns slots
     in O(1) (no O(max_slots) list scans on the per-step path)."""
     import jax.numpy as jnp
-    c = SlotStateCache(1, 5, (("S", (2, 4), jnp.float32),))
+    c = SlotCache(("state",), (("S", (2, 4), jnp.float32),), 5, 16, 2,
+                  4, jnp.float32, page_size=16)
     rng = np.random.RandomState(0)
     held = set()
     for _ in range(200):
@@ -93,7 +99,7 @@ def test_slot_bookkeeping_is_maintained_not_scanned():
             held.discard(int(s))
         elif len(held) < 5:
             s = rng.choice(sorted(set(range(5)) - held))
-            c.assign(int(s), "r")
+            c.assign(int(s), SimpleNamespace(rid=int(s)))
             held.add(int(s))
     for s in range(5):                  # misuse stays loud
         if s in held:

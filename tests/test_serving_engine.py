@@ -9,7 +9,7 @@ import paddle_tpu as paddle
 from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny_config
 from paddle_tpu.serving import (FIFOScheduler, PagedKVCache, Request,
                                 SamplingParams, ServingEngine,
-                                SlotStateCache, bucket_for,
+                                SlotCache, bucket_for,
                                 prefill_buckets, sample_token)
 
 
@@ -55,13 +55,15 @@ def test_bucket_policy():
 
 def test_slot_cache_lease_cycle():
     import jax.numpy as jnp
-    c = SlotStateCache(2, 3, (("S", (2, 4, 4), jnp.float32),
-                              ("z", (2, 4), jnp.float32)))
+    c = SlotCache(("state", "state"), (("S", (2, 4, 4), jnp.float32),
+                                       ("z", (2, 4), jnp.float32)),
+                  3, 16, 2, 4, jnp.float32, page_size=16)
     assert c.free_slots() == [0, 1, 2] and c.occupancy == 0.0
-    c.assign(1, "req")
+    from types import SimpleNamespace
+    c.assign(1, SimpleNamespace(rid=7))
     assert c.free_slots() == [0, 2] and c.active_slots() == [1]
     with pytest.raises(RuntimeError):
-        c.assign(1, "other")
+        c.assign(1, SimpleNamespace(rid=8))
     c.release(1)
     with pytest.raises(RuntimeError):
         c.release(1)
