@@ -136,6 +136,21 @@ class _ModelAdapter:
         return pick("kv"), pick("state")
 
 
+class _Step:
+    """One enqueued k=1 decode step: the (slot, request) rows it runs,
+    its outputs (on the device until the step is read), and what its
+    ``serving.decode`` span says of it."""
+    __slots__ = ("rows", "logits", "best", "counted", "on_device",
+                 "live_pages", "ahead", "discarded")
+
+    def __init__(self, rows, logits, best, counted, on_device: bool,
+                 live_pages: int, ahead: bool):
+        self.rows, self.logits, self.best = rows, logits, best
+        self.counted, self.on_device = counted, on_device
+        self.live_pages, self.ahead = live_pages, ahead
+        self.discarded = 0
+
+
 class ServingEngine:
     """Slot-based continuous-batching engine (see module docstring)."""
 
@@ -466,6 +481,14 @@ class ServingEngine:
         self._chunk_jit = None
         self._chunk_local_jit = None
         self._chunk_fin_jit = None
+        self._tokens_jit = None
+        # where a decode step's tokens lie: replicated over a mesh, as
+        # the decode program takes them (_tokens_fn)
+        self._tokens_sharding = self.meshctx.repl("decode") \
+            if self.meshctx is not None else None
+        # the decode step enqueued one step ahead and not read yet
+        # (_decode_plain), or None: the engine is drained
+        self._ahead: Optional[_Step] = None
         self._next_rid = 0
         self._step_idx = 0
         # set when a step fails after donating the cache pools (device
@@ -503,7 +526,7 @@ class ServingEngine:
         self.trace_counts = {"decode": 0, "verify": 0, "draft": 0,
                              "prefill": {},
                              "extend": {}, "copy": 0, "install": {},
-                             "chunk": {}, "promote": 0}
+                             "chunk": {}, "promote": 0, "tokens": 0}
         # what the decode program's attention traced as, also on its
         # compile.decode span: "paged_kernel" (the live-pages Pallas
         # kernel) or "einsum"; None until it has traced
@@ -544,6 +567,16 @@ class ServingEngine:
             "tokens of plain decode steps, by where each was chosen: "
             "the decode program's argmax (device) or the fetched "
             "logits (host)", labels=("where",))
+        self._m_ahead = reg.counter(
+            "ptpu_serving_decode_ahead_total",
+            "plain decode steps enqueued one step ahead, fed the step "
+            "before's argmax on the device before it was read")
+        self._m_discarded = reg.counter(
+            "ptpu_serving_decode_discarded_rows_total",
+            "rows of a step enqueued ahead thrown away unread: the "
+            "request finished at the step before (or left its slot), or "
+            "sampled another token than the one fed ahead",
+            labels=("reason",))
         if self.prefill_chunk is not None:
             self._m_chunk_steps = reg.counter(
                 "ptpu_serving_chunk_steps_total",
@@ -951,6 +984,9 @@ class ServingEngine:
 
     def _on_step_error(self, step_idx: int, e: Exception,
                        finished: List[Request]) -> None:
+        # a step enqueued ahead is abandoned with the failed one: the
+        # next step (or recover()) starts again from the host's tokens
+        self._ahead = None
         if finished:
             self._undelivered.extend(finished)
         if self._donate():
@@ -1149,75 +1185,58 @@ class ServingEngine:
         return admitted, len(active)
 
     def _decode_plain(self, active, finished: List[Request]) -> None:
-        """The k=1 decode step (non-speculative engines)."""
-        with self._decode_span("serving.decode", active) as dsp:
-            with span("serving.decode.build") as sp:
-                toks = np.zeros((self.max_slots, 1), np.int64)
-                pos = np.zeros((self.max_slots,), np.int32)
-                mask = np.zeros((self.max_slots,), bool)
-                copies = []
-                for s in active:
-                    req = self.cache.slots[s]
-                    toks[s, 0] = req.out_tokens[-1]
-                    pos[s] = req.next_pos
-                    mask[s] = True
-                    if self.paged:
-                        # the write may cross into a new page
-                        # (allocate) or a shared one (COW) — resolve
-                        # BEFORE the step
-                        c = self.cache.ensure_decode_page(s, req.next_pos)
-                        if c is not None:
-                            copies.append(c)
-                # COW copies run BEFORE the fault point:
-                # ensure_decode_page already flipped the table rows,
-                # and a retried (non-broken) step would not re-issue a
-                # lost copy — device state must be consistent with the
-                # table when the fault can fire
-                if self.paged:
-                    self._run_copies(copies)
-                    # the pages a length-aware attention has to read
-                    dsp.set_attr("live_pages", int(
-                        (pos[mask] // self.cache.page_size + 1).sum()))
-                sp.set_attr("cow_copies", len(copies))
-            maybe_fail("serving.step.decode", step=self._step_idx - 1)
-            if self.meshctx is not None:
-                # mesh engines: the SHARDED decode program is about to
-                # run (chaos kill point for the tensor-parallel flavor)
-                maybe_fail("serving.decode.sharded",
-                           step=self._step_idx - 1, tp=self.meshctx.tp)
-            with span("serving.decode.enqueue", batch=len(active)):
-                c = self.cache
-                logits, best, counted, ks, vs, kss, vss, *pools = \
-                    self._decode_fn()(
-                        self._params, self._buffers, toks, pos, mask,
-                        c.page_table.copy(), c.ks, c.vs, c.kss, c.vss,
-                        *c.pools)
-                c.ks, c.vs = list(ks), list(vs)
-                c.kss, c.vss = list(kss), list(vss)
-                c.pools = pools
+        """The k=1 decode step (non-speculative engines), run one step
+        ahead where the engine can (docs/SERVING.md "One step ahead"):
+        the next step, fed this step's argmax where it lies on the
+        device, is enqueued before this step's tokens are read, so the
+        host samples, delivers and admits while the device runs it.
+        ``self._ahead`` is the step an earlier call enqueued so; a call
+        without one builds its step from the host's tokens, and a call
+        that enqueues none after it is a synchronous step."""
+        step, self._ahead = self._ahead, None
+        if step is not None:
+            # a request that left its slot since (a cancel, a deadline,
+            # a disconnect) finished: its row is thrown away unread
+            ran = step.rows
+            step.rows = [(s, r) for s, r in ran if self.cache.slots[s] is r]
+            self._discard(step, "finished", len(ran) - len(step.rows))
+            if not step.rows:
+                step = None
+        if step is None:
+            ran = [(s, self.cache.slots[s]) for s in active]
+        with self._decode_span("serving.decode",
+                               [r for _, r in ran]) as dsp:
+            if step is None:
+                step = self._enqueue_decode(ran)
+            rows = self._ahead_rows(step, active)
+            nxt = self._enqueue_decode(rows, step.best) if rows else None
+            dsp.set_attr("ahead", int(step.ahead))
+            dsp.set_attr("discarded", step.discarded)
+            if self.paged:
+                # the pages a length-aware attention has to read
+                dsp.set_attr("live_pages", step.live_pages)
             # the batch decides what the step fetches: all rows greedy,
             # the program's argmax ([slots] int32); one row that
             # samples, the logits, which its seeded host stream draws
-            # from (a mixed batch takes the host path whole)
-            on_device = all(self.cache.slots[s].sampling.temperature <= 0
-                            for s in active)
-            # what the model counted crosses with the tokens, in one
-            # fetch: a fetch of its own costs its fixed ~0.4 ms a step
+            # from (a mixed batch takes the host path whole). What the
+            # model counted crosses with the tokens, in one fetch: a
+            # fetch of its own costs its fixed ~0.4 ms a step
+            on_device = step.on_device
             fetched, counted = self._fetch(
-                "serving.decode.fetch", best if on_device else logits,
-                beside=counted)
+                "serving.decode.fetch",
+                step.best if on_device else step.logits,
+                beside=step.counted)
             if counted:
                 self._publish_counted(dsp, counted)
-        n = len(active)
+        n = len(step.rows)
         with span("serving.sample", rows=n,
                   device_rows=n if on_device else 0,
                   host_rows=0 if on_device else n) as sp:
             self._m_sampled.labels(
                 where="device" if on_device else "host").inc(n)
             n0 = len(finished)
-            for s in active:
-                req = self.cache.slots[s]
-                row = ArgmaxRow(logits, s, int(fetched[s])) \
+            for s, req in step.rows:
+                row = ArgmaxRow(step.logits, s, int(fetched[s])) \
                     if on_device else fetched[s]
                 tok = sample_token(row, req.sampling, req._rng)
                 req.out_tokens.append(tok)
@@ -1225,6 +1244,119 @@ class ServingEngine:
                 if self._is_finished(req, tok):
                     self._evict(s, req, finished)
             sp.set_attr("finished", len(finished) - n0)
+        if nxt is not None:
+            # the step ahead was fed the argmax: a row whose request
+            # ended at this step, or whose sampled token is another,
+            # is thrown away; the latter runs its position again from
+            # the sampled token, which overwrites the K and V written
+            keep = []
+            for s, req in nxt.rows:
+                if req.finished:
+                    self._discard(nxt, "finished")
+                elif req.out_tokens[-1] != int(fetched[s]):
+                    self._discard(nxt, "resampled")
+                else:
+                    keep.append((s, req))
+            nxt.rows = keep
+            self._ahead = nxt if keep else None
+
+    def _ahead_rows(self, step: _Step, active) -> list:
+        """The rows of a step to enqueue behind ``step`` before it is
+        read, or none: the engine then drains. A step runs ahead only
+        where its guess cannot outlive it: K/V pages alone (a page
+        written from a token that is then not the sampled one is
+        written again from the sampled one; a recurrent state folds it
+        in for good), no drafts, every row greedy and in the step, and
+        nothing waiting for the boundary between the two steps: an
+        admission into a slot free now or freed by length at this
+        step, a chunk, a cancel, a deadline. A row that ends by length
+        at this step is left out."""
+        if self.stateful or self.speculative or not step.on_device \
+                or len(step.rows) != len(active) or self._chunk_fifo:
+            return []
+        now = self.metrics.now()
+        rows, frees = [], False
+        for s, req in step.rows:
+            if req.cancel_requested or (req.deadline is not None
+                                        and now > req.deadline):
+                return []
+            if len(req.out_tokens) + 1 >= req.max_new_tokens:
+                frees = True
+            else:
+                rows.append((s, req))
+        if self.scheduler.has_pending() \
+                and (frees or self.cache.free_slots()):
+            return []
+        return rows
+
+    def _enqueue_decode(self, rows, fed=None) -> _Step:
+        """Build and enqueue one decode step over ``rows``, (slot,
+        request) pairs: each request's last token at its next position
+        or, with ``fed`` (the argmax of the step enqueued just before,
+        still on the device), that token one position further on."""
+        ahead = fed is not None
+        with span("serving.decode.build") as sp:
+            toks = np.zeros((self.max_slots,), np.int32)
+            pos = np.zeros((self.max_slots,), np.int32)
+            mask = np.zeros((self.max_slots,), bool)
+            copies = []
+            for s, req in rows:
+                toks[s] = req.out_tokens[-1]
+                pos[s] = req.next_pos + ahead
+                mask[s] = True
+                if self.paged:
+                    # the write may cross into a new page (allocate)
+                    # or a shared one (COW) — resolve BEFORE the step.
+                    # One page a row, the one holding pos: a retried
+                    # step claims it again idempotently and writes it
+                    # anyway, pos stays inside the row's reservation
+                    # (a row that ends by length is never run ahead),
+                    # and release() frees it on evict
+                    # ptpu-lint: disable=PTL301 -- no claim to strand
+                    c = self.cache.ensure_decode_page(s, int(pos[s]))
+                    if c is not None:
+                        copies.append(c)
+            # COW copies run BEFORE the fault point:
+            # ensure_decode_page already flipped the table rows, and a
+            # retried (non-broken) step would not re-issue a lost copy
+            # — device state must be consistent with the table when
+            # the fault can fire
+            live = 0
+            if self.paged:
+                self._run_copies(copies)
+                live = int((pos[mask] // self.cache.page_size + 1).sum())
+            sp.set_attr("cow_copies", len(copies))
+        maybe_fail("serving.step.decode", step=self._step_idx - 1)
+        if self.meshctx is not None:
+            # mesh engines: the SHARDED decode program is about to run
+            # (chaos kill point for the tensor-parallel flavor)
+            maybe_fail("serving.decode.sharded",
+                       step=self._step_idx - 1, tp=self.meshctx.tp)
+        with span("serving.decode.enqueue", batch=len(rows)):
+            c = self.cache
+            if not ahead:
+                # the host's tokens go where the argmax lies
+                fed = jax.device_put(toks, self._tokens_sharding)
+            logits, best, counted, ks, vs, kss, vss, *pools = \
+                self._decode_fn()(
+                    self._params, self._buffers,
+                    self._tokens_fn()(fed), pos, mask,
+                    c.page_table.copy(), c.ks, c.vs, c.kss, c.vss,
+                    *c.pools)
+            c.ks, c.vs = list(ks), list(vs)
+            c.kss, c.vss = list(kss), list(vss)
+            c.pools = pools
+        if ahead:
+            self._m_ahead.inc()
+        return _Step(rows, logits, best, counted,
+                     all(r.sampling.temperature <= 0 for _, r in rows),
+                     live, ahead)
+
+    def _discard(self, step: _Step, reason: str, n: int = 1) -> None:
+        """``n`` rows of ``step``, enqueued ahead, thrown away unread."""
+        if n:
+            step.discarded += n
+            self._m_discarded.labels(reason=reason).inc(n)
 
     def _publish_counted(self, dsp, counted: dict) -> None:
         """What the model's decode program counted beside its logits
@@ -1241,15 +1373,15 @@ class ServingEngine:
                     "(step_counters()), summed over the decode steps")
             self._m_counted[name].inc(n)
 
-    def _decode_span(self, name: str, active, **attrs):
-        """The batch span of one decode or verify step. It carries the
-        batch size; the request ids ride along only on a process whose
-        requests have trace contexts (a cluster worker: the merged
-        timeline fans batch spans out to per-request lanes)."""
+    def _decode_span(self, name: str, reqs, **attrs):
+        """The batch span of one decode or verify step over the requests
+        ``reqs``. It carries the batch size; the request ids ride along
+        only on a process whose requests have trace contexts (a cluster
+        worker: the merged timeline fans batch spans out to per-request
+        lanes)."""
         if has_bindings():
-            attrs["request_ids"] = [self.cache.slots[s].rid
-                                    for s in active]
-        return span(name, batch=len(active), **attrs)
+            attrs["request_ids"] = [r.rid for r in reqs]
+        return span(name, batch=len(reqs), **attrs)
 
     def _decode_verify(self, active, finished: List[Request]) -> None:
         """One speculative verify step: draft up to k-1 tokens per
@@ -1393,7 +1525,9 @@ class ServingEngine:
                 maybe_fail("serving.decode.sharded",
                            step=self._step_idx - 1,
                            tp=self.meshctx.tp)
-            with self._decode_span("serving.verify", active, k=K):
+            with self._decode_span("serving.verify",
+                                   [self.cache.slots[s] for s in active],
+                                   k=K):
                 with span("serving.decode.enqueue", batch=len(active)):
                     logits, greedy, acc, ks, vs, kss, vss = \
                         self._verify_fn()(
@@ -1744,6 +1878,7 @@ class ServingEngine:
         # SURVIVES — _new_cache() rehydrates its radix index from the
         # tier, so demoted prefixes stay warm across the rebuild
         self._staged_promotions.clear()
+        self._ahead = None
         self.cache = self._new_cache()
         self._refresh_state()
         # accumulate on the ENGINE, not a local: if a re-prefill below
@@ -2952,6 +3087,25 @@ class ServingEngine:
             ptpu_decode, donate_argnums=self._donate_idx(
                 *range(6, 10 + len(self.cache.pools))), **jit_kw)
         return self._decode_jit
+
+    def _tokens_fn(self):
+        """The decode program's ``[slots, 1]`` token block from a
+        ``[slots]`` int32 vector: the host's tokens, or a step's argmax
+        left on the device (a step enqueued ahead). Both reach the
+        decode program through this one program, so the decode program
+        sees one argument signature and compiles once; mesh engines get
+        the block replicated, as the decode program takes it."""
+        if self._tokens_jit is not None:
+            return self._tokens_jit
+        R = self._tokens_sharding
+        jit_kw = {} if R is None else dict(in_shardings=R, out_shardings=R)
+
+        def ptpu_tokens(toks):
+            self._count_trace("tokens")
+            return toks[:, None]
+
+        self._tokens_jit = self._jit(ptpu_tokens, **jit_kw)
+        return self._tokens_jit
 
     def _verify_fn(self):
         """THE speculative verify program (compiled once per engine):

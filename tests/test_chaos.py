@@ -656,11 +656,13 @@ def test_open_ended_soak(tmp_path):
 # seed's fault schedule drives the ledger red (the bug class is
 # DETECTED), while the fixed code stays green on the same seed.
 
-PINNED_SEED_BUG_A = 17      # deadline expiry in the step a decode
+PINNED_SEED_BUG_A = 4       # deadline expiry in the step a decode
 PINNED_SEED_BUG_B = 7       # fault lands in / fault mid-drain
 # (re-pinned for the SPECULATIVE episode flow — the speculative-engine
 # sampling, verify fault arm and repetitive pool prompts shifted every
-# seed's schedule)
+# seed's schedule; bug (a)'s seed again once plain decode steps ran one
+# step ahead: the decode fault point fires where a step is enqueued,
+# which moved the schedule's fault into another step)
 
 
 def test_pinned_seed_catches_lost_finished_on_failed_step(monkeypatch):

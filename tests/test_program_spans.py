@@ -286,18 +286,30 @@ def test_each_step_has_its_six_children_inside_it(engine_run):
     by_parent = {}
     for s in spans:
         by_parent.setdefault(s["parent"], []).append(s)
+    enqueues, ahead = 0, []
     for st in steps:
         kids = by_parent.get(st["id"], [])
         below = list(kids)
         for k in kids:                  # build/enqueue/fetch sit under
             below += by_parent.get(k["id"], [])    # serving.decode
         names = {k["name"] for k in below}
-        assert set(ENGINE_CHILDREN) <= names, (st["attrs"], names)
-        assert "serving.decode" in {k["name"] for k in kids}
+        (dec,) = [k for k in kids if k["name"] == "serving.decode"]
+        # build and enqueue sit in the step that enqueued the decode it
+        # reads: itself, or, where that decode ran one step ahead, the
+        # step before, beside its own
+        need = set(ENGINE_CHILDREN)
+        if dec["attrs"]["ahead"]:
+            need -= {"serving.decode.build", "serving.decode.enqueue"}
+        assert need <= names, (st["attrs"], names)
+        enqueues += sum(k["name"] == "serving.decode.enqueue"
+                        for k in below)
+        ahead.append(dec["attrs"]["ahead"])
         for k in below:
             assert st["t0"] <= k["t0"] <= k["t1"] <= st["t1"], k
         assert sum(k["dur"] for k in kids) <= st["dur"] + 1e-9
         assert st["self"] <= st["dur"]
+    # every decode read was enqueued once; most here ran ahead
+    assert enqueues == len(steps) and 0 < sum(ahead) < len(steps)
     dec = next(s for s in spans if s["name"] == "serving.decode")
     inside = {k["name"] for k in by_parent[dec["id"]]}
     assert inside >= {"serving.decode.build", "serving.decode.enqueue",
@@ -393,7 +405,7 @@ def test_compile_events_are_logged_and_kept_for_the_recorder(caplog):
     assert any(ln.startswith("kind=decode ") for ln in lines)
     (step,) = [r for r in rec.snapshot() if r["kind"] == "serving.step"]
     assert [(c["kind"], c["key"]) for c in step["compiles"]] == \
-        [("prefill", 16), ("decode", None)]
+        [("prefill", 16), ("tokens", None), ("decode", None)]
     eng.step()                           # nothing compiles any more
     assert rec.snapshot()[-1]["compiles"] == []
 

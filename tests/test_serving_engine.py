@@ -535,3 +535,219 @@ def test_metrics_accounting_fake_clock():
     # token gaps [0, 1, 1]: two tokens at t=1, then one per step
     assert m["tok_latency_p50_s"] == pytest.approx(1.0)
     assert m["occupancy_mean"] == pytest.approx(1.0)
+
+
+# -- one step ahead ----------------------------------------------------
+# A page engine enqueues the next decode step, fed this step's argmax
+# on the device, before it reads this step. Each case runs the same
+# traffic on an engine that runs ahead and on one drained (every step
+# read before the next is built: its run-ahead taken away), with the
+# same clock, and compares what the requests got.
+
+@pytest.fixture(scope="module")
+def ahead_model():
+    return _tiny_llama()
+
+
+def _ahead_engine(model, drained=False, **kw):
+    from paddle_tpu.observability import MetricRegistry
+    kw = dict(dict(max_slots=3, max_len=64, min_bucket=8, page_size=8,
+                   registry=MetricRegistry()), **kw)
+    eng = ServingEngine(model, **kw)
+    if drained:
+        eng._ahead_rows = lambda step, active: []
+    return eng
+
+
+def _ahead_counts(eng):
+    """(steps enqueued ahead, {reason: rows thrown away})."""
+    reg = eng.registry
+    disc = reg.get("ptpu_serving_decode_discarded_rows_total")
+    return (int(reg.get("ptpu_serving_decode_ahead_total").value),
+            {r: int(disc.labels(reason=r).value)
+             for r in ("finished", "resampled")})
+
+
+def _mixed_traffic():
+    """Four requests at step 0 on three slots (one waits), two more
+    admitted between later steps: the first shares 12 tokens of the
+    first prompt's 20, into the middle of its second page (a COW copy).
+    One request of step 0 carries a deadline, another is cancelled."""
+    rng = np.random.RandomState(12)
+    base = rng.randint(1, 128, (20,)).astype(np.int64)
+    first = [(base, 12, None), (_prompts(rng, [7])[0], 16, 4.5),
+             (_prompts(rng, [11])[0], 14, None),
+             (_prompts(rng, [5])[0], 9, None)]
+    later = {2: (np.concatenate([base[:12], _prompts(rng, [6])[0]]), 8),
+             4: (_prompts(rng, [9])[0], 10)}
+    return first, later
+
+
+def _drive(eng, clock, traffic, cancel_at=8, max_steps=80):
+    first, later = traffic
+    reqs = [eng.submit(p, n, deadline_s=d) for p, n, d in first]
+    steps = 0
+    while eng.has_work() and steps < max_steps:
+        clock["t"] = float(steps)
+        eng.step()
+        steps += 1
+        if steps in later:
+            reqs.append(eng.submit(*later[steps]))
+        if steps == cancel_at:
+            eng.cancel(reqs[2])
+    return [(r.output_ids, r.finish_reason) for r in reqs]
+
+
+def test_running_ahead_serves_the_drained_engines_tokens(ahead_model):
+    """(a) Greedy outputs and finish reasons, run ahead and drained,
+    over EOS mid-stream, length, admissions between steps, a shared
+    prefix with a COW page, a cancel and a deadline."""
+    traffic = _mixed_traffic()
+    clock = {"t": 0.0}
+    probe = _drive(_ahead_engine(ahead_model, drained=True,
+                                 time_fn=lambda: clock["t"]),
+                   clock, traffic)
+    eos = probe[5][0][3]      # the fourth token of a request let in late
+    got = {}
+    for drained in (False, True):
+        clock["t"] = 0.0
+        eng = _ahead_engine(ahead_model, drained=drained, eos_id=eos,
+                            time_fn=lambda: clock["t"])
+        got[drained] = _drive(eng, clock, traffic)
+        assert eng.cache.cow_copies >= 1
+        assert not eng.has_work()
+        if not drained:
+            ahead, thrown = _ahead_counts(eng)
+    assert got[False] == got[True]
+    reasons = [why for _, why in got[False]]
+    assert {"eos", "length", "cancelled", "deadline"} <= set(reasons)
+    assert any(why == "eos" and len(out) > 1 for out, why in got[False])
+    # the deadline, the cancel and the EOS each threw away a row of a
+    # step enqueued ahead
+    assert ahead > 10
+    assert thrown == {"finished": 3, "resampled": 0}
+
+
+def _altered_every_fifth_of_each_request():
+    """``chipbench.serving_loop.altered_tokens``'s fault counted per
+    request (a request's own sampling stream names it), so that which
+    tokens are altered does not depend on how rows interleave."""
+    import contextlib
+    import paddle_tpu.serving.engine as engine_module
+    real, calls = engine_module.sample_token, {}
+
+    def altered(logits, params, rng):
+        n = calls[id(rng)] = calls.get(id(rng), 0) + 1
+        tok = real(logits, params, rng)
+        return (tok + 1) % len(logits) if n % 5 == 0 else tok
+
+    @contextlib.contextmanager
+    def patched():
+        engine_module.sample_token = altered
+        try:
+            yield calls
+        finally:
+            engine_module.sample_token = real
+    return patched()
+
+
+@pytest.mark.parametrize("traffic", ["one_request", "a_batch"])
+def test_a_resampled_row_runs_its_position_again(ahead_model, traffic):
+    """(b) ``sample_token`` alters every fifth token, through the seam
+    the benchmark's planted fault uses: a row whose sampled token is
+    not the argmax it was fed ahead is thrown away and run again from
+    the sampled token, so the outputs equal the drained engine's under
+    the same fault, and ``discarded{resampled}`` counts those rows."""
+    from chipbench.serving_loop import altered_tokens
+    rng = np.random.RandomState(13)
+    if traffic == "one_request":
+        work = [(_prompts(rng, [9])[0], 17)]
+        fault = lambda: altered_tokens(every=5)
+    else:
+        work = list(zip(_prompts(rng, [6, 10, 4, 8]), [15, 12, 18, 9]))
+        fault = _altered_every_fifth_of_each_request
+    got = {}
+    for drained in (False, True):
+        eng = _ahead_engine(ahead_model, drained=drained)
+        with fault():
+            reqs = [eng.submit(p, n) for p, n in work]
+            eng.run(max_steps=100)
+        got[drained] = [r.output_ids for r in reqs]
+        if not drained:
+            ahead, thrown = _ahead_counts(eng)
+    assert got[False] == got[True]
+    assert got[False] != [model_out for model_out in
+                          _greedy_outputs(ahead_model, work)]
+    # the altered tokens past each request's first (its prefill's)
+    # that are not its last: a step had been enqueued behind each
+    altered = sum(len(range(5, n, 5)) for _, n in work)
+    assert thrown["finished"] == 0 and ahead > 0
+    if traffic == "one_request":
+        assert thrown["resampled"] == altered == 3
+    else:
+        assert 0 < thrown["resampled"] <= altered
+
+
+def _greedy_outputs(model, work):
+    eng = _ahead_engine(model, max_slots=len(work))
+    reqs = [eng.submit(p, n) for p, n in work]
+    eng.run(max_steps=100)
+    return [r.output_ids for r in reqs]
+
+
+def test_host_and_device_fed_steps_share_one_decode_program(ahead_model):
+    """(c) The decode program is traced once over a run that mixes
+    steps fed from the host's tokens with steps fed the argmax on the
+    device: both reach it through the one token program, with one
+    dtype, shape and placement."""
+    eng = _ahead_engine(ahead_model)
+    real, seen = eng._decode_fn(), []
+
+    def spy(*args):
+        toks = args[2]
+        seen.append((type(toks), toks.dtype, toks.shape,
+                     str(toks.sharding), toks.committed))
+        return real(*args)
+
+    eng._decode_jit = spy
+    rng = np.random.RandomState(14)
+    for p, n in zip(_prompts(rng, [5, 9, 3]), [8, 5, 11]):
+        eng.submit(p, n)
+    for _ in range(3):
+        eng.step()
+    eng.submit(_prompts(rng, [7])[0], 6)      # admitted while ahead
+    eng.run(max_steps=100)
+    ahead, _ = _ahead_counts(eng)
+    assert 0 < ahead < len(seen)              # host-fed steps too
+    assert len(set(seen)) == 1
+    assert eng.trace_counts["decode"] == 1
+    assert eng.trace_counts["tokens"] == 1
+
+
+@pytest.mark.parametrize("case", ["sampled_row", "state_engine"])
+def test_what_cannot_run_ahead_stays_synchronous(ahead_model, case):
+    """(d) A batch with a ``temperature > 0`` row fetches its logits and
+    samples on the host, and an engine with a recurrent state folds a
+    token into it for good: neither runs a step ahead."""
+    from paddle_tpu.observability import (TraceBuffer,
+                                          install_trace_buffer, tracing)
+    rng = np.random.RandomState(15)
+    if case == "state_engine":
+        from test_brumby_serving import _model as brumby_model
+        eng = _ahead_engine(brumby_model(), page_size=None)
+    else:
+        eng = _ahead_engine(ahead_model)
+        eng.submit(_prompts(rng, [4])[0], 12,
+                   sampling=SamplingParams(temperature=0.9, seed=3))
+    for p, n in zip(_prompts(rng, [6, 3]), [7, 10]):
+        eng.submit(p, n)
+    buf = TraceBuffer(tracing.DEFAULT_CAPACITY)
+    prev = install_trace_buffer(buf)
+    try:
+        eng.run(max_steps=100)
+        spans = tracing.query("serving.decode")["spans"]
+    finally:
+        install_trace_buffer(prev)
+    assert not eng.has_work() and spans
+    assert {s["attrs"]["ahead"] for s in spans} == {0}
+    assert _ahead_counts(eng) == (0, {"finished": 0, "resampled": 0})
